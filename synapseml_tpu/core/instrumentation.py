@@ -19,19 +19,33 @@ from typing import Iterator
 
 __all__ = ["InstrumentationMeasures", "profile_trace", "chip_peak_tflops"]
 
-# bf16 peak TFLOPs per chip, by device_kind substring (for MFU reporting)
-_CHIP_PEAK_TFLOPS = [
-    ("v5 lite", 197.0), ("v5e", 197.0), ("v5p", 459.0), ("v6", 918.0),
-    ("v4", 275.0), ("v3", 123.0), ("v2", 45.0),
-]
+# bf16 peak TFLOP/s of one chip, keyed by the exact ``device_kind`` string
+# jax reports (lower-cased). Source: Google Cloud TPU documentation, the
+# per-generation system-architecture pages ("TPU v5e": 197 TFLOP/s bf16).
+_CHIP_PEAK_TFLOPS = {
+    "tpu v2": 45.0,
+    "tpu v3": 123.0,
+    "tpu v4": 275.0,
+    "tpu v5 lite": 197.0,   # v5e
+    "tpu v5e": 197.0,
+    "tpu v5": 459.0,        # v5p
+    "tpu v5p": 459.0,
+    "tpu v6 lite": 918.0,   # v6e (Trillium)
+    "tpu v6e": 918.0,
+}
 
 
-def chip_peak_tflops(device_kind: str) -> float | None:
-    kind = (device_kind or "").lower()
-    for key, peak in _CHIP_PEAK_TFLOPS:
-        if key in kind:
-            return peak
-    return None
+def chip_peak_tflops(device_kind: str) -> float:
+    """bf16 peak of the named TPU chip (the MFU denominator). A device that
+    is not in the table is an error, not a default — callers on non-TPU
+    platforms do not ask."""
+    try:
+        return _CHIP_PEAK_TFLOPS[(device_kind or "").strip().lower()]
+    except KeyError:
+        raise ValueError(
+            f"no bf16 peak recorded for device_kind {device_kind!r}; add it "
+            f"to core.instrumentation._CHIP_PEAK_TFLOPS with its source "
+            f"(known: {sorted(_CHIP_PEAK_TFLOPS)})") from None
 
 
 class InstrumentationMeasures:

@@ -284,9 +284,13 @@ class SubprocessWorkerLauncher(WorkerLauncher):
             f"fleet_worker_main({self.registry_root!r}, {slo.model!r}, "
             f"{slo.ref!r}, {register_url!r}, serve_kwargs={kwargs!r}, "
             f"use_aot={self.use_aot!r}, warmup_rows={self.warmup_rows!r})")
+        from ..core.platform import check_chip_launch
+
+        # no CPU default: workers run on what JAX finds, or on what ``env=``
+        # / this process's environment explicitly names in JAX_PLATFORMS
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         env.update(self._env)
+        check_chip_launch(len(self._procs) + 1, env)
         repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
         paths = [repo_root, *self._extra_sys_path]
@@ -337,11 +341,10 @@ def fleet_worker_main(registry_root: str, model: str, ref: str = "latest",
     the swap report in the registration shows whether it did), register
     with the driver, and park. ``POST /admin/drain`` finishes the backlog,
     deregisters, and exits the process — the graceful half of elasticity."""
-    import jax
-
-    jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS", "cpu"))
+    from ..core.platform import enable_compile_cache
     from ..io.serving import serve_pipeline
 
+    enable_compile_cache()
     server = serve_pipeline(_PlaceholderStage(), version="starting",
                             **(serve_kwargs or {}))
     payload = {"registry": registry_root, "model": model, "ref": ref,

@@ -15,6 +15,16 @@ HBM traffic is one stream over (seg, grad, hess, count) per feature, nothing
 else. Grid = (bin-tiles, row-chunks) with chunks innermost, so each output
 tile stays VMEM-resident while every chunk accumulates into it.
 
+Layout (what the Mosaic lowering accepts — every block's last two dims are
+multiples of (8, 128)): rows are laid ``_LANES`` to a lane-row; one grid
+step takes ``_SUB_ROWS`` lane-rows of segment ids as an ``(8, _LANES)``
+block and the matching ``(8, 8, _LANES)`` block of data, where each
+lane-row's data is an aligned ``(8, _LANES)`` tile holding grad, hess, count
+on sublanes 0..2 and zeros below. Per lane-row the product is
+``data[8, L] . onehot[bin_tile, L]^T`` (both operands contract their lane
+axis, the q.k^T form), so the histogram comes out TRANSPOSED as a lane-dense
+``(8, bin_tile)`` tile and the wrapper slices rows 0..2 back to ``(WB, 3)``.
+
 Interpret mode makes the same kernel run (slowly) on CPU for tests.
 """
 
@@ -24,20 +34,22 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+from ..core import platform
 
 __all__ = ["pallas_segment_histogram"]
 
-_ROW_CHUNK = 1024     # rows per grid step (seg/g/h/c stream tile)
-_BIN_TILE = 512       # histogram slots per output tile (lanes)
+_LANES = 512          # rows per lane-row (last block dim, multiple of 128)
+_SUB_ROWS = 8         # lane-rows per grid step (the sublane tile)
+_BIN_TILE = 512       # histogram slots per output tile (lanes of the output)
 
 
-def _hist_kernel(seg_ref, g_ref, h_ref, c_ref, out_ref, *, bin_tile: int,
-                 chunk: int):
-    """One (bin-tile j, row-chunk c) program: out[j] += onehot(seg_c)^T @ data.
+def _hist_kernel(seg_ref, data_ref, out_ref, *, bin_tile: int):
+    """One (bin-tile j, row-chunk c) program: out[:, j] += data . onehot^T.
 
-    seg/g/h/c blocks: [1, chunk]; out block: [bin_tile, 3] (revisited across
-    the chunk dimension — accumulate, init at the first chunk).
+    seg block [_SUB_ROWS, _LANES] int32; data block [_SUB_ROWS, 8, _LANES]
+    f32; out block [8, bin_tile] f32 (revisited across the chunk dimension —
+    accumulate, init at the first chunk).
     """
     from jax.experimental import pallas as pl
 
@@ -48,16 +60,19 @@ def _hist_kernel(seg_ref, g_ref, h_ref, c_ref, out_ref, *, bin_tile: int,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    seg = seg_ref[...]                                   # [1, chunk] int32
     # one-hot tile generated in VMEM: bins_col[b, r] = j*bin_tile + b
     bins_col = j * bin_tile + jax.lax.broadcasted_iota(
-        jnp.int32, (bin_tile, chunk), 0)
-    oh = (seg == bins_col).astype(jnp.float32)           # [bin_tile, chunk]
-    data = jnp.concatenate([g_ref[...], h_ref[...], c_ref[...]], axis=0)
-    # [bin_tile, chunk] @ [3, chunk]^T on the MXU, f32 accumulation
-    out_ref[...] += jax.lax.dot_general(
-        oh, data, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        jnp.int32, (bin_tile, _LANES), 0)
+    acc = jnp.zeros(out_ref.shape, jnp.float32)
+    for i in range(_SUB_ROWS):
+        oh = (seg_ref[i:i + 1, :] == bins_col).astype(jnp.float32)
+        # HIGHEST: grad/hess are f32 and a one-pass bf16 product would
+        # round them; the one-hot side is exact either way
+        acc += jax.lax.dot_general(
+            data_ref[i], oh, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+    out_ref[...] += acc
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -71,36 +86,30 @@ def pallas_segment_histogram(seg: jax.Array, data: jax.Array,
     """
     from jax.experimental import pallas as pl
 
-    if jax.default_backend() not in ("tpu", "cpu"):
-        import warnings
-
-        warnings.warn(
-            "histogram_impl='pallas' has a compiled kernel only on TPU; on "
-            f"{jax.default_backend()!r} it runs in interpret mode, orders of "
-            "magnitude slower — use 'segment' or 'onehot' here",
-            stacklevel=2)
     N = seg.shape[0]
-    # floor of 128: last-dim tiles below the TPU's 128-lane register width
-    # are not guaranteed to compile in Mosaic (padding covers the unused tail)
-    chunk = min(_ROW_CHUNK, max(int(2 ** np.ceil(np.log2(max(N, 8)))), 128))
-    n_chunks = -(-N // chunk)
+    chunk = _SUB_ROWS * _LANES
+    n_chunks = max(-(-N // chunk), 1)
     n_pad = n_chunks * chunk - N
-    bin_tile = min(_BIN_TILE, max(-(-num_segments // 128) * 128, 128))
+    bin_tile = min(_BIN_TILE, -(-num_segments // 128) * 128)
     n_tiles = -(-num_segments // bin_tile)
     wb_pad = n_tiles * bin_tile
 
     # padded rows get seg = wb_pad: matches no bin tile, contributes nothing
     seg_p = jnp.pad(seg.astype(jnp.int32), (0, n_pad),
-                    constant_values=wb_pad).reshape(n_chunks, chunk)
-    gp, hp, cp = (jnp.pad(data[:, i], (0, n_pad)).reshape(n_chunks, chunk)
-                  for i in range(3))
+                    constant_values=wb_pad).reshape(-1, _LANES)
+    # (N, 3) -> (lane-rows, 8, _LANES): channels on sublanes 0..2
+    data_p = jnp.pad(data.astype(jnp.float32).T, ((0, 5), (0, n_pad)))
+    data_p = data_p.reshape(8, -1, _LANES).transpose(1, 0, 2)
 
     out = pl.pallas_call(
-        functools.partial(_hist_kernel, bin_tile=bin_tile, chunk=chunk),
+        functools.partial(_hist_kernel, bin_tile=bin_tile),
         grid=(n_tiles, n_chunks),
-        in_specs=[pl.BlockSpec((1, chunk), lambda j, c: (c, 0))] * 4,
-        out_specs=pl.BlockSpec((bin_tile, 3), lambda j, c: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((wb_pad, 3), jnp.float32),
-        interpret=jax.default_backend() != "tpu",
-    )(seg_p, gp, hp, cp)
-    return out[:num_segments]
+        in_specs=[
+            pl.BlockSpec((_SUB_ROWS, _LANES), lambda j, c: (c, 0)),
+            pl.BlockSpec((_SUB_ROWS, 8, _LANES), lambda j, c: (c, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((8, bin_tile), lambda j, c: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((8, wb_pad), jnp.float32),
+        interpret=platform.pallas_interpret(),
+    )(seg_p, data_p)
+    return out[:3, :num_segments].T

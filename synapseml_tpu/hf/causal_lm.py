@@ -16,8 +16,6 @@ tests. Tokenization: a transformers tokenizer when available locally
 
 from __future__ import annotations
 
-import functools
-
 import numpy as np
 
 from ..core import batching as cb
@@ -179,6 +177,13 @@ class HuggingFaceCausalLM(Transformer):
                 mesh, params = shard_pretrained_params(
                     plain, self.get("mesh_config"),
                     self.get("partition_rules"))
+            else:
+                import jax
+
+                # ONE device copy shared by the dense and paged engines: a
+                # checkpoint directory loads as host numpy, and a host tree
+                # passed to a jitted step is re-uploaded on every call
+                params = jax.device_put(params)
             self.__dict__["_cache_model"] = (model, params, tok, mesh)
         return self.__dict__["_cache_model"]
 
@@ -243,14 +248,17 @@ class HuggingFaceCausalLM(Transformer):
                 jitted = jax.jit(fn, out_shardings=mesh.replicated())
 
                 def run(ids, mask, offset, _j=jitted, _m=mesh):
-                    with _m.mesh:
+                    with _m.scope():
                         # batch shards over data/fsdp; params already placed
                         return _j(params, _m.shard_batch(ids),
                                   _m.shard_batch(mask), offset)
 
                 return run
-            jitted = jax.jit(functools.partial(fn, params))
-            return jitted
+            # params are a jit ARGUMENT here too: closed over, the weights
+            # would be baked into the program as constants — gigabytes of
+            # HLO literal at real widths
+            jitted = jax.jit(fn)
+            return lambda ids, mask, offset: jitted(params, ids, mask, offset)
 
         return cb.get_compiled_cache().get(
             "hf_causal_lm", (B, P) + eff_key, build,

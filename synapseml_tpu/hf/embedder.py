@@ -145,7 +145,7 @@ class HuggingFaceSentenceEmbedder(Transformer):
             jitted = jax.jit(embed_fn)
             if mesh is not None:
                 def sharded(ids, m, _j=jitted, _m=mesh):
-                    with _m.mesh:
+                    with _m.scope():
                         return _j(_m.shard_batch(ids), _m.shard_batch(m))
                 return sharded
             return jitted

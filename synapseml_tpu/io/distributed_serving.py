@@ -2019,11 +2019,12 @@ def worker_main(pipeline_path: str, registry_address: str,
     then park forever (the per-executor server loop). A hot swap
     (``POST /admin/load``) re-registers the worker with its NEW version so
     the front's canary routing and per-version metrics follow the swap."""
-    import jax
-
-    jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS", "cpu"))
+    # the worker runs on what JAX finds (or what its launcher explicitly
+    # put in JAX_PLATFORMS) — never a CPU default that would hide the chip
+    from ..core.platform import enable_compile_cache
     from .serving import serve_pipeline
 
+    enable_compile_cache()
     with open(pipeline_path, "rb") as f:
         pipeline = pickle.load(f)
     server = serve_pipeline(pipeline, batch_interval_ms=batch_interval_ms,
@@ -2068,12 +2069,11 @@ def llm_worker_main(model_name: str, registry_address: str,
     registry, then park. The survivable-serving chaos tests SIGKILL these
     processes mid-decode; a drain (``/admin/drain`` with ``migrate_to``)
     deregisters and exits cleanly instead."""
-    import jax
-
-    jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS", "cpu"))
+    from ..core.platform import enable_compile_cache
     from ..hf import HuggingFaceCausalLM
     from .serving import serve_llm
 
+    enable_compile_cache()
     lm = HuggingFaceCausalLM(model_name=model_name,
                              max_new_tokens=max_new_tokens, engine=engine)
     server = serve_llm(lm, warmup=warmup)
@@ -2189,6 +2189,15 @@ def serve_pipeline_distributed(pipeline, num_workers: int = 2,
             "row per loop — the group would serialize for no gain)")
     import tempfile
 
+    from ..core.platform import check_chip_launch
+
+    # workers inherit this process's environment unchanged: JAX_PLATFORMS=cpu
+    # there is the explicit way to ask for CPU workers; on a chip host the
+    # launch is refused when it could only hang (parent holds the chip, or
+    # several workers would each claim it)
+    env = dict(os.environ)
+    check_chip_launch(num_workers, env)
+
     fd, path = tempfile.mkstemp(suffix=".pipeline.pkl")
     with os.fdopen(fd, "wb") as f:
         pickle.dump(pipeline, f)
@@ -2197,8 +2206,6 @@ def serve_pipeline_distributed(pipeline, num_workers: int = 2,
     code = ("from synapseml_tpu.io.distributed_serving import worker_main; "
             f"worker_main({path!r}, {registry.address + '/register'!r}, "
             f"{batch_interval_ms}, version={version!r})")
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     paths = [repo_root]

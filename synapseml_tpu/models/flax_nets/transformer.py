@@ -57,16 +57,10 @@ class TransformerConfig:
     remat: bool = False
     norm_eps: float = 1e-6
     # attention backend: 'einsum' (XLA, always available), 'flash' (Pallas
-    # blockwise kernel, ops.flash_attention), 'ring' (sequence-parallel ring
-    # over `seq_axis`, ops.ring_attention — requires a live mesh whose
-    # seq axis size > 1; falls back to flash/einsum otherwise).
-    # 'einsum' is the measured-fastest default on v5e at T=128..4096
-    # (docs/BENCHMARKS.md) — XLA's fused attention beats the Pallas kernel;
-    # use 'flash' only when the O(T^2) score buffer doesn't fit, 'ring' for
-    # true long-context over the mesh. CAVEAT: that table predates the bf16
-    # MXU fix (commit ee387ce) which made the flash/ring kernels ~4x faster;
-    # re-measurement is queued as the `attn-backends` bench child — treat
-    # the default as provisional until it lands (docs/BENCHMARKS.md).
+    # blockwise kernel, ops.flash_attention), 'ring' / 'ulysses'
+    # (sequence-parallel over `seq_axis` — an error without a mesh in scope
+    # whose seq axis size > 1). Which is fastest on the chip is not
+    # measured (ROADMAP A3); 'flash' avoids the O(T^2) score buffer.
     attn_impl: str = "einsum"
     seq_axis: str = "seq"
     # mixture-of-experts MLP (switch-transformer routing): 0 = dense MLP.
@@ -143,29 +137,6 @@ def make_causal_mask(q_len: int, kv_len: int, offset: int = 0) -> jax.Array:
     return (kv_pos <= q_pos)[None, None, :, :]  # [1,1,Q,KV]
 
 
-def _current_mesh():
-    """The mesh in scope (``with mesh:`` context or jit sharding env), if any."""
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return m
-    except Exception:
-        pass
-    try:
-        import warnings
-
-        from jax.interpreters import pxla
-
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            m = pxla.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
-
-
 class Attention(nn.Module):
     """Multi-head / grouped-query attention with optional rotary embeddings and
     a linen cache collection for autoregressive decode.
@@ -189,7 +160,9 @@ class Attention(nn.Module):
                              and mask.shape[1] == 1 and mask.shape[2] == 1)
         if mask_is_kv_shaped:
             kv_mask = mask[:, 0, 0, :]
-        impl = cfg.attn_impl
+        # init only makes params and the core has none: its example inputs
+        # (a (1, 8) batch in shard_inference_params) need not divide a mesh
+        impl = "einsum" if self.is_initializing() else cfg.attn_impl
         # NOTE: flash/ring never materialize attention probabilities, so
         # attention-probability dropout does not apply on those paths (standard
         # for fused kernels); residual/MLP dropout is unaffected. Falling back
@@ -197,27 +170,29 @@ class Attention(nn.Module):
         eligible = not self.decode and (mask is None or mask_is_kv_shaped)
 
         if impl in ("ring", "ulysses") and eligible:
-            mesh = _current_mesh()
-            if mesh is not None and dict(zip(mesh.axis_names, mesh.axis_sizes)
-                                         ).get(cfg.seq_axis, 1) > 1:
-                if impl == "ulysses":
-                    from ...ops import ulysses_attention_sharded
+            from ...parallel.mesh import current_mesh
 
-                    return ulysses_attention_sharded(
-                        mesh, q, k, v, kv_mask=kv_mask, causal=cfg.causal,
-                        seq_axis=cfg.seq_axis)
-                from ...ops import ring_attention_sharded
+            mesh = current_mesh()
+            if mesh is None or mesh.axis_sizes.get(cfg.seq_axis, 1) <= 1:
+                # never substitute a local kernel: the caller sized the
+                # sequence for the mesh, and a silent swap hides the device
+                raise ValueError(
+                    f"attn_impl={impl!r} needs a mesh with a "
+                    f"'{cfg.seq_axis}' axis of size > 1 in scope (apply the "
+                    f"module under MeshContext.scope(), e.g. "
+                    f"mesh_config=MeshConfig(data=-1, seq=2)); in scope: "
+                    f"{mesh and mesh.axis_sizes}")
+            if impl == "ulysses":
+                from ...ops import ulysses_attention_sharded
 
-                return ring_attention_sharded(mesh, q, k, v, kv_mask=kv_mask,
-                                              causal=cfg.causal,
-                                              seq_axis=cfg.seq_axis)
-            import warnings
+                return ulysses_attention_sharded(
+                    mesh, q, k, v, kv_mask=kv_mask, causal=cfg.causal,
+                    seq_axis=cfg.seq_axis)
+            from ...ops import ring_attention_sharded
 
-            warnings.warn(
-                f"attn_impl={impl!r} requested but no mesh with a "
-                f"'{cfg.seq_axis}' axis (size>1) is in scope; using the local "
-                f"flash kernel instead", stacklevel=2)
-            impl = "flash"
+            return ring_attention_sharded(mesh, q, k, v, kv_mask=kv_mask,
+                                          causal=cfg.causal,
+                                          seq_axis=cfg.seq_axis)
 
         if impl == "flash" and eligible:
             from ...ops import flash_attention

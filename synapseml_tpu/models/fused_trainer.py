@@ -214,14 +214,14 @@ class FusedTrainer:
                                    if self.trials[t]["seed"] is None
                                    else self.trials[t]["seed"]))
             for t in slot_trials])
-        with self.mesh.mesh:
+        with self.mesh.scope():
             params = init_fn(keys)
         tx = self._tx
 
         def _build_opt():
             return jax.jit(jax.vmap(tx.init))
 
-        with self.mesh.mesh:
+        with self.mesh.scope():
             opt_state = cache.get("fused_opt_init", (rung,), _build_opt,
                                   instance=token)(params)
         hp = dict(opt_state.hyperparams)
@@ -292,7 +292,7 @@ class FusedTrainer:
             "fused_train_step", (self.rung,) + _batch_shape_key(batch),
             self._build_step(), instance=cb.instance_token(self))
         placed = self.mesh.shard_batch(batch)
-        with self.mesh.mesh:
+        with self.mesh.scope():
             return fn(state, placed)
 
     # ---- early-stop masking + rung compaction ----
@@ -332,7 +332,7 @@ class FusedTrainer:
             instance=cb.instance_token(self))
         core = {k: state[k] for k in ("params", "opt_state", "step",
                                       "hparams")}
-        with self.mesh.mesh:
+        with self.mesh.scope():
             core = fn(core, jnp.asarray(idx, jnp.int32))
         self.slot_ids = [self.slot_ids[s] for s in keep]
         self._active_host = np.asarray(

@@ -135,7 +135,10 @@ class DeepTextClassifier(Estimator, _TextParams):
             import dataclasses
 
             cfg = dataclasses.replace(cfg, attn_impl=self.get("attn_impl"))
-        mesh = create_mesh(self.get("mesh_config") or MeshConfig())
+        # an explicit mesh_config that does not fit the devices is an error,
+        # never a silent degrade to data-parallel
+        mesh = create_mesh(self.get("mesh_config") or MeshConfig(),
+                           allow_fewer=False)
         module = BertClassifier(cfg, num_classes=self.get("num_classes"))
 
         texts = df.collect_column(self.get("text_col"))
@@ -175,6 +178,10 @@ class DeepTextClassifier(Estimator, _TextParams):
             max_token_len=self.get("max_token_len"),
             batch_size=self.get("batch_size"),
             train_metrics=trainer.metrics,
+            # sequence-parallel attention only exists on a mesh with a seq
+            # axis: the model scores on the mesh it was trained on
+            mesh_config=(self.get("mesh_config")
+                         if cfg.attn_impl in ("ring", "ulysses") else None),
         )
 
 
@@ -251,11 +258,15 @@ class DeepTextModel(Model, _TextParams):
             if self.get("mesh_config") is not None:
                 from ..parallel.mesh import shard_inference_params
 
-                mesh = create_mesh(self.get("mesh_config"))
+                mesh = create_mesh(self.get("mesh_config"), allow_fewer=False)
                 params = shard_inference_params(
                     module, {"input_ids": jnp.zeros((1, 8), jnp.int32),
                              "attention_mask": jnp.ones((1, 8), jnp.int32)},
                     params, mesh)
+            else:
+                # one device copy: the saved params are host numpy, and a
+                # host tree passed to jit is re-uploaded on every batch
+                params = jax.device_put(params)
 
             def apply_fn(params, input_ids, attention_mask):
                 logits = module.apply({"params": params}, input_ids, attention_mask)
@@ -266,7 +277,7 @@ class DeepTextModel(Model, _TextParams):
                     jitted = jax.jit(apply_fn)
                     if mesh is not None:
                         def run(ids, m, _j=jitted, _m=mesh):
-                            with _m.mesh:
+                            with _m.scope():
                                 return _j(params, _m.shard_batch(ids),
                                           _m.shard_batch(m))
                         return run

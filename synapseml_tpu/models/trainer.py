@@ -370,7 +370,7 @@ class Trainer:
         ``from_pretrained``."""
         rng = rng if rng is not None else jax.random.PRNGKey(0)
         inputs = self._model_inputs(example_batch)
-        with self.mesh.mesh:
+        with self.mesh.scope():
             variables = self.module.init(rng, **inputs)
         boxed = variables["params"]
         if init_params is not None:
@@ -474,16 +474,16 @@ class Trainer:
         if self._train_step is None:
             self._train_step = jax.jit(self._step_fn(), donate_argnums=(0,))
         placed = self.mesh.shard_batch(batch)
-        with self.mesh.mesh:
+        with self.mesh.scope():
             sd, metrics = self._train_step(state.as_dict() | {"batch_stats": state.batch_stats},
                                            placed)
         return TrainState(params=sd["params"], opt_state=sd["opt_state"], step=sd["step"],
                           batch_stats=sd.get("batch_stats")), metrics
 
     # ---- scanned multi-step: K optimizer steps in ONE dispatch ----
-    # Host dispatch overhead (and, under a remote tunnel, round-trip latency)
-    # disappears: the train loop itself lives on-device as a lax.scan, the
-    # TPU-idiomatic replacement for horovod's per-step host-driven loop.
+    # Host dispatch overhead disappears: the train loop itself lives
+    # on-device as a lax.scan, the TPU-idiomatic replacement for horovod's
+    # per-step host-driven loop.
     def train_steps_scan(self, state: TrainState, stacked_batches: dict
                          ) -> tuple[TrainState, dict]:
         """stacked_batches: pytree whose leaves have leading dim K (num steps)."""
@@ -495,7 +495,7 @@ class Trainer:
 
             self._scan_step = jax.jit(multi, donate_argnums=(0,))
         placed = self.mesh.shard_stacked_batch(stacked_batches)
-        with self.mesh.mesh:
+        with self.mesh.scope():
             sd, metrics = self._scan_step(
                 state.as_dict() | {"batch_stats": state.batch_stats}, placed)
         return (TrainState(params=sd["params"], opt_state=sd["opt_state"], step=sd["step"],
@@ -818,8 +818,10 @@ class _ThroughputMeter:
         self.n_samples = 0
         self.n_tokens = 0
         self.flops_per_token = trainer._flops_per_token(params)
+        # on a TPU the peak table must know the device (it raises
+        # otherwise): an MFU that silently disappears hides the device
         dev = jax.devices()[0]
-        self.peak = (chip_peak_tflops(getattr(dev, "device_kind", "") or "")
+        self.peak = (chip_peak_tflops(dev.device_kind)
                      if dev.platform == "tpu" else None)
         self._last_t = self.t0
         self._last_steps = 0

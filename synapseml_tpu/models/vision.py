@@ -195,7 +195,7 @@ class DeepVisionModel(Model, _VisionParams):
                     jitted = jax.jit(apply_fn)
                     if mesh is not None:
                         def run(x, _j=jitted, _m=mesh):
-                            with _m.mesh:
+                            with _m.scope():
                                 return _j(variables, _m.shard_batch(x))
                         return run
                     return lambda x: jitted(variables, x)

@@ -22,12 +22,13 @@ import threading
 
 import numpy as np
 
-__all__ = ["available", "murmur3_32_native", "murmur3_batch", "docs_token_hashes",
-           "bin_rows", "library_path"]
+__all__ = ["available", "build_error", "murmur3_32_native", "murmur3_batch",
+           "docs_token_hashes", "bin_rows", "library_path"]
 
 _LOCK = threading.Lock()
 _LIB = None
 _TRIED = False
+_BUILD_ERROR: str | None = None  # why the library is unavailable, if it is
 
 _SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "src",
                     "native_ops.cpp")
@@ -64,9 +65,11 @@ def library_path() -> str:
 
 
 def _build() -> str | None:
+    global _BUILD_ERROR
     try:
         out = library_path()  # content-addressed: existing file IS this source
-    except OSError:  # source stripped from the install: pure-Python fallback
+    except OSError as e:  # source stripped from the install: pure-Python fallback
+        _BUILD_ERROR = f"{type(e).__name__}: {e}"
         return None
     if os.path.exists(out):
         return out
@@ -75,8 +78,12 @@ def _build() -> str | None:
             ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", out],
             check=True, capture_output=True, timeout=120)
         return out
-    except (OSError, subprocess.SubprocessError):
-        return None
+    except subprocess.CalledProcessError as e:
+        _BUILD_ERROR = f"g++ exited {e.returncode}: " \
+            f"{e.stderr.decode(errors='replace')[-300:]}"
+    except (OSError, subprocess.SubprocessError) as e:
+        _BUILD_ERROR = f"{type(e).__name__}: {e}"
+    return None
 
 
 def _load():
@@ -90,7 +97,9 @@ def _load():
             return None
         try:
             lib = ctypes.CDLL(path)
-        except OSError:
+        except OSError as e:
+            global _BUILD_ERROR
+            _BUILD_ERROR = f"{type(e).__name__}: {e}"
             return None
         lib.nat_murmur3_32.restype = ctypes.c_uint32
         lib.nat_murmur3_32.argtypes = [ctypes.c_char_p, ctypes.c_int64,
@@ -117,6 +126,13 @@ def _load():
 
 def available() -> bool:
     return _load() is not None
+
+
+def build_error() -> str | None:
+    """Why :func:`available` is False (compiler output / load error), or
+    None when the library loaded."""
+    _load()
+    return _BUILD_ERROR
 
 
 def murmur3_32_native(data: bytes, seed: int = 0) -> int | None:

@@ -24,11 +24,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import platform
+
 _NEG_INF = -1e30
-
-
-def _pick_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def reference_attention(q, k, v, kv_mask=None, causal: bool = False,
@@ -169,7 +167,7 @@ def _flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k, scale):
             pltpu.VMEM((block_q, 128), jnp.float32),   # running sum (lane-bcast)
             pltpu.VMEM((block_q, Dp), jnp.float32),    # output accumulator
         ],
-        interpret=_pick_interpret(),
+        interpret=platform.pallas_interpret(),
     )(q, k, v, kv_mask.astype(jnp.int32)[:, None, :])
     return out, lse[:, :, 0]
 

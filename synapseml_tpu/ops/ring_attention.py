@@ -277,15 +277,12 @@ def seq_parallel_shard_map(mesh_ctx, q, k, v, kv_mask, causal, seq_axis,
     qkv_spec = P(batch_axes or None, seq_axis, head, None)
     mask_spec = P(batch_axes or None, seq_axis)
     fn = fn_factory(n)
-    from ..parallel.collectives import compat_shard_map
-
-    mapped = compat_shard_map(
+    mapped = jax.shard_map(
         lambda q_, k_, v_, m_: fn(q_, k_, v_, kv_mask=m_),
-        mesh,
-        (qkv_spec, qkv_spec, qkv_spec, mask_spec),
-        qkv_spec,
-        check_vma=check_vma,
-    )
+        mesh=mesh,
+        in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
+        out_specs=qkv_spec,
+        check_vma=check_vma)
     if kv_mask is None:
         kv_mask = jnp.ones(q.shape[:2], bool)
     return mapped(q, k, v, kv_mask)

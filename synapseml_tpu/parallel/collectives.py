@@ -17,37 +17,8 @@ from jax.sharding import PartitionSpec as P
 
 from .mesh import MeshContext
 
-__all__ = ["compat_shard_map", "psum_over", "pmean_over", "all_gather_over",
+__all__ = ["psum_over", "pmean_over", "all_gather_over",
            "data_parallel_map", "ring_permute"]
-
-
-def compat_shard_map(fn, mesh, in_specs, out_specs,
-                     check_vma: bool | None = None):
-    """``shard_map`` across the jax range the framework supports: the
-    top-level ``jax.shard_map`` (with its ``check_vma`` kwarg when it
-    exists) on new versions, ``jax.experimental.shard_map`` (whose
-    equivalent knob is ``check_rep``) on older ones."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        try:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, **kw)
-        except TypeError:
-            # a jax.shard_map generation without the check_vma kwarg
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return legacy_shard_map(fn, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, **kw)
-
-
-def shard_map(fn, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """Backwards-compatible alias of :func:`compat_shard_map` (this module
-    historically re-exported the jax symbol)."""
-    return compat_shard_map(fn, mesh, in_specs, out_specs,
-                            check_vma=check_vma)
 
 
 def psum_over(mesh_ctx: MeshContext, axis: str | Sequence[str] = "data"):
@@ -61,7 +32,8 @@ def psum_over(mesh_ctx: MeshContext, axis: str | Sequence[str] = "data"):
 
 
 def _run_collective(mesh_ctx: MeshContext, fn, x):
-    sharded = shard_map(fn, mesh=mesh_ctx.mesh, in_specs=P(), out_specs=P(), check_vma=False)
+    sharded = jax.shard_map(fn, mesh=mesh_ctx.mesh, in_specs=P(), out_specs=P(),
+                            check_vma=False)
     return sharded(x)
 
 
@@ -77,7 +49,7 @@ def all_gather_over(mesh_ctx: MeshContext, axis: str = "data", tiled: bool = Tru
         return jax.lax.all_gather(x, axis, tiled=tiled)
 
     def run(x):
-        sharded = shard_map(inner, mesh=mesh_ctx.mesh,
+        sharded = jax.shard_map(inner, mesh=mesh_ctx.mesh,
                             in_specs=P(axis), out_specs=P(), check_vma=False)
         return sharded(x)
 
@@ -94,7 +66,7 @@ def ring_permute(mesh_ctx: MeshContext, axis: str = "seq", shift: int = 1):
         return jax.lax.ppermute(x, axis, perm)
 
     def run(x):
-        sharded = shard_map(inner, mesh=mesh_ctx.mesh,
+        sharded = jax.shard_map(inner, mesh=mesh_ctx.mesh,
                             in_specs=P(axis), out_specs=P(axis), check_vma=False)
         return sharded(x)
 

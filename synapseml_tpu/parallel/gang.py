@@ -728,8 +728,10 @@ def launch_gang_processes(script_path: str, world: int, *,
     import subprocess
     import sys
 
+    from ..core.platform import check_chip_launch
     from .backend import DriverRendezvous
 
+    check_chip_launch(int(world), os.environ if env is None else env)
     driver = DriverRendezvous(world_size=int(world), keep_alive=True)
     driver.start()
     addr = f"127.0.0.1:{driver.port}"

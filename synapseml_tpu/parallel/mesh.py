@@ -20,16 +20,19 @@ sharding annotations (GSPMD), we only name axes and place constraints.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
+import logging
 import math
-import os
 from typing import Any, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["MeshConfig", "MeshContext", "create_mesh", "batch_sharding", "replicated",
+__all__ = ["MeshConfig", "MeshContext", "create_mesh", "current_mesh",
+           "batch_sharding", "replicated",
            "logical_axis_rules", "shard_params", "shard_inference_params", "P"]
 
 AXES = ("data", "fsdp", "tensor", "seq", "expert", "pipe")
@@ -59,6 +62,11 @@ class MeshConfig:
         if math.prod(sizes.values()) != n_devices:
             raise ValueError(f"mesh {sizes} does not cover {n_devices} devices")
         return sizes
+
+
+# the MeshContext whose scope() is active in this thread (see current_mesh)
+_SCOPE: contextvars.ContextVar = contextvars.ContextVar(
+    "synapseml_tpu_mesh", default=None)
 
 
 class MeshContext:
@@ -108,12 +116,29 @@ class MeshContext:
         sh = self.sharding(None, ("data", "fsdp"))
         return jax.tree.map(lambda x: place_leaf(x, sh), batch)
 
-    def __enter__(self):
-        self._ctx = self.mesh.__enter__()
-        return self
+    @contextlib.contextmanager
+    def scope(self):
+        """Put this mesh in scope for everything traced inside:
+        :func:`current_mesh` returns it, which is how sequence-parallel
+        attention finds the mesh to ``shard_map`` over.
 
-    def __exit__(self, *exc):
-        return self.mesh.__exit__(*exc)
+        Framework-owned on purpose. ``jax.set_mesh`` would also switch on
+        flax's own param constraints, and flax 0.12.3 cannot trace those:
+        ``DenseGeneral`` makes its kernel at a flattened rank that the
+        kernel's 3-name partition spec cannot annotate, so every
+        ``module.apply`` under a set mesh raises. The legacy ``with mesh:``
+        is visible only through the deprecated ``pxla.thread_resources``."""
+        token = _SCOPE.set(self)
+        try:
+            yield self
+        finally:
+            _SCOPE.reset(token)
+
+
+def current_mesh() -> "MeshContext | None":
+    """The :class:`MeshContext` whose :meth:`~MeshContext.scope` is active in
+    this thread, or None."""
+    return _SCOPE.get()
 
 
 def create_mesh(config: MeshConfig | None = None, devices: Sequence[Any] | None = None,
@@ -132,12 +157,17 @@ def create_mesh(config: MeshConfig | None = None, devices: Sequence[Any] | None 
     n = len(devices)
     try:
         sizes = config.resolve(n)
-    except ValueError:
+    except ValueError as e:
         if not allow_fewer:
             raise
-        # degrade gracefully on smaller device counts (e.g. 1-chip CI)
+        # degrade to pure data-parallel on smaller device counts (e.g.
+        # 1-chip CI) — never silently: the caller asked for another layout
         sizes = {k: 1 for k in AXES}
         sizes["data"] = n
+        logging.getLogger(__name__).warning(
+            "mesh %s does not fit %d device(s) (%s); degrading to pure data "
+            "parallel %s — pass allow_fewer=False to make this an error",
+            config, n, e, {"data": n})
     shape = tuple(sizes[a] for a in AXES)
     arr = np.asarray(devices).reshape(shape)
     mesh = Mesh(arr, AXES)

@@ -35,43 +35,18 @@ import jax
 import jax.numpy as jnp
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """``jax.shard_map`` moved out of ``jax.experimental`` in newer
-    versions; the version-spanning shim lives in ``.collectives``."""
-    from .collectives import compat_shard_map
-
-    return compat_shard_map(fn, mesh, in_specs, out_specs)
-
 __all__ = ["pipeline_apply", "pipeline_apply_interleaved",
            "pipeline_apply_scattered", "pipeline_sharded",
            "stack_stage_params"]
 
 
-def _axis_size(axis_name):
-    """``jax.lax.axis_size`` is missing on older jax; ``psum(1, axis)``
-    constant-folds to the same static int inside shard_map."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def _pvary(x, axis_name):
-    """Mark x as varying over axis_name (vma typing); tolerate jax versions
-    where the API is pcast / pvary / absent, and values already varying
-    over the axis (pcast rejects varying->varying)."""
-    try:
-        if hasattr(jax.lax, "pcast"):
-            return jax.lax.pcast(x, axis_name, to="varying")
-        if hasattr(jax.lax, "pvary"):
-            return jax.lax.pvary(x, axis_name)
-    except ValueError as e:
-        # swallow only the already-varying case. pcast says "Unsupported
-        # pcast from=varying"; pvary phrases it "invariant->variant
-        # collective ... must not be present in jax.typeof(inp).vma"
-        msg = str(e)
-        if "varying" not in msg and "vma" not in msg:
-            raise
-    return x
+def _to_varying(x, axis_name):
+    """Type ``x`` as varying over ``axis_name`` (shard_map's vma typing).
+    ``pcast`` rejects varying -> varying, so a value that already varies —
+    ``zeros_like`` of a pipe-sharded input — passes through."""
+    if axis_name in jax.typeof(x).vma:
+        return x
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def stack_stage_params(stage_params_list):
@@ -88,7 +63,7 @@ def _stage_preamble(stage_fn, stacked_params, axis_name, remat):
         # recompute stage activations in the backward scan instead of saving
         # every tick's outputs — the GPipe memory trade
         stage_fn = jax.checkpoint(stage_fn)
-    n_stages = _axis_size(axis_name)
+    n_stages = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     shard = jax.tree.leaves(stacked_params)[0].shape[0]
     if shard != 1:
@@ -148,8 +123,8 @@ def pipeline_apply(stage_fn, stacked_params, x_micro, axis_name: str = "pipe",
     # the carry becomes pipe-VARYING inside the loop (ppermute/idx-dependent
     # writes); the init must carry the same varying-axes type or scan rejects
     # the carry under shard_map's vma checking
-    state0 = tmap(lambda xm: _pvary(jnp.zeros_like(xm[0]), axis_name), x_micro)
-    outs0 = tmap(lambda xm: _pvary(jnp.zeros_like(xm), axis_name), x_micro)
+    state0 = tmap(lambda xm: _to_varying(jnp.zeros_like(xm[0]), axis_name), x_micro)
+    outs0 = tmap(lambda xm: _to_varying(jnp.zeros_like(xm), axis_name), x_micro)
     (_, outs), _ = jax.lax.scan(tick, (state0, outs0),
                                 jnp.arange(n_ticks, dtype=jnp.int32))
     # only the last stage holds real outputs; zero elsewhere -> psum = bcast
@@ -215,9 +190,9 @@ def pipeline_apply_scattered(stage_fn, stacked_params, x_local,
         drain_m = jax.lax.ppermute(drain_m, axis_name, fwd)
         return (state, feed, drain, drain_m, outs), None
 
-    one = tmap(lambda xl: _pvary(jnp.zeros_like(xl[0]), axis_name), x_local)
-    outs0 = tmap(lambda xl: _pvary(jnp.zeros_like(xl), axis_name), x_local)
-    m0 = _pvary(jnp.int32(-1), axis_name)
+    one = tmap(lambda xl: _to_varying(jnp.zeros_like(xl[0]), axis_name), x_local)
+    outs0 = tmap(lambda xl: _to_varying(jnp.zeros_like(xl), axis_name), x_local)
+    m0 = _to_varying(jnp.int32(-1), axis_name)
     (_, _, _, _, outs), _ = jax.lax.scan(
         tick, (one, tmap(jnp.copy, one), tmap(jnp.copy, one), m0, outs0),
         jnp.arange(n_ticks, dtype=jnp.int32))
@@ -242,7 +217,7 @@ def pipeline_apply_interleaved(stage_fn, stacked_params, x_micro,
     """
     if remat:
         stage_fn = jax.checkpoint(stage_fn)
-    n_stages = _axis_size(axis_name)
+    n_stages = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     v = jax.tree.leaves(stacked_params)[0].shape[0]
     n_micro = jax.tree.leaves(x_micro)[0].shape[0]
@@ -279,8 +254,8 @@ def pipeline_apply_interleaved(stage_fn, stacked_params, x_micro,
         state = tmap(lambda yy: jax.lax.ppermute(yy, axis_name, fwd), y)
         return (state, outs), None
 
-    state0 = tmap(lambda xm: _pvary(jnp.zeros_like(xm[0]), axis_name), x_micro)
-    outs0 = tmap(lambda xm: _pvary(jnp.zeros_like(xm), axis_name), x_micro)
+    state0 = tmap(lambda xm: _to_varying(jnp.zeros_like(xm[0]), axis_name), x_micro)
+    outs0 = tmap(lambda xm: _to_varying(jnp.zeros_like(xm), axis_name), x_micro)
     (_, outs), _ = jax.lax.scan(tick, (state0, outs0),
                                 jnp.arange(n_ticks, dtype=jnp.int32))
     outs = tmap(lambda os: jnp.where(idx == 0, os, jnp.zeros_like(os)), outs)
@@ -375,9 +350,9 @@ def pipeline_sharded(mesh_ctx, stage_fn, stacked_params, x_micro,
         fn = functools.partial(pipeline_apply, stage_fn, axis_name=axis_name,
                                remat=remat)
         micro_spec = jax.tree.map(lambda _: P(), x_micro)
-    mapped = _shard_map(
-        fn, mesh,
-        (jax.tree.map(lambda _: P(axis_name), stacked_params), micro_spec),
-        micro_spec,
-    )
+    mapped = jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=(jax.tree.map(lambda _: P(axis_name), stacked_params),
+                  micro_spec),
+        out_specs=micro_spec)
     return mapped(stacked_params, x_micro)

@@ -126,14 +126,19 @@ def aot_mechanism() -> str | None:
         out = de.execute([jax.device_put(np.ones(2, np.float32))])
         if float(np.asarray(out[0])[0]) == 2.0:
             return "xla"
-    except Exception:  # noqa: BLE001 - any probe failure just demotes
-        pass
+    except Exception as e:  # noqa: BLE001 - any probe failure just demotes
+        # logged once (lru_cache): the next reader must see WHY the
+        # mechanism is "export" and loads compile again
+        logger.warning("aot_mechanism: serialize_executable probe failed, "
+                     "demoting to jax.export: %s: %s", type(e).__name__, e)
     try:
         from jax import export as jexport
 
         del jexport
         return "export"
-    except Exception:  # noqa: BLE001
+    except Exception as e:  # noqa: BLE001
+        logger.warning("aot_mechanism: jax.export unavailable, no AOT: "
+                     "%s: %s", type(e).__name__, e)
         return None
 
 

@@ -34,13 +34,12 @@ def retrieval_worker_main(registry_root: str, index: str,
     ``shards`` limits the advertised scoring responsibility (None = the
     full roster); ``refresh_s`` is the alias-watch poll interval (0
     disables the watch)."""
-    import jax
-
-    jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS", "cpu"))
+    from ..core.platform import enable_compile_cache
     from ..fleet.autoscaler import _post_json
     from ..fleet.residency import ResidencyManager, serve_multi_model
     from ..registry import ModelRegistry
 
+    enable_compile_cache()
     registry = ModelRegistry(registry_root)
     residency = ResidencyManager(registry, byte_budget, refs={index: ref})
     server = serve_multi_model(residency, port=port)

@@ -57,16 +57,21 @@ def test_entry_single_chip_compiles():
     assert "entry ok" in r.stdout
 
 
-def test_bench_cpu_smoke_emits_json():
+def test_chip_smoke_cpu_tiny_mode():
+    """chip_smoke.py end to end in its explicit small-CPU mode (named on the
+    command line, never inferred): the training, kernel and serving legs at
+    toy sizes, a last-line JSON that says ``cpu``. (That it REFUSES a CPU
+    without the flag is tier-1: tests/test_chip_paths.py.)"""
     import json
 
-    # flagship only: the full rotation (5 CPU-smoke configs) belongs to the
-    # driver's bench run, not the test lane
-    r = _run("import bench; bench.main()",
-             extra_env={"JAX_PLATFORMS": "cpu", "BENCH_CONFIGS": "flagship"},
-             timeout=600)
-    assert r.returncode == 0, r.stderr[-2000:]
-    line = [l for l in r.stdout.splitlines() if l.startswith("{")][-1]
-    payload = json.loads(line)
-    assert {"metric", "value", "unit", "vs_baseline"} <= set(payload)
-    assert payload["value"] > 0
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py"), "--cpu-tiny",
+         "--out", os.path.join(REPO, "chip_smoke_out", "test")],
+        cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+    last = json.loads(r.stdout.strip().splitlines()[-1])
+    assert last == {"ok": True, "mode": "cpu-tiny",
+                    "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
+    legs = [l for l in r.stdout.splitlines() if l.startswith("LEG ")]
+    assert [l.split()[1] for l in legs] == ["A", "B", "C"]

@@ -373,7 +373,7 @@ def test_llama2_7b_code_path_reduced_width():
                                     plain, mesh)
     B, P = 2, 8
     ids = jnp.asarray(np.random.default_rng(0).integers(0, 512, (B, P)), jnp.int32)
-    with mesh.mesh:
+    with mesh.scope():
         out = generate(model, placed, ids, 4, temperature=0.8, top_k=50,
                        top_p=0.9, rng=jax.random.PRNGKey(1))
     out = np.asarray(out)

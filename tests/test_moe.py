@@ -172,7 +172,7 @@ def test_moe_expert_parallel_matches_unsharded():
 
     mesh = create_mesh(MeshConfig(data=2, expert=4))
     placed = shard_params(variables, mesh)
-    with mesh.mesh:
+    with mesh.scope():
         out = jax.jit(lambda v, xx: block.apply(v, xx))(placed, x)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=1e-5)
 
@@ -190,7 +190,7 @@ def test_moe_scatter_expert_parallel_matches_unsharded():
 
     mesh = create_mesh(MeshConfig(data=2, expert=4))
     placed = shard_params(variables, mesh)
-    with mesh.mesh:
+    with mesh.scope():
         out = jax.jit(lambda v, xx: block.apply(v, xx))(placed, x)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=1e-5)
 

@@ -178,12 +178,12 @@ def test_encoder_attn_impls_agree():
                                np.asarray(out_flash)[valid], atol=2e-4)
 
     mesh = create_mesh(MeshConfig(data=2, seq=4))
-    with mesh.mesh:
+    with mesh.scope():
         out_ring = Encoder(dataclasses.replace(base, attn_impl="ring")).apply(variables, x, mask)
     np.testing.assert_allclose(np.asarray(out_einsum)[valid],
                                np.asarray(out_ring)[valid], atol=2e-4)
 
-    with mesh.mesh:  # n_heads=4 divides seq=4: ulysses eligible
+    with mesh.scope():  # n_heads=4 divides seq=4: ulysses eligible
         out_uly = Encoder(dataclasses.replace(base, attn_impl="ulysses")).apply(variables, x, mask)
     np.testing.assert_allclose(np.asarray(out_einsum)[valid],
                                np.asarray(out_uly)[valid], atol=2e-4)

@@ -342,7 +342,7 @@ def test_realistic_width_compiled_memory_divides_by_fsdp():
         state = tr.init_state(batch)
         step = jax.jit(tr._step_fn(), donate_argnums=(0,))
         placed = tr.mesh.shard_batch(batch)
-        with tr.mesh.mesh:
+        with tr.mesh.scope():
             compiled = step.lower(
                 state.as_dict() | {"batch_stats": None}, placed).compile()
         return compiled

@@ -116,15 +116,12 @@ def test_pipeline_inside_shard_map_direct():
     stacked = stack_stage_params(stages)
     x = jnp.asarray(np.random.default_rng(9).normal(size=(n_micro, mb, d)),
                     jnp.float32)
-    from synapseml_tpu.parallel.pipeline import _shard_map
-
     mesh = create_mesh(MeshConfig(data=1, pipe=8))
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         lambda p, xx: pipeline_apply(mlp_stage, p, xx),
-        mesh.mesh,
-        (jax.tree.map(lambda _: P("pipe"), stacked), P()),
-        P(),
-    )
+        mesh=mesh.mesh,
+        in_specs=(jax.tree.map(lambda _: P("pipe"), stacked), P()),
+        out_specs=P())
     np.testing.assert_allclose(np.asarray(mapped(stacked, x)),
                                np.asarray(sequential(stages, x)),
                                rtol=1e-5, atol=1e-6)
@@ -223,7 +220,7 @@ def test_pipeline_sharded_io_memory_scales_inverse_with_stages():
                     jnp.float32)
     mesh = create_mesh(MeshConfig(data=2, pipe=4))
     assert dict(mesh.mesh.shape)["pipe"] == n_stages  # not the seq fallback
-    with mesh.mesh:
+    with mesh.scope():
         out_s = jax.jit(lambda p, xx: pipeline_sharded(
             mesh, mlp_stage, p, xx, io="sharded"))(stacked, x)
         out_r = jax.jit(lambda p, xx: pipeline_sharded(

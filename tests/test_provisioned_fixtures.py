@@ -1,7 +1,7 @@
 """Fixture-gated parity tests — activate when driver-provisioned files appear.
 
-The container has no egress (see docs/BENCHMARKS.md "Real data, real weights,
-stock-engine interop"), so two reference-strength checks can't run on
+The container has no egress (see docs/BENCHMARKS.md "Accuracy gates"), so
+two reference-strength checks can't run on
 materials we can produce ourselves:
 
 1. stock-LightGBM interop (reference ``booster/LightGBMBooster.scala:458``
