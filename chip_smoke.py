@@ -36,6 +36,7 @@ import gc
 import json
 import math
 import os
+import resource
 import shutil
 import sys
 import threading
@@ -466,9 +467,10 @@ def llm_depth(cfg: dict, device) -> int:
     return int(max(2, min(32, (0.4 * limit - fixed) // per_layer)))
 
 
-def write_llama_checkpoint(path: str, cfg: dict, layers: int, seed: int) -> int:
-    """A seeded HF-format Llama checkpoint (config.json + one float32
-    safetensors shard per layer). Returns the parameter count."""
+def write_llama_checkpoint(path: str, cfg: dict, layers: int, seed: int):
+    """A seeded HF-format Llama checkpoint: config.json + float32 safetensors
+    shards, none larger than the token embedding (0.5 GB), since a machine
+    may refuse larger files. Returns (parameter count, largest file bytes)."""
     from concurrent.futures import ThreadPoolExecutor
 
     import numpy as np
@@ -489,37 +491,45 @@ def write_llama_checkpoint(path: str, cfg: dict, layers: int, seed: int) -> int:
         w *= 0.02
         return w
 
-    def shard(i):
-        if i == layers:
-            return {"model.embed_tokens.weight": tensor(1000, (V, H)),
-                    "lm_head.weight": tensor(1001, (V, H)),
-                    "model.norm.weight": np.ones(H, np.float32)}
+    ones = lambda: np.ones(H, np.float32)
+    shapes = {"self_attn.q_proj": (H, H), "self_attn.k_proj": (H, H),
+              "self_attn.v_proj": (H, H), "self_attn.o_proj": (H, H),
+              "mlp.gate_proj": (M, H), "mlp.up_proj": (M, H),
+              "mlp.down_proj": (H, M)}
+    wide = ("mlp.gate_proj", "mlp.up_proj")     # their own file per layer
+
+    def layer(i, names):
+        return {f"model.layers.{i}.{name}.weight": tensor(10 * i + j, shapes[name])
+                for j, name in enumerate(shapes) if name in names}
+
+    files = {"model-embed.safetensors": lambda: {
+                 "model.embed_tokens.weight": tensor(1000, (V, H))},
+             "model-head.safetensors": lambda: {
+                 "lm_head.weight": tensor(1001, (V, H)),
+                 "model.norm.weight": ones()}}
+    for i in range(layers):
         p = f"model.layers.{i}"
-        shapes = {"self_attn.q_proj": (H, H), "self_attn.k_proj": (H, H),
-                  "self_attn.v_proj": (H, H), "self_attn.o_proj": (H, H),
-                  "mlp.gate_proj": (M, H), "mlp.up_proj": (M, H),
-                  "mlp.down_proj": (H, M)}
-        out = {f"{p}.{name}.weight": tensor(10 * i + j, shape)
-               for j, (name, shape) in enumerate(shapes.items())}
-        out[f"{p}.input_layernorm.weight"] = np.ones(H, np.float32)
-        out[f"{p}.post_attention_layernorm.weight"] = np.ones(H, np.float32)
-        return out
+        files[f"model-{i:05d}-a.safetensors"] = lambda i=i, p=p: {
+            **layer(i, set(shapes) - set(wide)),
+            f"{p}.input_layernorm.weight": ones(),
+            f"{p}.post_attention_layernorm.weight": ones()}
+        files[f"model-{i:05d}-b.safetensors"] = lambda i=i: layer(i, wide)
 
-    weight_map, n_params = {}, 0
-
-    def write(i):
-        tensors = shard(i)
-        name = f"model-{i:05d}.safetensors"
+    def write(name):
+        tensors = files[name]()
         save_file(tensors, os.path.join(path, name))
-        return name, {k: int(v.size) for k, v in tensors.items()}
+        return (name, {k: int(v.size) for k, v in tensors.items()},
+                os.path.getsize(os.path.join(path, name)))
 
+    weight_map, n_params, largest = {}, 0, 0
     with ThreadPoolExecutor(max_workers=min(8, os.cpu_count() or 1)) as pool:
-        for name, sizes in pool.map(write, range(layers + 1)):
+        for name, sizes, nbytes in pool.map(write, files):
             weight_map.update({k: name for k in sizes})
             n_params += sum(sizes.values())
+            largest = max(largest, nbytes)
     with open(os.path.join(path, "model.safetensors.index.json"), "w") as f:
         json.dump({"weight_map": weight_map}, f)
-    return n_params
+    return n_params, largest
 
 
 def http_json(address: str, payload: dict, headers: dict | None = None,
@@ -557,11 +567,12 @@ def leg_c(leg: Leg) -> None:
     layers = llm_depth(cfg, ctx.devices[0])
     ckpt = os.path.join(leg.scratch, "llama")
     t0 = time.perf_counter()
-    n_params = write_llama_checkpoint(ckpt, cfg, layers, seed=3)
+    n_params, largest = write_llama_checkpoint(ckpt, cfg, layers, seed=3)
     leg.fact(widths=dict(hidden=cfg["hidden"], heads=cfg["heads"],
                          mlp_dim=cfg["mlp"], vocab=cfg["vocab"]),
              depth_cut=f"{layers} of 32 layers", max_len=cfg["max_len"],
              n_params=n_params, param_dtype="float32",
+             largest_checkpoint_file_bytes=largest,
              checkpoint_write_s=round(time.perf_counter() - t0, 1))
 
     tok = {"kind": "hashing", "vocab_size": cfg["vocab"], "lowercase": True,
@@ -858,6 +869,7 @@ def main(argv: list[str] | None = None) -> int:
         libtpu = "not installed"
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices)}
+    fsize = resource.getrlimit(resource.RLIMIT_FSIZE)[0]
     print("chip_smoke " + json.dumps({
         "device": device, "mode": "cpu-tiny" if args.cpu_tiny else "full",
         "jax": jax.__version__, "jaxlib": jaxlib.__version__, "libtpu": libtpu,
@@ -867,6 +879,8 @@ def main(argv: list[str] | None = None) -> int:
         "pci_tpu_chips": _visible_tpu_chips(),
         "tpu_env": {k: v for k, v in os.environ.items() if k.startswith("TPU_")},
         "disk_free_gb": round(shutil.disk_usage(args.out).free / 1e9, 1),
+        # the largest file written is Leg C's 0.5 GB embedding shard
+        "file_size_limit_bytes": None if fsize == resource.RLIM_INFINITY else fsize,
         **memory_facts(dev)}), flush=True)
 
     ctx = Ctx(TINY if args.cpu_tiny else FULL, devices, CompileMeter(),

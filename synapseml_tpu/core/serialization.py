@@ -57,13 +57,43 @@ def tree_structure(tree: Any) -> Any:
     return _tree_structure(tree)
 
 
-def save_pytree(tree: Any, path: str) -> None:
-    """Save a (possibly nested dict) pytree of arrays as one npz + structure JSON."""
-    flat = _flatten_pytree(tree)
-    np.savez(path + ".npz", **flat)
+# No file save_pytree writes grows past this unless one leaf alone is larger:
+# a process file-size limit (RLIMIT_FSIZE) or a filesystem's own maximum
+# refuses a multi-GB npz with EFBIG — BERT-base's params + Adam moments are
+# 1.3 GB — where several smaller files are accepted.
+MAX_FILE_BYTES = 512 << 20
+
+
+def save_pytree(tree: Any, path: str,
+                max_file_bytes: int | None = None) -> list[str]:
+    """Save a (possibly nested dict) pytree of arrays as ``path.npz`` +
+    structure JSON. Leaves beyond ``max_file_bytes`` (default
+    ``MAX_FILE_BYTES``) spill, in flatten order,
+    into ``path.part00001.npz``, ...; the structure's root records the file
+    count. Returns every path written (a checkpoint digests each one)."""
+    if max_file_bytes is None:
+        max_file_bytes = MAX_FILE_BYTES
+    groups, room = [{}], max_file_bytes
+    for name, leaf in _flatten_pytree(tree).items():
+        if groups[-1] and leaf.nbytes > room:
+            groups.append({})
+            room = max_file_bytes
+        groups[-1][name] = leaf
+        room -= leaf.nbytes
+    written = _part_paths(path, len(groups))
+    for target, group in zip(written, groups):
+        np.savez(target, **group)
     structure = _tree_structure(tree)
+    if len(groups) > 1:
+        structure["__parts__"] = len(groups)
     with open(path + ".tree.json", "w") as f:
         json.dump(structure, f)
+    return written + [path + ".tree.json"]
+
+
+def _part_paths(path: str, parts: int) -> list[str]:
+    return [path + ".npz"] + [f"{path}.part{i:05d}.npz"
+                              for i in range(1, parts)]
 
 
 def _tree_structure(tree: Any) -> Any:
@@ -94,10 +124,13 @@ def rebuild_pytree(structure: Any, flat: Any) -> Any:
 
 
 def load_pytree(path: str) -> Any:
-    data = np.load(path + ".npz", allow_pickle=False)
     with open(path + ".tree.json") as f:
         structure = json.load(f)
-    return rebuild_pytree(structure, data)
+    flat = {}
+    for part in _part_paths(path, structure.get("__parts__", 1)):
+        with np.load(part, allow_pickle=False) as data:
+            flat.update({name: data[name] for name in data.files})
+    return rebuild_pytree(structure, flat)
 
 
 def _is_array_pytree(v: Any) -> bool:

@@ -93,18 +93,20 @@ def save_checkpoint(path: str, tree: Any, step: int = 0, use_orbax: bool | None 
         ckptr = ocp.PyTreeCheckpointer()
         ckptr.save(os.path.join(target, "orbax"), host_tree, force=True)
     else:
-        serialization.save_pytree(host_tree, os.path.join(target, "state"))
+        payloads = serialization.save_pytree(host_tree,
+                                             os.path.join(target, "state"))
     if sharding:
         with open(os.path.join(target, "sharding.json"), "w") as f:
             json.dump(sharding, f, indent=2, sort_keys=True)
     if not use_orbax:
-        # sha256 sidecar per payload (npz AND the tree/sharding JSON —
-        # save_pytree writes both, and a torn tree.json would otherwise
-        # pass verification then die as an opaque JSONDecodeError),
-        # written BEFORE the DONE marker: restore verifies against them
-        # and demotes a torn step to the previous completed one
-        for payload in ("state.npz", "state.tree.json", "sharding.json"):
-            _write_digest_sidecar(os.path.join(target, payload))
+        # sha256 sidecar per payload (state.npz, the part files a large
+        # state spills into, AND the tree/sharding JSON — a torn tree.json
+        # would otherwise pass verification then die as an opaque
+        # JSONDecodeError), written BEFORE the DONE marker: restore
+        # verifies against them and demotes a torn step to the previous
+        # completed one
+        for payload in payloads + [os.path.join(target, "sharding.json")]:
+            _write_digest_sidecar(payload)
     with open(os.path.join(target, "DONE"), "w") as f:
         f.write(str(step))
     if keep is not None:

@@ -1,3 +1,5 @@
+import os
+
 import numpy as np
 import pytest
 
@@ -97,6 +99,36 @@ def test_checkpoint_roundtrip(tmp_path):
     np.testing.assert_allclose(restored["w"], tree["w"] + 1)
     restored3 = restore_checkpoint(str(tmp_path), step=3)
     np.testing.assert_allclose(restored3["opt"]["mu"], np.zeros(3))
+
+
+def test_checkpoint_spills_a_large_state_over_bounded_files(tmp_path, monkeypatch):
+    """A state beyond serialization.MAX_FILE_BYTES lands in several npz
+    files (a process file-size limit refused the one 1.3 GB file of
+    BERT-base), each digested, and restores as the same tree."""
+    from synapseml_tpu.core import serialization
+    from synapseml_tpu.parallel.checkpoint import (CheckpointCorrupt,
+                                                   verify_checkpoint)
+
+    monkeypatch.setattr(serialization, "MAX_FILE_BYTES", 100)
+    tree = {"params": {f"w{i}": np.full(16, i, np.float32) for i in range(4)},
+            "big": np.arange(64, dtype=np.float32),     # alone above the bound
+            "step": np.asarray(5)}
+    target = save_checkpoint(str(tmp_path), tree, step=5)
+    parts = sorted(n for n in os.listdir(target) if n.endswith(".npz"))
+    assert parts == ["state.npz"] + [f"state.part{i:05d}.npz" for i in (1, 2, 3, 4)]
+    for name in parts:              # only a leaf alone may pass the bound
+        with np.load(os.path.join(target, name)) as z:
+            assert len(z.files) == 1 or sum(z[k].nbytes for k in z.files) <= 100
+    assert all(os.path.isfile(os.path.join(target, n + ".sha256")) for n in parts)
+    restored = restore_checkpoint(str(tmp_path))
+    assert jax.tree.all(jax.tree.map(np.array_equal, restored, tree))
+    assert jax.tree.structure(restored) == jax.tree.structure(tree)
+
+    with open(os.path.join(target, parts[2]), "ab") as f:
+        f.write(b"x")
+    assert not verify_checkpoint(str(tmp_path), 5)
+    with pytest.raises(CheckpointCorrupt):
+        restore_checkpoint(str(tmp_path), step=5)
 
 
 def test_rendezvous_single_host():
