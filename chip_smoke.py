@@ -229,11 +229,25 @@ def auc(y, score) -> float:
     return float((ranks[y].sum() - n1 * (n1 + 1) / 2.0) / (n1 * n0))
 
 
-def sequence_len_trained(entry: dict, n_params: int) -> float:
-    """Tokens per sample the trainer saw, recovered from its own metrics:
-    model_tflops/s = 6 * params * tokens/s, over samples/s."""
-    return entry["model_tflops_per_sec"] * 1e12 / (
-        6.0 * n_params * entry["samples_per_sec"])
+def sequence_len_trained(batch: int) -> float:
+    """Tokens per sample the trainer placed on the device in this process's
+    last fit, from the fit loop's own spans: the bytes of its `train.place`
+    spans over the steps of its `train.dispatch` spans, per sample, less the
+    int32 label and the loader's float32 `_valid`, over the bytes a token takes
+    in the tokenizer's arrays."""
+    import numpy as np
+
+    from synapseml_tpu.core import observability as obs
+    from synapseml_tpu.models.tokenizer import resolve_tokenizer
+
+    spans = obs.get_tracer().finished_spans()
+    root = [s for s in spans if s.name == "train.fit"][-1]
+    kids = [s for s in spans if s.parent_id == root.span_id]
+    placed = sum(s.attributes["bytes"] for s in kids if s.name == "train.place")
+    steps = sum(s.attributes["steps"] for s in kids if s.name == "train.dispatch")
+    token_bytes = sum(np.asarray(v).dtype.itemsize
+                      for v in resolve_tokenizer(None)(["a"], max_len=8).values())
+    return (placed / (steps * batch) - 8) / token_bytes
 
 
 # ---------------------------------------------------------------------------
@@ -271,10 +285,9 @@ def leg_a(leg: Leg) -> None:
     entry = stage.get("train_metrics")[-1]
     leg.fact(n_params=n_params, steps=entry["step"],
              final_loss=round(entry["loss"], 4),
-             samples_per_sec_incl_compile=round(entry["samples_per_sec"], 1),
-             mfu_incl_compile=entry.get("mfu"))
+             samples_per_sec=round(entry["samples_per_sec"], 1))   # first dispatch left out
     leg.check(entry["step"] == s["steps"], f"trained {entry['step']} steps")
-    seq_seen = sequence_len_trained(entry, n_params)
+    seq_seen = sequence_len_trained(s["batch"])
     leg.fact(trained_batch=(s["batch"], round(seq_seen, 2)))
     leg.check(abs(seq_seen - s["seq"]) < 0.5,
               f"trainer saw T={seq_seen:.2f}, want {s['seq']}")
@@ -286,8 +299,10 @@ def leg_a(leg: Leg) -> None:
     leg.check(entry["loss"] < LN2 - 0.05,
               f"final loss {entry['loss']:.4f} did not fall below ln2")
     if ctx.on_tpu:
-        leg.check("mfu" in entry, "train_metrics carries no mfu: the peak "
-                  "table did not know this device")
+        from synapseml_tpu.core.instrumentation import chip_peak_tflops
+
+        # raises for a device the peak table does not know
+        leg.fact(chip_peak_tflops=chip_peak_tflops(ctx.devices[0].device_kind))
         # params + both Adam moments, f32, lived on the device
         peak = memory_facts(ctx.devices[0])["peak_bytes_in_use"]
         leg.check(peak >= 3 * 4 * n_params / len(ctx.devices),
@@ -392,9 +407,7 @@ def leg_b(leg: Leg) -> None:
     with leg.timed("flash_fit"):
         stage = est.fit(df)
     entry = stage.get("train_metrics")[-1]
-    n_params = sum(int(np.prod(np.shape(x)))
-                   for x in jax.tree.leaves(stage.get("model_params")))
-    seq_seen = sequence_len_trained(entry, n_params)
+    seq_seen = sequence_len_trained(f["fit_batch"])
     leg.fact(flash_fit_steps=entry["step"], flash_fit_loss=round(entry["loss"], 4),
              flash_fit_batch=(f["fit_batch"], round(seq_seen, 2)))
     leg.check(entry["step"] == f["fit_steps"] and np.isfinite(entry["loss"])

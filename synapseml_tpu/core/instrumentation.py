@@ -103,11 +103,25 @@ class InstrumentationMeasures:
 
 
 @contextlib.contextmanager
-def profile_trace(log_dir: str, host_tracer_level: int = 2) -> Iterator[None]:
+def profile_trace(log_dir: str, host_tracer_level: int = 0) -> Iterator[None]:
     """``jax.profiler.trace`` context: captures an XLA/TPU trace viewable in
     TensorBoard/Perfetto. The SURVEY §5 tracing-subsystem analog — wrap any
-    fit/transform/bench region."""
+    fit/transform/bench region.
+
+    ``host_tracer_level`` is the profiler's own (0 off, 1 ``TraceAnnotation``
+    spans, 2 and 3 more of the runtime's); the Python tracer stays off. The
+    default records the device's planes only: at level 1 or 2 the TPU
+    runtime adds a host span for every tile it transposes on the way to the
+    device, 3.2 million in one ViT-B/16 dispatch of 617 MB, which stalls that
+    dispatch by seconds and fills the tracer (PERF.md, section 6). The fit
+    loop's own spans (``train.*``) need no host tracer: they are kept by
+    ``core.observability`` on the trace's clock (``Span``), and
+    ``export_chrome_trace`` writes them to a timeline of their own."""
     import jax.profiler
 
-    with jax.profiler.trace(log_dir, create_perfetto_trace=False):
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = int(host_tracer_level)
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(log_dir, create_perfetto_trace=False,
+                            profiler_options=opts):
         yield

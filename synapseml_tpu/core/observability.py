@@ -593,11 +593,20 @@ def extract_context(headers) -> SpanContext | None:
 
 class Span:
     """One timed operation. ``end()`` freezes duration; finished spans land
-    in the tracer's ring buffer for export."""
+    in the tracer's ring buffer for export.
+
+    A span is on the profiler's clock. ``start_ns`` is ``time.time_ns()``
+    (Unix-epoch nanoseconds) and ``end_ns`` is ``start_ns`` plus the span's
+    monotonic duration. A ``jax.profiler`` trace counts every line's times,
+    the device's and the host's, in nanoseconds since the
+    ``profile_start_time`` stat of its ``Task Environment`` plane, which is
+    epoch nanoseconds too: a span's time in that trace is
+    ``start_ns - profile_start_time``, whether or not the profiler's host
+    tracer was on. ``start_wall`` is the same instant in seconds."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attributes",
-                 "start_wall", "_start_mono", "duration_ms", "status",
-                 "pid", "tid")
+                 "start_ns", "start_wall", "_start_mono", "duration_ms",
+                 "status", "pid", "tid")
 
     def __init__(self, name: str, trace_id: str, span_id: str,
                  parent_id: str | None, attributes: dict | None = None):
@@ -606,7 +615,8 @@ class Span:
         self.span_id = span_id
         self.parent_id = parent_id
         self.attributes = dict(attributes or {})
-        self.start_wall = time.time()
+        self.start_ns = time.time_ns()
+        self.start_wall = self.start_ns / 1e9
         self._start_mono = time.perf_counter()
         self.duration_ms: float | None = None
         self.status = "ok"
@@ -616,6 +626,13 @@ class Span:
     @property
     def context(self) -> SpanContext:
         return SpanContext(self.trace_id, self.span_id)
+
+    @property
+    def end_ns(self) -> int | None:
+        """Epoch nanoseconds at which the span ended; None while it runs."""
+        if self.duration_ms is None:
+            return None
+        return self.start_ns + int(self.duration_ms * 1e6)
 
     def set_attribute(self, key: str, value) -> None:
         self.attributes[key] = value
@@ -632,7 +649,7 @@ class Span:
         return {
             "name": self.name, "trace_id": self.trace_id,
             "span_id": self.span_id, "parent_id": self.parent_id,
-            "start_wall": self.start_wall,
+            "start_wall": self.start_wall, "start_ns": self.start_ns,
             "duration_ms": round(self.duration_ms or 0.0, 3),
             "status": self.status, "pid": self.pid, "tid": self.tid,
             "attributes": self.attributes,
@@ -692,14 +709,17 @@ class Tracer:
 
     def end_span(self, span: Span, error: BaseException | None = None) -> None:
         span.end(error)
-        stack = self._stack()
-        if span in stack:
-            # pop through (tolerates a leaked deeper span)
-            del stack[stack.index(span):]
+        self.discard_span(span)  # pops through: tolerates a leaked deeper span
         with self._lock:
             self._finished.append(span)
             if len(self._finished) > self._max_spans:
                 del self._finished[:len(self._finished) - self._max_spans]
+
+    def discard_span(self, span: Span) -> None:
+        """Take a started span off the thread's stack without recording it."""
+        stack = self._stack()
+        if span in stack:
+            del stack[stack.index(span):]
 
     @contextlib.contextmanager
     def span(self, name: str, attributes: dict | None = None,

@@ -16,6 +16,7 @@ Also covers the reference's fine-tuning semantics:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any, Callable, Iterator
@@ -26,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from ..core import observability as obs
 from ..parallel.mesh import MeshContext, logical_axis_rules
 
 __all__ = ["TrainerConfig", "Trainer", "cross_entropy_loss", "TrainState",
@@ -95,7 +97,85 @@ class TrainerConfig:
     nonfinite_action: str = "count"  # count | raise
 
 
-_GUARD_METRICS = None  # lazy obs.HandleCache for the non-finite guard
+# phases of the fit loop: a ``train.<phase>`` span each, and one series each of
+# synapseml_train_loop_ms
+_LOOP_PHASES = ("chunk_wait", "place", "dispatch", "fetch", "checkpoint",
+                "chunk_build")
+
+_TRAIN_METRICS = obs.HandleCache(lambda reg: {
+    "nonfinite": reg.counter(
+        "synapseml_train_nonfinite_total",
+        "optimizer steps whose loss was NaN/Inf", ("engine",)),
+    "last_finite": reg.gauge(
+        "synapseml_train_last_finite_step",
+        "newest optimizer step with a finite loss"),
+    "dispatches": reg.counter(
+        "synapseml_train_dispatches_total",
+        "calls of the jitted train step (scan: K optimizer steps a call)",
+        ("program",)),
+    "compiles": reg.counter(
+        "synapseml_train_step_compiles_total",
+        "dispatches across which the jitted step's cache grew (a new "
+        "signature: traced, then compiled or loaded)", ("program",)),
+    "loop_ms": {phase: reg.histogram(
+        "synapseml_train_loop_ms",
+        "host time of one boundary of the fit loop (the train.<phase> "
+        "span's duration)", ("phase",)).labels(phase=phase)
+        for phase in _LOOP_PHASES},
+    "step_ms": reg.histogram(
+        "synapseml_train_step_duration_ms",
+        "training step (boosting iteration / optimizer step) wall "
+        "time", ("engine",)).labels(engine="trainer"),
+    "samples_per_sec": reg.gauge(
+        "synapseml_train_samples_per_sec", "fit-loop throughput",
+        ("engine",)).labels(engine="trainer"),
+})
+
+
+class _LoopSpan:
+    """One boundary of the fit loop, timed once and kept twice: as a
+    ``core.observability`` span (always recorded; ``obs.Span`` says where its
+    times lie in a profiler trace) and as a ``jax.profiler.TraceAnnotation``
+    of the same name, which an operator's own profile shows when its host
+    tracer is on and which costs a flag check when it is not. The duration of
+    a ``train.<phase>`` span also feeds ``synapseml_train_loop_ms{phase}``.
+    ``keep = False`` inside the block leaves no span behind."""
+
+    __slots__ = ("span", "keep", "_start", "_ann", "_tracer")
+
+    def __init__(self, name: str, attributes: dict | None = None,
+                 parent: "obs.SpanContext | None" = None):
+        self._start = (name, attributes, parent)
+        self.keep = True
+
+    def __enter__(self) -> "_LoopSpan":
+        self._ann = jax.profiler.TraceAnnotation(self._start[0])
+        self._ann.__enter__()
+        self._tracer = obs.get_tracer()
+        self.span = self._tracer.start_span(*self._start)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self.keep:
+            self._tracer.end_span(self.span, error=exc)
+        else:
+            self._tracer.discard_span(self.span)
+        self._ann.__exit__(exc_type, exc, tb)
+        phase = self.span.name.removeprefix("train.")
+        if phase in _LOOP_PHASES and self.keep and exc is None:
+            _TRAIN_METRICS.get()["loop_ms"][phase].observe(self.span.duration_ms)
+
+
+def _tree_nbytes(tree) -> int:
+    return sum(int(getattr(x, "nbytes", 0)) for x in jax.tree.leaves(tree))
+
+
+def _jit_cache_size(fn) -> int:
+    """Entries of a jitted callable's signature cache; it grows when a call
+    traces (and compiles or loads) a new program. A private counter of
+    jax 0.9.0: where a later jax has none, no dispatch reads as compiled."""
+    size = getattr(fn, "_cache_size", None)
+    return size() if size is not None else 0
 
 
 class NonFiniteLossError(RuntimeError):
@@ -243,6 +323,9 @@ class Trainer:
         self.rules = rules or logical_axis_rules()
         self._loss_fn = loss_fn
         self._train_step = None
+        # inside fit: the loop's own count of the step the next dispatch
+        # trains from (train.dispatch's first_step); None outside
+        self._fit_step: int | None = None
         self._metrics: list[dict] = []
         # newest optimizer step whose loss was finite (post-step numbering,
         # comparable to checkpoint step numbers); -1 until the first loss
@@ -439,42 +522,79 @@ class Trainer:
         param_sh = getattr(self, "_param_shardings", None)
         opt_sh = getattr(self, "_opt_shardings", None)
 
+        # the scopes name the step's device ops whatever the module is called
+        # and whether or not a user loss_fn is set: ".../jvp(forward)/...",
+        # ".../transpose(jvp(forward))/..." (the backward pass),
+        # ".../optimizer/...", ".../step_metrics/...". Metadata only.
         def step_fn(state: dict, batch: dict) -> tuple[dict, dict]:
             def loss_of(params):
                 variables = {"params": params}
                 if state.get("batch_stats") is not None:
                     variables["batch_stats"] = state["batch_stats"]
-                if self._loss_fn is not None:
-                    loss = self._loss_fn(variables, batch)
-                    return loss, (None, {})
-                return self.default_loss(variables, batch, train=True)
+                with jax.named_scope("forward"):
+                    if self._loss_fn is not None:
+                        loss = self._loss_fn(variables, batch)
+                        return loss, (None, {})
+                    return self.default_loss(variables, batch, train=True)
 
             (loss, (_, new_vars)), grads = jax.value_and_grad(loss_of, has_aux=True)(
                 state["params"])
-            updates, new_opt = tx.update(grads, state["opt_state"], state["params"])
-            new_params = optax.apply_updates(state["params"], updates)
-            if param_sh is not None:
-                new_params = jax.lax.with_sharding_constraint(
-                    new_params, param_sh)
-            if opt_sh is not None:
-                new_opt = jax.lax.with_sharding_constraint(new_opt, opt_sh)
+            with jax.named_scope("optimizer"):
+                updates, new_opt = tx.update(grads, state["opt_state"], state["params"])
+                new_params = optax.apply_updates(state["params"], updates)
+                if param_sh is not None:
+                    new_params = jax.lax.with_sharding_constraint(
+                        new_params, param_sh)
+                if opt_sh is not None:
+                    new_opt = jax.lax.with_sharding_constraint(new_opt, opt_sh)
             new_state = {"params": new_params, "opt_state": new_opt,
                          "step": state["step"] + 1}
             if state.get("batch_stats") is not None:
                 new_state["batch_stats"] = new_vars.get("batch_stats", state["batch_stats"])
             else:
                 new_state["batch_stats"] = None
-            metrics = {"loss": loss.astype(jnp.float32),
-                       "grad_norm": optax.global_norm(grads).astype(jnp.float32)}
+            with jax.named_scope("step_metrics"):
+                grad_norm = optax.global_norm(grads).astype(jnp.float32)
+            metrics = {"loss": loss.astype(jnp.float32), "grad_norm": grad_norm}
             return new_state, metrics
 
         return step_fn
 
+    @contextlib.contextmanager
+    def _dispatching(self, program: str, fn, steps: int) -> Iterator[None]:
+        """The ``train.dispatch`` span around a call of the jitted step ``fn``.
+        The call returns when the program is enqueued, and holds the trace and
+        the compile when the signature is new (``compiled``). ``first_step``
+        is the fit loop's own count of the step this dispatch trains from:
+        None outside ``fit``, since reading ``state.step`` would wait for the
+        device.
+
+        The caller makes the call in its own frame, inside this ``with``: a
+        helper frame above it, or a dozen more locals in ``fit``, moved the
+        step's trace onto a slow alignment of CPython's 16 KiB frame-stack
+        chunks and cost a quarter of the trace and lowering time on the
+        chip's host (PERF.md section 6, PR 27;
+        ``perfbench/tools/chunk_shim.c`` counts it on any machine)."""
+        first = self._fit_step
+        grown_from = _jit_cache_size(fn)
+        with _LoopSpan("train.dispatch", {"program": program, "steps": steps,
+                                          "first_step": first}) as ls:
+            yield
+            compiled = _jit_cache_size(fn) > grown_from
+            ls.span.set_attribute("compiled", compiled)
+        if first is not None:
+            self._fit_step = first + steps
+        m = _TRAIN_METRICS.get()
+        m["dispatches"].inc(program=program)
+        if compiled:
+            m["compiles"].inc(program=program)
+
     def train_step(self, state: TrainState, batch: dict) -> tuple[TrainState, dict]:
         if self._train_step is None:
             self._train_step = jax.jit(self._step_fn(), donate_argnums=(0,))
-        placed = self.mesh.shard_batch(batch)
-        with self.mesh.scope():
+        with _LoopSpan("train.place", {"bytes": _tree_nbytes(batch)}):
+            placed = self.mesh.shard_batch(batch)
+        with self._dispatching("step", self._train_step, 1), self.mesh.scope():
             sd, metrics = self._train_step(state.as_dict() | {"batch_stats": state.batch_stats},
                                            placed)
         return TrainState(params=sd["params"], opt_state=sd["opt_state"], step=sd["step"],
@@ -494,30 +614,16 @@ class Trainer:
                 return jax.lax.scan(step_fn, sd, batches)
 
             self._scan_step = jax.jit(multi, donate_argnums=(0,))
-        placed = self.mesh.shard_stacked_batch(stacked_batches)
-        with self.mesh.scope():
+        with _LoopSpan("train.place", {"bytes": _tree_nbytes(stacked_batches)}):
+            placed = self.mesh.shard_stacked_batch(stacked_batches)
+        steps = int(np.shape(jax.tree.leaves(stacked_batches)[0])[0])
+        with self._dispatching("scan", self._scan_step, steps), self.mesh.scope():
             sd, metrics = self._scan_step(
                 state.as_dict() | {"batch_stats": state.batch_stats}, placed)
         return (TrainState(params=sd["params"], opt_state=sd["opt_state"], step=sd["step"],
                            batch_stats=sd.get("batch_stats")), metrics)
 
     # ---- non-finite loss guard ----
-    @staticmethod
-    def _guard_metrics():
-        global _GUARD_METRICS
-        from ..core import observability as obs
-
-        if _GUARD_METRICS is None:
-            _GUARD_METRICS = obs.HandleCache(lambda reg: {
-                "nonfinite": reg.counter(
-                    "synapseml_train_nonfinite_total",
-                    "optimizer steps whose loss was NaN/Inf", ("engine",)),
-                "last_finite": reg.gauge(
-                    "synapseml_train_last_finite_step",
-                    "newest optimizer step with a finite loss"),
-            })
-        return _GUARD_METRICS.get()
-
     def _observe_losses(self, losses, last_step: int) -> None:
         """Check host-side per-step losses ending at post-step number
         ``last_step``: advance ``last_finite_step``, count non-finite steps
@@ -528,7 +634,7 @@ class Trainer:
         if arr.size == 0:
             return
         finite = np.isfinite(arr)
-        m = self._guard_metrics()
+        m = _TRAIN_METRICS.get()
         if bool(finite.all()):
             self.last_finite_step = max(self.last_finite_step, int(last_step))
         else:
@@ -544,10 +650,6 @@ class Trainer:
         m["last_finite"].set(self.last_finite_step)
 
     # ---- loop ----
-    def _flops_per_token(self, params) -> int:
-        n_params = sum(int(np.prod(np.shape(x))) for x in jax.tree.leaves(params))
-        return 6 * n_params  # fwd + bwd matmul FLOPs per token estimate
-
     def fit(self, state: TrainState, batch_iter: Iterator[dict], max_steps: int,
             log_every: int = 50, callback: Callable[[int, dict], None] | None = None,
             scan_chunk: int = 8, checkpointer=None,
@@ -589,6 +691,13 @@ class Trainer:
         dance (train to the gang's sync step, force a checkpoint, ack,
         wait for the driver's commit) and raises :class:`~synapseml_tpu.
         parallel.gang.Preempted`. Forces the per-step path.
+
+        The loop times itself: every call records one ``train.fit`` root
+        span and a span at each boundary under it (``train.chunk_wait``,
+        ``train.place``, ``train.dispatch``, ``train.fetch``,
+        ``train.checkpoint``, ``train.chunk_build``), with
+        ``synapseml_train_loop_ms{phase}`` and the dispatch and compile
+        counters beside them (docs/OBSERVABILITY.md).
         """
         it = iter(batch_iter)
         if checkpointer is not None and 0 < checkpoint_every < scan_chunk:
@@ -596,82 +705,112 @@ class Trainer:
             # requested durability by shrinking the fused chunk
             scan_chunk = checkpoint_every
         ckpt_due = self._ckpt_writer(checkpointer, checkpoint_every)
-        if callback is not None or skip_fn is not None or scan_chunk <= 1 \
-                or max_steps <= 1 or gang is not None:
-            meter = _ThroughputMeter(self, state.params)
-            base = int(state.step)
-            # per-step host materialization of the loss blocks async
-            # dispatch — only the "raise" guard (the supervised continual
-            # path, which needs prompt NaN detection for its rewind) pays
-            # it; "count" mode samples the losses already pulled at the
-            # log windows, keeping the default path's overlap intact
-            eager_guard = self.cfg.nonfinite_action == "raise"
-            if gang is not None:
-                gang.heartbeat(base)  # alive before the first (slow) compile
-            sync_at: int | None = None
-            i = -1
-            for i in range(max_steps):
-                try:
-                    batch = next(it)  # never pull past max_steps batches
-                except StopIteration:
-                    i -= 1
-                    break
-                if skip_fn is not None and skip_fn(base + i):
-                    # consumed, not trained: the stream stays aligned with
-                    # the step counter, the params stay at the checkpoint
-                    state = dataclasses.replace(state,
-                                                step=state.step + 1)
-                    self._count_skipped()
-                    ckpt_due(state, i + 1)
+        per_step = (callback is not None or skip_fn is not None or scan_chunk <= 1
+                    or max_steps <= 1 or gang is not None)
+        base = int(state.step)
+        # the root of the loop's spans: train.chunk_wait, train.place,
+        # train.dispatch, train.fetch and train.checkpoint on this thread,
+        # train.chunk_build on the chunk producer's, all of one trace id
+        with _LoopSpan("train.fit", {"scan_chunk": 1 if per_step else scan_chunk,
+                                     "first_step": base}) as root:
+            self._fit_step = base
+            try:
+                if not per_step:
+                    state, steps_done = self._fit_chunked(
+                        state, it, max_steps, scan_chunk, log_every, ckpt_due,
+                        root.span.context)
                 else:
-                    state, metrics = self.train_step(state, batch)
-                    meter.observe(batch, steps=1)
-                    if eager_guard:
-                        self._observe_losses(
-                            [float(np.asarray(metrics["loss"]))],
-                            last_step=base + i + 1)
-                    if callback is not None:
-                        callback(i, metrics)
-                    if (i + 1) % log_every == 0:
-                        lf = float(metrics["loss"])
-                        if not eager_guard:
-                            self._observe_losses([lf],
-                                                 last_step=base + i + 1)
-                        self._metrics.append(meter.entry(lf))
-                    ckpt_due(state, i + 1)
-                if gang is not None:
-                    step_now = base + i + 1
-                    gang.heartbeat(step_now)
-                    if sync_at is None:
-                        v = gang.check(step_now)
-                        if v == "resize":
-                            from ..parallel.gang import GangAborted
+                    state, steps_done = self._fit_per_step(
+                        state, it, max_steps, log_every, callback, ckpt_due,
+                        checkpointer, skip_fn, gang)
+            finally:
+                self._fit_step = None
+            root.span.set_attribute("steps_done", steps_done)
+        return state
 
-                            raise GangAborted(
-                                "gang verdict: resize — a member failed; "
-                                "exit and resume from the last committed "
-                                "checkpoint")
-                        if isinstance(v, tuple):  # ("sync", S)
-                            sync_at = int(v[1])
-                    if sync_at is not None and step_now >= sync_at:
-                        # emergency coordinated checkpoint at the gang's
-                        # sync step: force the write, flush it, phase-2 ack
-                        from ..parallel.gang import GangAborted, Preempted
+    def _fit_per_step(self, state: TrainState, it: Iterator[dict],
+                      max_steps: int, log_every: int, callback, ckpt_due,
+                      checkpointer, skip_fn, gang) -> tuple[TrainState, int]:
+        base = self._fit_step
+        meter = _ThroughputMeter()
+        # per-step host materialization of the loss blocks async
+        # dispatch — only the "raise" guard (the supervised continual
+        # path, which needs prompt NaN detection for its rewind) pays
+        # it; "count" mode samples the losses already pulled at the
+        # log windows, keeping the default path's overlap intact
+        eager_guard = self.cfg.nonfinite_action == "raise"
+        if gang is not None:
+            gang.heartbeat(base)  # alive before the first (slow) compile
+        sync_at: int | None = None
+        i = -1
+        for i in range(max_steps):
+            try:
+                batch = next(it)  # never pull past max_steps batches
+            except StopIteration:
+                i -= 1
+                break
+            if skip_fn is not None and skip_fn(base + i):
+                # consumed, not trained: the stream stays aligned with
+                # the step counter, the params stay at the checkpoint
+                state = dataclasses.replace(state,
+                                            step=state.step + 1)
+                self._fit_step += 1
+                self._count_skipped()
+                ckpt_due(state, i + 1)
+            else:
+                state, metrics = self.train_step(state, batch)
+                # this loop fetches no loss a step: its cycle ends where
+                # the dispatch returns
+                meter.cycle(time.time_ns(), batch, steps=1)
+                if eager_guard:
+                    self._observe_losses(
+                        [self._fetch_loss(metrics)], last_step=base + i + 1)
+                if callback is not None:
+                    callback(i, metrics)
+                if (i + 1) % log_every == 0:
+                    lf = self._fetch_loss(metrics)
+                    if not eager_guard:
+                        self._observe_losses([lf],
+                                             last_step=base + i + 1)
+                    self._metrics.append(meter.entry(lf))
+                ckpt_due(state, i + 1)
+            if gang is not None:
+                step_now = base + i + 1
+                gang.heartbeat(step_now)
+                if sync_at is None:
+                    v = gang.check(step_now)
+                    if v == "resize":
+                        from ..parallel.gang import GangAborted
 
-                        ckpt_due(state, i + 1, final=True)
-                        if checkpointer is not None:
-                            checkpointer.wait()
-                        if checkpointer is not None \
-                                and gang.ack_and_wait_commit(step_now):
-                            raise Preempted(step_now)
                         raise GangAborted(
-                            "emergency checkpoint did not commit inside "
-                            "the grace window — resume from the last "
-                            "committed step")
-            ckpt_due(state, i + 1, final=True)
-            return state
-        return self._fit_chunked(state, it, max_steps, scan_chunk, log_every,
-                                 ckpt_due)
+                            "gang verdict: resize — a member failed; "
+                            "exit and resume from the last committed "
+                            "checkpoint")
+                    if isinstance(v, tuple):  # ("sync", S)
+                        sync_at = int(v[1])
+                if sync_at is not None and step_now >= sync_at:
+                    # emergency coordinated checkpoint at the gang's
+                    # sync step: force the write, flush it, phase-2 ack
+                    from ..parallel.gang import GangAborted, Preempted
+
+                    ckpt_due(state, i + 1, final=True)
+                    if checkpointer is not None:
+                        checkpointer.wait()
+                    if checkpointer is not None \
+                            and gang.ack_and_wait_commit(step_now):
+                        raise Preempted(step_now)
+                    raise GangAborted(
+                        "emergency checkpoint did not commit inside "
+                        "the grace window — resume from the last "
+                        "committed step")
+        ckpt_due(state, i + 1, final=True)
+        return state, i + 1
+
+    @staticmethod
+    def _fetch_loss(metrics: dict) -> float:
+        """A single step's loss on the host: blocks until the device has it."""
+        with _LoopSpan("train.fetch"):
+            return float(np.asarray(metrics["loss"]))
 
     @staticmethod
     def _count_skipped() -> None:
@@ -692,14 +831,16 @@ class Trainer:
             if final or (every > 0 and steps_done - last[0] >= every):
                 if final and last[0] == steps_done:
                     return  # already saved at exactly this step
-                checkpointer.save(state.as_dict(), step=int(state.step))
+                step = int(state.step)
+                with _LoopSpan("train.checkpoint", {"step": step}):
+                    checkpointer.save(state.as_dict(), step=step)
                 last[0] = steps_done
 
         return due
 
     def _fit_chunked(self, state: TrainState, it: Iterator[dict],
-                     max_steps: int, scan_chunk: int,
-                     log_every: int = 50, ckpt_due=None) -> TrainState:
+                     max_steps: int, scan_chunk: int, log_every: int,
+                     ckpt_due, root: "obs.SpanContext") -> tuple[TrainState, int]:
         import queue
         import threading
 
@@ -724,51 +865,73 @@ class Trainer:
                     continue
             return False
 
+        taken = 0
+
+        def gather(group: list) -> tuple[dict | None, bool, float]:
+            """Fill ``group`` with consecutive same-shape batches up to
+            ``scan_chunk``. Returns the batch of another shape that ended it
+            early (else None), whether the stream may hold more, and the
+            seconds spent inside ``next(it)``."""
+            nonlocal taken
+            key = shape_key(group[0]) if group else None
+            next_s = 0.0
+            while len(group) < scan_chunk and taken < max_steps:
+                t0 = time.perf_counter()
+                try:
+                    b = next(it)
+                except StopIteration:
+                    return None, False, next_s + time.perf_counter() - t0
+                next_s += time.perf_counter() - t0
+                taken += 1
+                if key is None:
+                    key = shape_key(b)
+                elif shape_key(b) != key:
+                    return b, True, next_s
+                group.append(b)
+            return None, taken < max_steps, next_s
+
         def producer():
             try:
-                pending: list[dict] = []
-                pkey = None
-                taken = 0
-
-                def flush() -> bool:
-                    nonlocal pending, pkey
-                    if not pending:
-                        return True
-                    if len(pending) == scan_chunk:
-                        item = ("chunk", {k: np.stack([b[k] for b in pending])
-                                          for k in pending[0]})
-                    else:  # short/odd tail: per-step, no extra scan compile
-                        item = ("steps", pending)
-                    pending, pkey = [], None
-                    return put(item)
-
-                while taken < max_steps:
-                    try:
-                        b = next(it)
-                    except StopIteration:
-                        break
-                    key = shape_key(b)
-                    if pending and key != pkey:
-                        if not flush():
-                            return
-                    pending.append(b)
-                    pkey = key
-                    taken += 1
-                    if len(pending) == scan_chunk:
-                        if not flush():
-                            return
-                if flush():
-                    put(END)
+                carry, more = None, True
+                while more or carry is not None:
+                    # one span a chunk put on the queue, under the fit's root
+                    # (the tracer's stack is per thread)
+                    with _LoopSpan("train.chunk_build", parent=root) as ls:
+                        group = [] if carry is None else [carry]
+                        carry, more, next_s = gather(group)
+                        if not group:  # the stream ended on a chunk boundary
+                            ls.keep = False
+                            break
+                        t0 = time.perf_counter()
+                        steps = len(group)
+                        if steps == scan_chunk:
+                            item = ("chunk", {k: np.stack([b[k] for b in group])
+                                              for k in group[0]})
+                        else:  # short/odd tail: per-step, no extra scan compile
+                            item = ("steps", group)
+                        del group  # not held through the wait on a full queue
+                        t1 = time.perf_counter()
+                        ls.span.attributes.update(
+                            next_ms=next_s * 1e3, stack_ms=(t1 - t0) * 1e3,
+                            bytes=_tree_nbytes(item[1]), steps=steps)
+                        sent = put(item)
+                        del item
+                        ls.span.set_attribute(
+                            "put_wait_ms", (time.perf_counter() - t1) * 1e3)
+                    if not sent:
+                        return
+                put(END)
             except BaseException as e:  # surface producer errors
                 put(e)
 
         threading.Thread(target=producer, daemon=True).start()
-        meter = _ThroughputMeter(self, state.params)
+        meter = _ThroughputMeter()
         steps_done = logged_at = 0
-        base = int(state.step)
+        base = self._fit_step
         try:
             while True:
-                item = q.get()
+                with _LoopSpan("train.chunk_wait"):
+                    item = q.get()
                 if item is END:
                     break
                 if isinstance(item, BaseException):
@@ -776,29 +939,29 @@ class Trainer:
                 kind, payload = item
                 if kind == "chunk":
                     state, metrics = self.train_steps_scan(state, payload)
-                    meter.observe(payload, steps=scan_chunk)
+                    with _LoopSpan("train.fetch") as fetch:
+                        losses = np.asarray(metrics["loss"])
+                    meter.cycle(fetch.span.end_ns, payload, steps=scan_chunk)
                     steps_done += scan_chunk
-                    losses = np.asarray(metrics["loss"])
                     loss = float(losses[-1])
                 else:
                     losses = []
                     for b in payload:
                         state, metrics = self.train_step(state, b)
-                        meter.observe(b, steps=1)
-                        losses.append(float(np.asarray(metrics["loss"])))
+                        with _LoopSpan("train.fetch") as fetch:
+                            losses.append(float(np.asarray(metrics["loss"])))
+                        meter.cycle(fetch.span.end_ns, b, steps=1)
                     steps_done += len(payload)
                     loss = losses[-1]
                 self._observe_losses(losses, last_step=base + steps_done)
                 if steps_done - logged_at >= log_every or steps_done >= max_steps:
                     self._metrics.append(meter.entry(loss))
                     logged_at = steps_done
-                if ckpt_due is not None:
-                    ckpt_due(state, steps_done)
-            if ckpt_due is not None:
-                ckpt_due(state, steps_done, final=True)
+                ckpt_due(state, steps_done)
+            ckpt_due(state, steps_done, final=True)
         finally:
             stop.set()
-        return state
+        return state, steps_done
 
     @property
     def metrics(self) -> list[dict]:
@@ -806,72 +969,44 @@ class Trainer:
 
 
 class _ThroughputMeter:
-    """Shared samples/sec + 6ND TFLOP/s + MFU accounting for both the
-    per-step and scan-chunked fit loops. Tokens come from the ``input_ids``
-    tensor only — the estimate is meaningless for pixel inputs."""
+    """samples/sec and step time of one fit, counted in dispatch cycles. A
+    cycle ends where the loop has a dispatch's losses back (``train.fetch``'s
+    end) or, in the per-step loop, where the dispatch call returns. The clock
+    starts at the END of the first cycle, so the first dispatch's trace and
+    compile are in no rate; every later cycle's time over its steps is one
+    observation of ``synapseml_train_step_duration_ms``. Utilisation is not
+    this meter's to give: it needs the model's FLOPs from its shapes
+    (``perfbench/flops/`` and the benchmark's ``step_mfu``)."""
 
-    def __init__(self, trainer: "Trainer", params):
-        from ..core.instrumentation import chip_peak_tflops
-
-        self.t0 = time.perf_counter()
+    def __init__(self):
         self.steps = 0
-        self.n_samples = 0
-        self.n_tokens = 0
-        self.flops_per_token = trainer._flops_per_token(params)
-        # on a TPU the peak table must know the device (it raises
-        # otherwise): an MFU that silently disappears hides the device
-        dev = jax.devices()[0]
-        self.peak = (chip_peak_tflops(dev.device_kind)
-                     if dev.platform == "tpu" else None)
-        self._last_t = self.t0
-        self._last_steps = 0
+        self.n_samples = 0          # of the cycles after the first
+        self._first_ns: int | None = None
+        self._last_ns = 0
 
-    def observe(self, batch: dict, steps: int) -> None:
+    def cycle(self, end_ns: int, batch: dict, steps: int) -> None:
         """``batch`` leaves are (B, ...) when steps==1, (K, B, ...) stacked
-        when steps==K."""
+        when steps==K; ``end_ns`` is on ``obs.Span``'s clock."""
         self.steps += steps
+        if self._first_ns is None:
+            self._first_ns = self._last_ns = end_ns
+            return
         first = np.shape(next(iter(batch.values())))
         self.n_samples += int(np.prod(first[: (2 if steps > 1 else 1)]))
-        ids = batch.get("input_ids")
-        if ids is not None:
-            self.n_tokens += int(np.prod(np.shape(ids)))
+        if end_ns > self._last_ns:
+            _TRAIN_METRICS.get()["step_ms"].observe(
+                (end_ns - self._last_ns) / 1e6 / steps)
+            self._last_ns = end_ns
 
     def entry(self, loss: float) -> dict:
-        dt = time.perf_counter() - self.t0
-        out = {"step": self.steps, "loss": loss,
-               "samples_per_sec": self.n_samples / dt}
-        if self.n_tokens:
-            out["model_tflops_per_sec"] = (self.flops_per_token * self.n_tokens
-                                           / dt / 1e12)
-            if self.peak:
-                out["mfu"] = round(out["model_tflops_per_sec"]
-                                   / jax.device_count() / self.peak, 4)
-        self._export(out)
+        """One line of ``Trainer.metrics``; ``samples_per_sec`` once a cycle
+        after the first has ended."""
+        out = {"step": self.steps, "loss": loss}
+        if self._first_ns is not None and self._last_ns > self._first_ns:
+            out["samples_per_sec"] = (self.n_samples * 1e9
+                                      / (self._last_ns - self._first_ns))
+            _TRAIN_METRICS.get()["samples_per_sec"].set(out["samples_per_sec"])
         return out
-
-    def _export(self, out: dict) -> None:
-        """Push each logging window onto the unified metrics plane: the
-        window-average step time feeds the step histogram (p50/p95/p99 over
-        the whole fit), MFU/throughput land as gauges."""
-        from ..core import observability as obs
-
-        now = time.perf_counter()
-        dsteps = self.steps - self._last_steps
-        reg = obs.get_registry()
-        if dsteps > 0:
-            reg.histogram(
-                "synapseml_train_step_duration_ms",
-                "training step (boosting iteration / optimizer step) wall "
-                "time", ("engine",),
-            ).observe((now - self._last_t) * 1e3 / dsteps, engine="trainer")
-        self._last_t, self._last_steps = now, self.steps
-        reg.gauge("synapseml_train_samples_per_sec",
-                  "fit-loop throughput", ("engine",)
-                  ).set(out["samples_per_sec"], engine="trainer")
-        if "mfu" in out:
-            reg.gauge("synapseml_train_mfu",
-                      "model FLOPs utilization vs chip_peak_tflops",
-                      ("engine",)).set(out["mfu"], engine="trainer")
 
 
 def plan_fit(n: int, batch_size: int, epochs: int, max_steps: int) -> tuple[int, int]:
