@@ -170,6 +170,25 @@ def _tree_nbytes(tree) -> int:
     return sum(int(getattr(x, "nbytes", 0)) for x in jax.tree.leaves(tree))
 
 
+def _host_leaves(tree) -> list:
+    """The leaves a placement still has to move: those not yet ``jax.Array``s."""
+    return [x for x in jax.tree.leaves(tree) if not isinstance(x, jax.Array)]
+
+
+def _place_attrs(batch) -> dict:
+    """``train.place``'s attributes where a dispatch places its own input:
+    the bytes of the leaves that still are host arrays, and ``ahead``, true
+    where none is (the fit's chunk producer, or a loader's ``place_fn``, put
+    the input on the device before the loop asked for it)."""
+    host = _host_leaves(batch)
+    return {"bytes": _tree_nbytes(host), "ahead": not host}
+
+
+def _stack_steps(batches: list) -> dict:
+    """K same-shape batches -> one pytree whose leaves lead with K."""
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *batches)
+
+
 def _jit_cache_size(fn) -> int:
     """Entries of a jitted callable's signature cache; it grows when a call
     traces (and compiles or loads) a new program. A private counter of
@@ -323,6 +342,9 @@ class Trainer:
         self.rules = rules or logical_axis_rules()
         self._loss_fn = loss_fn
         self._train_step = None
+        self._scan_step = None
+        # K device-resident batches -> the [K, B, ...] chunk (_fit_chunked)
+        self._stack_chunk = None
         # inside fit: the loop's own count of the step the next dispatch
         # trains from (train.dispatch's first_step); None outside
         self._fit_step: int | None = None
@@ -592,7 +614,7 @@ class Trainer:
     def train_step(self, state: TrainState, batch: dict) -> tuple[TrainState, dict]:
         if self._train_step is None:
             self._train_step = jax.jit(self._step_fn(), donate_argnums=(0,))
-        with _LoopSpan("train.place", {"bytes": _tree_nbytes(batch)}):
+        with _LoopSpan("train.place", _place_attrs(batch)):
             placed = self.mesh.shard_batch(batch)
         with self._dispatching("step", self._train_step, 1), self.mesh.scope():
             sd, metrics = self._train_step(state.as_dict() | {"batch_stats": state.batch_stats},
@@ -607,14 +629,14 @@ class Trainer:
     def train_steps_scan(self, state: TrainState, stacked_batches: dict
                          ) -> tuple[TrainState, dict]:
         """stacked_batches: pytree whose leaves have leading dim K (num steps)."""
-        if getattr(self, "_scan_step", None) is None:
+        if self._scan_step is None:
             step_fn = self._step_fn()
 
             def multi(sd: dict, batches: dict):
                 return jax.lax.scan(step_fn, sd, batches)
 
             self._scan_step = jax.jit(multi, donate_argnums=(0,))
-        with _LoopSpan("train.place", {"bytes": _tree_nbytes(stacked_batches)}):
+        with _LoopSpan("train.place", _place_attrs(stacked_batches)):
             placed = self.mesh.shard_stacked_batch(stacked_batches)
         steps = int(np.shape(jax.tree.leaves(stacked_batches)[0])[0])
         with self._dispatching("scan", self._scan_step, steps), self.mesh.scope():
@@ -658,13 +680,20 @@ class Trainer:
             gang=None) -> TrainState:
         """Streaming fit over ANY batch iterator.
 
-        Default path: ``scan_chunk`` same-shape batches are stacked into ONE
-        ``lax.scan`` dispatch while a background thread prefetches the next
-        chunk (double buffering) — the DataFrame/streaming plane gets the same
-        dispatch amortization as array training. Odd-shaped or leftover
-        batches run per-step automatically, so iterators with varying batch
-        shapes stay correct (each shape still compiles once). A per-step
-        ``callback`` (or ``scan_chunk<=1``) forces the per-step loop.
+        Default path: ``scan_chunk`` same-shape batches train in ONE
+        ``lax.scan`` dispatch — the DataFrame/streaming plane gets the same
+        dispatch amortization as array training. A background thread pulls
+        the batches of the next chunks and places each on the mesh
+        (``mesh.shard_batch``), so a chunk is device-resident before the loop
+        asks for it; the loop stacks it there with one small jitted program
+        and hands ``train_steps_scan`` the ``[K, B, ...]`` device arrays. No
+        host array of the chunk's size is made. At most two chunks wait on
+        the device beside the one that runs; the producer is never more than
+        three chunks of batches ahead of the trained step. Odd-shaped or
+        leftover batches run per-step automatically, as host batches, so
+        iterators with varying batch shapes stay correct (each shape still
+        compiles once). A per-step ``callback`` (or ``scan_chunk<=1``) forces
+        the per-step loop.
 
         ``checkpointer`` (a ``parallel.AsyncCheckpointer``) +
         ``checkpoint_every``: full train state (params/opt_state/step/
@@ -695,7 +724,8 @@ class Trainer:
         The loop times itself: every call records one ``train.fit`` root
         span and a span at each boundary under it (``train.chunk_wait``,
         ``train.place``, ``train.dispatch``, ``train.fetch``,
-        ``train.checkpoint``, ``train.chunk_build``), with
+        ``train.checkpoint``; from the chunk producer's thread
+        ``train.chunk_build`` and the ``train.place`` of each chunk), with
         ``synapseml_train_loop_ms{phase}`` and the dispatch and compile
         counters beside them (docs/OBSERVABILITY.md).
         """
@@ -710,7 +740,8 @@ class Trainer:
         base = int(state.step)
         # the root of the loop's spans: train.chunk_wait, train.place,
         # train.dispatch, train.fetch and train.checkpoint on this thread,
-        # train.chunk_build on the chunk producer's, all of one trace id
+        # train.chunk_build and a chunk's train.place on the chunk
+        # producer's, all of one trace id
         with _LoopSpan("train.fit", {"scan_chunk": 1 if per_step else scan_chunk,
                                      "first_step": base}) as root:
             self._fit_step = base
@@ -845,8 +876,16 @@ class Trainer:
         import threading
 
         END = object()
-        q: "queue.Queue" = queue.Queue(maxsize=2)  # double buffer
+        # one chunk queued and one in the producer's hand: at most two chunks
+        # wait on the device beside the one that runs, whatever a chunk's size
+        q: "queue.Queue" = queue.Queue(maxsize=1)
         stop = threading.Event()  # consumer died: unblock the producer
+        # set while the loop waits for the device (and before its first
+        # program): the producer starts a chunk only then. Between two
+        # programs the loop alone runs Python, so the producer's gather and
+        # placing cannot take the interpreter lock from it while the chip idles
+        device_busy = threading.Event()
+        device_busy.set()
 
         def shape_key(b: dict):
             # dtype via attribute lookup: np.asarray on a jax.Array would
@@ -894,6 +933,9 @@ class Trainer:
             try:
                 carry, more = None, True
                 while more or carry is not None:
+                    while not device_busy.wait(timeout=0.5):
+                        if stop.is_set():
+                            return
                     # one span a chunk put on the queue, under the fit's root
                     # (the tracer's stack is per thread)
                     with _LoopSpan("train.chunk_build", parent=root) as ls:
@@ -905,8 +947,15 @@ class Trainer:
                         t0 = time.perf_counter()
                         steps = len(group)
                         if steps == scan_chunk:
-                            item = ("chunk", {k: np.stack([b[k] for b in group])
-                                              for k in group[0]})
+                            # on its way to the device from here: the copy
+                            # runs on the runtime's threads while the chip
+                            # computes the chunk before. Only transfers leave
+                            # this thread; every program is the main thread's
+                            with _LoopSpan("train.place",
+                                           {"bytes": _tree_nbytes(_host_leaves(group))},
+                                           parent=root):
+                                item = ("chunk", [self.mesh.shard_batch(b)
+                                                  for b in group])
                         else:  # short/odd tail: per-step, no extra scan compile
                             item = ("steps", group)
                         del group  # not held through the wait on a full queue
@@ -924,6 +973,11 @@ class Trainer:
             except BaseException as e:  # surface producer errors
                 put(e)
 
+        if self._stack_chunk is None:
+            # one local stack a device, no collective: the pieces and the
+            # chunk are sharded over the same axes of the batch dim
+            self._stack_chunk = jax.jit(
+                _stack_steps, out_shardings=self.mesh.stacked_batch_sharding())
         threading.Thread(target=producer, daemon=True).start()
         meter = _ThroughputMeter()
         steps_done = logged_at = 0
@@ -932,19 +986,27 @@ class Trainer:
             while True:
                 with _LoopSpan("train.chunk_wait"):
                     item = q.get()
+                    # the get lets the producer go on: hold it before it has
+                    # the interpreter lock again (if it wins that race, it
+                    # builds beside this dispatch, which costs time, no more)
+                    device_busy.clear()
                 if item is END:
                     break
                 if isinstance(item, BaseException):
                     raise item
                 kind, payload = item
+                del item  # the pieces of a chunk live no longer than its stack
                 if kind == "chunk":
+                    payload = self._stack_chunk(payload)
                     state, metrics = self.train_steps_scan(state, payload)
+                    device_busy.set()
                     with _LoopSpan("train.fetch") as fetch:
                         losses = np.asarray(metrics["loss"])
                     meter.cycle(fetch.span.end_ns, payload, steps=scan_chunk)
                     steps_done += scan_chunk
                     loss = float(losses[-1])
                 else:
+                    device_busy.set()  # a tail: nothing left to keep clear of
                     losses = []
                     for b in payload:
                         state, metrics = self.train_step(state, b)
@@ -1094,8 +1156,9 @@ def fit_source(trainer: "Trainer", source, *, batch_size: int, total_steps: int,
     runs ``total_steps - N`` further steps. ``device_prefetch`` places the
     next batch on the mesh inside the prefetch thread (double-buffered
     ``jax.device_put``) — only engaged on the per-step path
-    (``scan_chunk<=1``); the chunked scan path stacks on host and already
-    overlaps assembly with device compute.
+    (``scan_chunk<=1``); the chunked scan path needs no flag: its chunk
+    producer places every batch of a chunk ahead of the dispatch and the
+    chunk is stacked on the device (``Trainer.fit``).
 
     ``host_index``/``host_count`` default to 0/1 — ONE logical stream,
     identical on every process, because ``mesh.shard_batch`` expects each

@@ -92,6 +92,11 @@ class MeshContext:
         """Shard leading (batch) dim over every data-like axis."""
         return self.sharding(("data", "fsdp"))
 
+    def stacked_batch_sharding(self) -> NamedSharding:
+        """[K, batch, ...] step-stacked arrays: K replicated (the scan axis),
+        the batch dim sharded as ``batch_sharding`` shards it."""
+        return self.sharding(None, ("data", "fsdp"))
+
     def replicated(self) -> NamedSharding:
         return self.sharding()
 
@@ -113,7 +118,7 @@ class MeshContext:
         batch dim sharded over the data axes."""
         from .partition import place_leaf
 
-        sh = self.sharding(None, ("data", "fsdp"))
+        sh = self.stacked_batch_sharding()
         return jax.tree.map(lambda x: place_leaf(x, sh), batch)
 
     @contextlib.contextmanager

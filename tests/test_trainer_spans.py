@@ -81,8 +81,21 @@ def test_one_root_a_fit(chunked):
                                    "steps_done": CHUNK * DISPATCHES}
 
 
+# a chunked dispatch has two `train.place` spans: the producer's, which moves the
+# chunk's bytes before the loop asks for it, and the dispatch's own, which then
+# finds nothing left to move
+PLACES = 2 * DISPATCHES
+
+
+def _places(kids, main_tid):
+    """(the producer's `train.place` spans, the dispatches' own), by start."""
+    ahead = [s for s in kids["train.place"] if s.tid != main_tid]
+    own = [s for s in kids["train.place"] if s.tid == main_tid]
+    return ahead, own
+
+
 @pytest.mark.parametrize("name,count", [
-    ("train.dispatch", DISPATCHES), ("train.place", DISPATCHES),
+    ("train.dispatch", DISPATCHES), ("train.place", PLACES),
     ("train.fetch", DISPATCHES), ("train.chunk_wait", DISPATCHES + 1),
     ("train.chunk_build", DISPATCHES)])
 def test_children_of_the_root(chunked, name, count):
@@ -105,9 +118,37 @@ def test_dispatch_spans_count_steps(chunked):
 def test_place_counts_the_chunk_bytes(chunked):
     per_chunk = CHUNK * sum(v.nbytes for v in _batch().values())
     kids = _children(chunked["spans"], chunked["root"])
-    assert [s.attributes["bytes"] for s in kids["train.place"]] == [per_chunk] * DISPATCHES
+    ahead, own = _places(kids, chunked["main_tid"])
+    # a chunk's bytes are counted once: where they left the host
+    assert [s.attributes["bytes"] for s in ahead] == [per_chunk] * DISPATCHES
+    assert [s.attributes["bytes"] for s in own] == [0] * DISPATCHES
+    assert sum(s.attributes["bytes"] for s in kids["train.place"]) \
+        == per_chunk * DISPATCHES
     assert [s.attributes["bytes"] for s in kids["train.chunk_build"]] \
         == [per_chunk] * DISPATCHES
+
+
+def test_every_chunked_dispatch_found_its_chunk_on_the_device(chunked):
+    kids = _children(chunked["spans"], chunked["root"])
+    ahead, own = _places(kids, chunked["main_tid"])
+    assert [s.attributes["ahead"] for s in own] == [True] * DISPATCHES
+    # `ahead` is the dispatch's own finding: one a dispatch, so a share of
+    # dispatches can be counted from the spans that carry it
+    assert all("ahead" not in s.attributes for s in ahead)
+
+
+@pytest.mark.parametrize("on_device", [False, True], ids=["host_arrays", "device_arrays"])
+def test_train_steps_scan_says_whether_its_input_was_ahead(chunked, on_device):
+    obs.reset_tracer()
+    tr = chunked["trainer"]
+    state = tr.init_state(_batch(), jax.random.PRNGKey(0))
+    stacked = {k: np.stack([v] * CHUNK) for k, v in _batch().items()}
+    nbytes = sum(v.nbytes for v in stacked.values())
+    if on_device:
+        stacked = tr.mesh.shard_stacked_batch(stacked)
+    tr.train_steps_scan(state, stacked)
+    (place,) = [s for s in obs.get_tracer().finished_spans() if s.name == "train.place"]
+    assert place.attributes == {"bytes": 0 if on_device else nbytes, "ahead": on_device}
 
 
 def test_chunk_build_comes_from_the_producer_thread(chunked):
@@ -121,14 +162,34 @@ def test_chunk_build_comes_from_the_producer_thread(chunked):
         assert a["next_ms"] + a["stack_ms"] + a["put_wait_ms"] <= s.duration_ms + 1.0
 
 
+def test_the_producer_places_inside_stack_ms(chunked):
+    """`stack_ms` runs from the last `next()` to the `put`: the producer's
+    `train.place` lies inside it, so `chunk_build_share` stays its busy share."""
+    kids = _children(chunked["spans"], chunked["root"])
+    ahead, _ = _places(kids, chunked["main_tid"])
+    for build, place in zip(kids["train.chunk_build"], ahead):
+        assert place.tid == build.tid
+        assert build.start_ns <= place.start_ns and place.end_ns <= build.end_ns + 1000
+        assert place.duration_ms <= build.attributes["stack_ms"] + 1e-3
+
+
 def test_the_loop_order_within_a_cycle(chunked):
     kids = _children(chunked["spans"], chunked["root"])
-    for wait, place, dispatch, fetch in zip(
-            kids["train.chunk_wait"], kids["train.place"], kids["train.dispatch"],
+    ahead, own = _places(kids, chunked["main_tid"])
+    for wait, placed, place, dispatch, fetch in zip(
+            kids["train.chunk_wait"], ahead, own, kids["train.dispatch"],
             kids["train.fetch"]):
+        # the chunk is on its way to the device before the loop has it ...
+        assert placed.end_ns <= wait.end_ns + 1000
+        # ... and the loop goes on as before: its own placement finds nothing
+        # to move, then the program, then its losses
         assert wait.end_ns <= place.start_ns + 1000
         assert place.end_ns <= dispatch.start_ns + 1000
         assert dispatch.end_ns <= fetch.start_ns + 1000
+    # every chunk after the first was placed while an earlier one trained:
+    # before the fetch that precedes its own dispatch had returned
+    for placed, fetch_before in zip(ahead[1:], kids["train.fetch"]):
+        assert placed.start_ns <= fetch_before.end_ns
 
 
 # ---- (b) which dispatch compiled -------------------------------------------
@@ -160,7 +221,7 @@ def test_second_fit_on_the_returned_state_compiles_nothing(chunked):
 
 
 @pytest.mark.parametrize("phase,count", [
-    ("chunk_wait", DISPATCHES + 1), ("place", DISPATCHES), ("dispatch", DISPATCHES),
+    ("chunk_wait", DISPATCHES + 1), ("place", PLACES), ("dispatch", DISPATCHES),
     ("fetch", DISPATCHES), ("chunk_build", DISPATCHES)])
 def test_loop_histogram_has_one_observation_a_span(chunked, phase, count):
     hist = chunked["snapshot"][LOOP_MS % phase]
