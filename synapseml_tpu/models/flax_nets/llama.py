@@ -11,12 +11,14 @@ from __future__ import annotations
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .transformer import (Encoder, MlpBlock, MoEBlock, TransformerConfig,
                           _norm, apply_rope, make_causal_mask,
                           rope_frequencies)
 
-__all__ = ["llama2_7b", "llama_tiny", "LlamaLM", "generate", "greedy_generate",
+__all__ = ["llama2_7b", "llama_tiny", "sparse_moe_lm", "next_token_labels", "LlamaLM",
+           "generate", "greedy_generate",
            "PagedLlamaLM", "paged_prefill", "paged_decode_step",
            "paged_extend", "paged_verify", "early_exit_params"]
 
@@ -37,8 +39,39 @@ def llama_tiny(**kw) -> TransformerConfig:
     return TransformerConfig(**defaults)
 
 
+def sparse_moe_lm(**kw) -> TransformerConfig:
+    """A decoder with learned sparse attention and routed experts only, at
+    the published sizes of Keye-VL-2.0-30B-A3B's language model (config.json of
+    Kwai-Keye/Keye-VL-2.0-30B-A3B): GQA 32/4 heads of 128 with per-head q/k
+    RMSNorm, an indexer of 16 heads of 64 choosing 2048 keys a query, 128
+    gated experts of width 768 with 8 a token and no dense MLP, no biases,
+    untied embedding and head. One chip's share of an expert-parallel
+    deployment is ``moe_experts`` (held) under ``moe_total_experts`` (routed
+    over) from ``moe_first_expert``, and a smaller ``vocab_size``."""
+    defaults = dict(vocab_size=151936, hidden=2048, n_layers=48, n_heads=32,
+                    n_kv_heads=4, head_dim=128, mlp_dim=768, max_len=262144,
+                    norm="rmsnorm", norm_eps=1e-6, act="silu", gated_mlp=True,
+                    causal=True, use_rope=True, rope_theta=1e7, attn_bias=False,
+                    qk_norm=True, attn_topk=2048, indexer_heads=16,
+                    indexer_head_dim=64, moe_experts=128, moe_total_experts=128,
+                    moe_top_k=8, moe_dispatch="grouped", moe_bias=False)
+    defaults.update(kw)
+    return TransformerConfig(**defaults)
+
+
+def next_token_labels(input_ids, ignore: int = -100):
+    """Labels of a packed row for `Trainer`'s loss: ``labels[:, t]`` is
+    ``input_ids[:, t + 1]``, the last position ``ignore`` (negative: left out
+    of the mean, `trainer.cross_entropy_loss`)."""
+    ids = np.asarray(input_ids)
+    last = np.full(ids.shape[:-1] + (1,), ignore, ids.dtype)
+    return np.concatenate([ids[..., 1:], last], axis=-1)
+
+
 class LlamaLM(nn.Module):
-    """[B,T] ids -> [B,T,V] logits; decode=True enables the KV cache."""
+    """[B,T] ids -> [B,T,V] logits; decode=True enables the KV cache. Trains
+    through `Trainer` on batches ``{"input_ids", "labels"}`` with
+    `next_token_labels`."""
 
     cfg: TransformerConfig
     decode: bool = False

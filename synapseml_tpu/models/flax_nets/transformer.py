@@ -36,6 +36,7 @@ class TransformerConfig:
     n_layers: int = 12
     n_heads: int = 12
     n_kv_heads: int | None = None  # None -> MHA; < n_heads -> GQA
+    head_dim: int | None = None  # None -> hidden // n_heads
     mlp_dim: int = 3072
     max_len: int = 512
     dropout: float = 0.0
@@ -77,11 +78,31 @@ class TransformerConfig:
     # buffers — O(E*C*H) memory and O(S*k*H) index work, never O(S*E*C) —
     # which is the only feasible layout when capacity must be dropless
     # (C = S, e.g. ingested Mixtral checkpoints at real sequence lengths).
+    # 'grouped' is dropless at any imbalance and does the routed pairs' work
+    # only (ops.grouped_ffn: gated experts, no biases); it is also the one
+    # layout that can hold a SHARE of the experts: the router scores
+    # `moe_total_experts` (0 -> moe_experts), this module holds the
+    # `moe_experts` consecutive ones from `moe_first_expert` and returns their
+    # part of the result (what experts held on other chips would add is left
+    # out: one chip of an expert-parallel deployment, without its exchange).
     moe_dispatch: str = "einsum"
+    moe_total_experts: int = 0
+    moe_first_expert: int = 0
+    moe_bias: bool = True  # b_up / b_dn on the experts
+    attn_bias: bool = True  # biases on the q, k, v, o projections
+    qk_norm: bool = False  # per-head RMSNorm on q and k, before RoPE
+    # learned sparse attention (ops.sparse_attention): 0 = every causal key.
+    # Each query attends to the exact `attn_topk` keys that an indexer of
+    # `indexer_heads` heads of `indexer_head_dim` (one key head) scores highest;
+    # the indexer's loss is sown under intermediates/sparse_attn_indexer_kl.
+    attn_topk: int = 0
+    indexer_heads: int = 0
+    indexer_head_dim: int = 0
+    attn_q_tile: int = 512  # queries a tile on the indexed path
 
-    @property
-    def head_dim(self) -> int:
-        return self.hidden // self.n_heads
+    def __post_init__(self):
+        if self.head_dim is None:
+            object.__setattr__(self, "head_dim", self.hidden // self.n_heads)
 
     @property
     def kv_heads(self) -> int:
@@ -98,10 +119,11 @@ def _act_fn(name: str) -> Callable:
 class RMSNorm(nn.Module):
     eps: float = 1e-6
     dtype: Dtype = jnp.bfloat16
+    axes: tuple = ("embed",)  # logical axis of the scale
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.with_logical_partitioning(nn.initializers.ones, ("embed",)),
+        scale = self.param("scale", nn.with_logical_partitioning(nn.initializers.ones, self.axes),
                            (x.shape[-1],))
         x32 = x.astype(jnp.float32)
         normed = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
@@ -210,6 +232,42 @@ class Attention(nn.Module):
             probs = nn.Dropout(cfg.dropout, deterministic=not self.has_rng("dropout"))(probs)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
+    def _attend_indexed(self, x, q, k, v, mask, positions):
+        """Learned sparse attention: the indexer reads the layer's input (no
+        gradient into the trunk), and every query attends to its exact
+        ``cfg.attn_topk`` highest-scored causal keys. Sows the indexer's loss
+        and the selected share of the causal candidates."""
+        from ...ops.sparse_attention import indexed_attention
+
+        cfg = self.cfg
+        if self.decode or not cfg.causal:
+            raise ValueError(
+                "attn_topk > 0 is the causal training/prefill path: a decode "
+                "cache would need the indexer's keys beside the model's")
+        if mask is not None and not (mask.ndim == 4 and mask.shape[1] == 1
+                                     and mask.shape[2] == 1):
+            raise ValueError("the indexed path takes a key-padding mask only")
+        HI, DI = cfg.indexer_heads, cfg.indexer_head_dim
+        proj = lambda name, feat, axes: nn.DenseGeneral(  # noqa: E731
+            features=feat, axis=-1, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            use_bias=False, kernel_init=nn.with_logical_partitioning(
+                nn.initializers.xavier_uniform(), axes), name=name)
+        h = jax.lax.stop_gradient(x)
+        qi = proj("indexer_q", (HI, DI), ("embed", "heads", "kv"))(h)
+        ki = proj("indexer_k", DI, ("embed", "kv"))(h)
+        wi = proj("indexer_w", HI, ("embed", "heads"))(h)
+        if cfg.use_rope:
+            cos_np, sin_np = rope_frequencies(DI, cfg.max_len, cfg.rope_theta)
+            cos, sin = jnp.asarray(cos_np), jnp.asarray(sin_np)
+            qi = apply_rope(qi, cos, sin, positions)
+            ki = apply_rope(ki[:, :, None, :], cos, sin, positions)[:, :, 0, :]
+        out, kl, share = indexed_attention(
+            q, k, v, qi, ki, wi, topk=cfg.attn_topk, q_tile=cfg.attn_q_tile,
+            kv_mask=None if mask is None else mask[:, 0, 0, :])
+        self.sow("intermediates", "sparse_attn_indexer_kl", kl)
+        self.sow("intermediates", "sparse_attn_selected_share", share)
+        return out
+
     @nn.compact
     def __call__(self, x, mask=None, positions=None):
         cfg = self.cfg
@@ -217,6 +275,7 @@ class Attention(nn.Module):
         H, KV, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
         dense = lambda name, heads: nn.DenseGeneral(  # noqa: E731
             features=(heads, D), axis=-1, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            use_bias=cfg.attn_bias,
             kernel_init=nn.with_logical_partitioning(nn.initializers.xavier_uniform(),
                                                      ("embed", "heads", "kv")),
             bias_init=nn.with_logical_partitioning(nn.initializers.zeros, ("heads", "kv")),
@@ -224,6 +283,9 @@ class Attention(nn.Module):
         q = dense("q", H)(x)
         k = dense("k", KV)(x)
         v = dense("v", KV)(x)
+        if cfg.qk_norm:
+            q = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, axes=("kv",), name="q_norm")(q)
+            k = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, axes=("kv",), name="k_norm")(k)
 
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
@@ -249,13 +311,16 @@ class Attention(nn.Module):
             kv_len = cfg.max_len
             causal = make_causal_mask(T, kv_len, offset=start)
             mask = causal if mask is None else jnp.logical_and(mask, causal)
-        if KV != H:
-            k = jnp.repeat(k, H // KV, axis=2)
-            v = jnp.repeat(v, H // KV, axis=2)
-
-        out = self._attend(q, k, v, mask)
+        if cfg.attn_topk > 0:
+            out = self._attend_indexed(x, q, k, v, mask, positions)
+        else:
+            if KV != H:
+                k = jnp.repeat(k, H // KV, axis=2)
+                v = jnp.repeat(v, H // KV, axis=2)
+            out = self._attend(q, k, v, mask)
         return nn.DenseGeneral(
             features=cfg.hidden, axis=(-2, -1), dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            use_bias=cfg.attn_bias,
             kernel_init=nn.with_logical_partitioning(nn.initializers.xavier_uniform(),
                                                      ("heads", "kv", "embed")),
             bias_init=nn.with_logical_partitioning(nn.initializers.zeros, ("embed",)),
@@ -287,17 +352,23 @@ class MlpBlock(nn.Module):
 
 
 class MoEBlock(nn.Module):
-    """Switch-transformer MoE MLP: top-k routing, capacity-bucketed einsum
-    dispatch, per-expert MLPs with the ``expert`` logical axis.
+    """Mixture-of-experts MLP: top-k routing over a float32 router, per-expert
+    MLPs with the ``expert`` logical axis, three layouts (``cfg.moe_dispatch``).
 
-    Net-new vs the reference (no model parallelism there); the TPU-native
-    shape of MoE: dispatch/combine are one-hot einsums (MXU work, static
-    shapes), expert weights ``[E, ...]`` shard over the mesh ``expert`` axis
-    and GSPMD derives the token all-to-alls from the einsum shardings.
-    Tokens overflowing an expert's capacity are dropped (switch behavior —
-    the residual connection in :class:`Block` carries them through).
-    The load-balancing auxiliary loss is sown under
-    ``intermediates/moe_aux_loss`` (mean over layers = the switch aux term).
+    'einsum' / 'scatter' (switch-transformer): capacity-bucketed dispatch and
+    combine as one-hot einsums or a sort-and-scatter into ``[E, C, H]``
+    buffers (static shapes; expert weights shard over the mesh ``expert`` axis
+    and GSPMD derives the token all-to-alls). Tokens overflowing an expert's
+    capacity are dropped (the residual connection in :class:`Block` carries
+    them through). 'grouped' (``ops.grouped_ffn``): dropless at any imbalance,
+    work that follows the routed pairs, and the one layout that can hold a
+    share of the experts (``moe_total_experts``, ``moe_first_expert``): the
+    router scores all of them (its product at highest precision), the result
+    is the held experts' part.
+
+    Sown under ``intermediates``: ``moe_aux_loss`` (the load-balance term over
+    all the router's experts; mean over layers = the switch aux term) and, on
+    the grouped path, ``moe_held_pairs`` and ``moe_expert_load_max_ratio``.
     """
 
     cfg: TransformerConfig
@@ -306,34 +377,60 @@ class MoEBlock(nn.Module):
     def __call__(self, x):
         cfg = self.cfg
         E, k = cfg.moe_experts, cfg.moe_top_k
+        # the router's width: all the model's experts, of which E are held
+        R = cfg.moe_total_experts or E
         B, T, H = x.shape
         S = B * T
         xf = x.reshape(S, H)
+        if cfg.moe_dispatch not in ("einsum", "scatter", "grouped"):
+            raise ValueError(
+                f"moe_dispatch must be 'einsum', 'scatter' or 'grouped', got "
+                f"{cfg.moe_dispatch!r}")
+        if cfg.moe_dispatch != "grouped" and (R != E or cfg.moe_first_expert):
+            raise ValueError("a share of the experts needs moe_dispatch='grouped'")
 
-        router = nn.Dense(
-            E, dtype=jnp.float32, param_dtype=cfg.param_dtype, use_bias=False,
-            kernel_init=nn.with_logical_partitioning(
-                nn.initializers.xavier_uniform(), ("embed", None)),
-            name="router")
-        logits = router(xf.astype(jnp.float32))           # [S, E] f32
-        probs = jax.nn.softmax(logits, axis=-1)
+        # on the grouped path float32 in earnest: a TPU's default float32
+        # product rounds its operands to bfloat16, and a rounded logit flips
+        # near-tied choices. 'einsum' / 'scatter' keep the default product
+        # (their users' results stay as they were)
+        grouped = cfg.moe_dispatch == "grouped"
+        with jax.named_scope("moe.route"):
+            router = nn.Dense(
+                R, dtype=jnp.float32, param_dtype=cfg.param_dtype, use_bias=False,
+                precision=jax.lax.Precision.HIGHEST if grouped else None,
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.xavier_uniform(), ("embed", None)),
+                name="router")
+            logits = router(xf.astype(jnp.float32))           # [S, R] f32
+            probs = jax.nn.softmax(logits, axis=-1)
+            gate_vals, gate_idx = jax.lax.top_k(probs, k)      # [S, k]
+            if k > 1:
+                # renormalize over the selected experts — identical to Mixtral's
+                # softmax-then-topk-then-divide. k=1 keeps the RAW router
+                # probability (switch-transformer semantics: the gate carries
+                # the router gradient); Mixtral never ships k=1 configs.
+                gate_vals = gate_vals / jnp.maximum(
+                    jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+            # load-balance aux loss: R * sum_e f_e * P_e over ALL the router's
+            # experts, with f_e the token fraction averaged over ALL k routing
+            # choices (the Mixtral/switch formulation — top-1-only would let
+            # second choices escape balancing pressure when k > 1)
+            frac_tokens = jnp.mean(
+                jax.nn.one_hot(gate_idx, R, dtype=jnp.float32), axis=(0, 1))
+            self.sow("intermediates", "moe_aux_loss",
+                     R * jnp.sum(frac_tokens * jnp.mean(probs, axis=0)))
+
+        def w(name, shape, axes):
+            return self.param(name, nn.with_logical_partitioning(
+                nn.initializers.xavier_uniform(), axes), shape,
+                cfg.param_dtype)
+
+        if grouped:
+            return self._grouped(x, xf, gate_vals, gate_idx, w)
 
         # capacity per expert, lane-friendly and >= 1
         C = max(int(np.ceil(cfg.moe_capacity_factor * S * k / E)), 1)
 
-        gate_vals, gate_idx = jax.lax.top_k(probs, k)      # [S, k]
-        if k > 1:
-            # renormalize over the selected experts — identical to Mixtral's
-            # softmax-then-topk-then-divide. k=1 keeps the RAW router
-            # probability (switch-transformer semantics: the gate carries the
-            # router gradient); Mixtral never ships k=1 configs.
-            gate_vals = gate_vals / jnp.maximum(
-                jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
-
-        if cfg.moe_dispatch not in ("einsum", "scatter"):
-            raise ValueError(
-                f"moe_dispatch must be 'einsum' or 'scatter', got "
-                f"{cfg.moe_dispatch!r}")
         if cfg.moe_dispatch == "scatter":
             # Sort the S*k (choice, token) assignments by expert so each
             # expert's tokens are contiguous, then scatter rows into [E, C, H]
@@ -381,24 +478,22 @@ class MoEBlock(nn.Module):
         expert_in = nn.with_logical_constraint(expert_in,
                                                ("expert", None, "embed"))
 
-        def w(name, shape, axes):
+        def bias(name, width, axis):
+            if not cfg.moe_bias:
+                return jnp.zeros((), cfg.dtype)
             return self.param(name, nn.with_logical_partitioning(
-                nn.initializers.xavier_uniform(), axes), shape,
-                cfg.param_dtype)
+                nn.initializers.zeros, ("expert", axis)), (E, width),
+                cfg.param_dtype)[:, None, :].astype(cfg.dtype)
 
         w_up = w("w_up", (E, H, cfg.mlp_dim), ("expert", "embed", "mlp"))
-        b_up = self.param("b_up", nn.with_logical_partitioning(
-            nn.initializers.zeros, ("expert", "mlp")), (E, cfg.mlp_dim),
-            cfg.param_dtype)
+        b_up = bias("b_up", cfg.mlp_dim, "mlp")
         w_dn = w("w_dn", (E, cfg.mlp_dim, H), ("expert", "mlp", "embed"))
-        b_dn = self.param("b_dn", nn.with_logical_partitioning(
-            nn.initializers.zeros, ("expert", "embed")), (E, H),
-            cfg.param_dtype)
+        b_dn = bias("b_dn", H, "embed")
 
         act = _act_fn(cfg.act)
         up = jnp.einsum("ech,ehm->ecm", expert_in, w_up.astype(cfg.dtype),
                         preferred_element_type=jnp.float32).astype(cfg.dtype) \
-            + b_up[:, None, :].astype(cfg.dtype)
+            + b_up
         if cfg.gated_mlp:
             # SwiGLU experts (the Mixtral block): act(x W_gate) * (x W_up)
             w_g = w("w_gate", (E, H, cfg.mlp_dim), ("expert", "embed", "mlp"))
@@ -413,7 +508,7 @@ class MoEBlock(nn.Module):
                            deterministic=not self.has_rng("dropout"))(h)
         out_e = jnp.einsum("ecm,emh->ech", h, w_dn.astype(cfg.dtype),
                            preferred_element_type=jnp.float32).astype(cfg.dtype) \
-            + b_dn[:, None, :].astype(cfg.dtype)
+            + b_dn
 
         if cfg.moe_dispatch == "scatter":
             rows = out_e.reshape(E * C, H)[jnp.minimum(buf_idx, E * C - 1)]
@@ -425,16 +520,31 @@ class MoEBlock(nn.Module):
                            out_e.astype(jnp.float32),
                            preferred_element_type=jnp.float32)
 
-        # load-balance aux loss: E * sum_e f_e * P_e, with f_e the token
-        # fraction averaged over ALL k routing choices (the Mixtral/switch
-        # formulation — top-1-only would let second choices escape balancing
-        # pressure when k > 1)
-        frac_tokens = jnp.mean(
-            jax.nn.one_hot(gate_idx, E, dtype=jnp.float32), axis=(0, 1))
-        frac_probs = jnp.mean(probs, axis=0)
-        self.sow("intermediates", "moe_aux_loss",
-                 E * jnp.sum(frac_tokens * frac_probs))
         return y.reshape(B, T, H).astype(cfg.dtype)
+
+    def _grouped(self, x, xf, gate_vals, gate_idx, w):
+        """The held experts' part of the result, dropless (ops.grouped_ffn).
+        Sows the pairs routed to the held experts and the most-loaded held
+        expert's pairs over their mean."""
+        from ...ops.grouped_ffn import expert_share_ffn
+
+        cfg = self.cfg
+        E, H = cfg.moe_experts, x.shape[-1]
+        if not cfg.gated_mlp or cfg.moe_bias or cfg.dropout > 0:
+            raise ValueError("moe_dispatch='grouped' runs gated experts without "
+                             "biases or dropout (gated_mlp=True, moe_bias=False)")
+        w_up = w("w_up", (E, H, cfg.mlp_dim), ("expert", "embed", "mlp"))
+        w_dn = w("w_dn", (E, cfg.mlp_dim, H), ("expert", "mlp", "embed"))
+        w_g = w("w_gate", (E, H, cfg.mlp_dim), ("expert", "embed", "mlp"))
+        with jax.named_scope("moe.experts"):
+            y, counts = expert_share_ffn(
+                xf.astype(cfg.dtype), gate_vals, gate_idx, w_g, w_up, w_dn,
+                first_expert=cfg.moe_first_expert, act=_act_fn(cfg.act))
+        held = jnp.sum(counts).astype(jnp.float32)
+        self.sow("intermediates", "moe_held_pairs", held)
+        self.sow("intermediates", "moe_expert_load_max_ratio",
+                 jnp.max(counts) * E / jnp.maximum(held, 1.0))
+        return y.reshape(x.shape).astype(cfg.dtype)
 
 
 class Block(nn.Module):

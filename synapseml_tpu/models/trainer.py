@@ -79,6 +79,10 @@ class TrainerConfig:
     # intermediates/moe_aux_loss); only consulted when the module's config
     # has moe_experts > 0
     moe_aux_weight: float = 0.01
+    # weight on the learned-sparse-attention indexer's own loss (sown by
+    # Attention as intermediates/sparse_attn_indexer_kl, summed over the layers); it
+    # reaches the indexer's weights alone
+    indexer_loss_weight: float = 1.0
     # declarative sharding (parallel.partition.PartitionRules): regex
     # param-path rules place params AND optimizer state on the mesh —
     # plain pytrees need no nn.Partitioned metadata. zero_shard=True adds
@@ -129,7 +133,28 @@ _TRAIN_METRICS = obs.HandleCache(lambda reg: {
     "samples_per_sec": reg.gauge(
         "synapseml_train_samples_per_sec", "fit-loop throughput",
         ("engine",)).labels(engine="trainer"),
+    # what a step's metrics carry beside loss and grad_norm, for modules that
+    # have the mechanism (``_MODEL_STATS``); fetched with the loss
+    "moe_held_pairs": reg.counter(
+        "synapseml_moe_held_pairs_total",
+        "(token, choice) pairs routed to the experts held here, over the "
+        "layers and steps trained"),
+    "moe_expert_load_max_ratio": reg.gauge(
+        "synapseml_moe_expert_load_max_ratio",
+        "most-loaded held expert's pairs over the held experts' mean, worst "
+        "layer of the newest step"),
+    "sparse_attn_selected_share": reg.gauge(
+        "synapseml_sparse_attn_selected_share",
+        "keys the indexer selected over the causal candidates, newest step"),
+    "sparse_attn_indexer_kl": reg.gauge(
+        "synapseml_sparse_attn_indexer_kl",
+        "the indexer's loss summed over the layers, newest step"),
 })
+
+# sown name -> how the layers' values become one number of a step's metrics
+_MODEL_STATS = {"moe_held_pairs": jnp.sum, "moe_expert_load_max_ratio": jnp.max,
+                "sparse_attn_selected_share": jnp.mean,
+                "sparse_attn_indexer_kl": jnp.sum}
 
 
 class _LoopSpan:
@@ -246,7 +271,17 @@ def _graft_params(boxed, values):
 
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array,
                        mask: jax.Array | None = None) -> jax.Array:
+    """Mean cross-entropy. ``mask`` weighs the labels; one of fewer axes (the
+    loader's ``[B]`` row mask on ``[B, T]`` labels) covers every token of its
+    row. Labels of more than one axis (a label a token) may be negative: such
+    a position is left out (the next-token convention: ``labels[:, t]`` is
+    ``input_ids[:, t + 1]``, the last position -100)."""
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    if labels.ndim > 1:
+        keep = (labels >= 0).astype(jnp.float32)
+        if mask is not None:
+            keep = keep * mask.reshape(mask.shape + (1,) * (labels.ndim - mask.ndim))
+        labels, mask = jnp.maximum(labels, 0), keep
     nll = -jnp.take_along_axis(logp, labels[..., None].astype(jnp.int32), axis=-1)[..., 0]
     if mask is not None:
         return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
@@ -498,8 +533,28 @@ class Trainer:
         return {k: v for k, v in batch.items() if k not in drop}
 
     @property
-    def _has_moe(self) -> bool:
-        return getattr(getattr(self.module, "cfg", None), "moe_experts", 0) > 0
+    def _sows(self) -> bool:
+        """The module sows loss terms and step statistics (``_MODEL_STATS``)."""
+        cfg = getattr(self.module, "cfg", None)
+        return getattr(cfg, "moe_experts", 0) > 0 or getattr(cfg, "attn_topk", 0) > 0
+
+    def _fold_sown(self, loss, inter) -> tuple:
+        """``loss`` with the sown terms added, and the step statistics."""
+        sown: dict = {}
+        for path, v in jax.tree_util.tree_flatten_with_path(inter)[0]:
+            for k in path:
+                name = getattr(k, "key", None)
+                if name == "moe_aux_loss" or name in _MODEL_STATS:
+                    sown.setdefault(name, []).append(jnp.mean(jnp.asarray(v)))
+        aux_terms = sown.pop("moe_aux_loss", None)
+        if aux_terms:
+            loss = loss + self.cfg.moe_aux_weight * (
+                sum(aux_terms) / len(aux_terms))
+        stats = {name: _MODEL_STATS[name](jnp.stack(terms)).astype(jnp.float32)
+                 for name, terms in sown.items()}
+        if "sparse_attn_indexer_kl" in stats:
+            loss = loss + self.cfg.indexer_loss_weight * stats["sparse_attn_indexer_kl"]
+        return loss, stats
 
     def default_loss(self, variables, batch, train: bool):
         kwargs = dict(self._model_inputs(batch))
@@ -507,10 +562,11 @@ class Trainer:
         if self.has_batch_stats:
             kwargs["train"] = train
             mutable = ["batch_stats"] if train else []
-        if train and self._has_moe:
-            # collect the sown switch load-balance terms — without this the
-            # router trains with zero balancing pressure and can collapse
-            # every token onto one expert
+        if train and self._sows:
+            # collect the sown terms: the switch load-balance loss — without
+            # it the router trains with zero balancing pressure and can
+            # collapse every token onto one expert —, the sparse-attention
+            # indexer's loss, and the step statistics
             mutable = list(mutable) + ["intermediates"]
         if mutable:
             logits, new_vars = self.module.apply(variables, mutable=mutable, **kwargs)
@@ -520,15 +576,11 @@ class Trainer:
         loss = cross_entropy_loss(logits, labels, batch.get("_valid"))
         inter = new_vars.get("intermediates") if isinstance(new_vars, dict) else None
         if inter:
-            aux_terms = [jnp.mean(jnp.asarray(v)) for path, v
-                         in jax.tree_util.tree_flatten_with_path(inter)[0]
-                         if any("moe_aux_loss" in str(getattr(k, "key", k))
-                                for k in path)]
-            if aux_terms:
-                loss = loss + self.cfg.moe_aux_weight * (
-                    sum(aux_terms) / len(aux_terms))
+            loss, stats = self._fold_sown(loss, inter)
             new_vars = {k: v for k, v in new_vars.items()
                         if k != "intermediates"}
+            if stats:
+                new_vars["step_stats"] = stats
         return loss, (logits, new_vars)
 
     # ---- the jitted step ----
@@ -577,7 +629,9 @@ class Trainer:
                 new_state["batch_stats"] = None
             with jax.named_scope("step_metrics"):
                 grad_norm = optax.global_norm(grads).astype(jnp.float32)
-            metrics = {"loss": loss.astype(jnp.float32), "grad_norm": grad_norm}
+            # beside them, for a module that has the mechanism: _MODEL_STATS
+            metrics = {"loss": loss.astype(jnp.float32), "grad_norm": grad_norm,
+                       **new_vars.get("step_stats", {})}
             return new_state, metrics
 
         return step_fn
@@ -840,8 +894,30 @@ class Trainer:
     @staticmethod
     def _fetch_loss(metrics: dict) -> float:
         """A single step's loss on the host: blocks until the device has it."""
-        with _LoopSpan("train.fetch"):
+        with _LoopSpan("train.fetch") as fetch:
+            Trainer._fetch_model_stats(metrics, fetch.span)
             return float(np.asarray(metrics["loss"]))
+
+    @staticmethod
+    def _fetch_model_stats(metrics: dict, span) -> None:
+        """Inside ``train.fetch``: the statistics a step's metrics carry for a
+        module with routed experts or learned sparse attention, onto the span
+        (the dispatch's last step; pairs summed over its steps) and into
+        their series. Nothing for any other module."""
+        if len(metrics) <= 2:
+            return
+        m = _TRAIN_METRICS.get()
+        for name in _MODEL_STATS:
+            if name not in metrics:
+                continue
+            steps = np.asarray(metrics[name], np.float64).reshape(-1)
+            if name == "moe_held_pairs":        # the one counter among them
+                value = float(steps.sum())
+                m[name].inc(value)
+            else:
+                value = float(steps[-1])
+                m[name].set(value)
+            span.set_attribute(name, value)
 
     @staticmethod
     def _count_skipped() -> None:
@@ -1002,6 +1078,7 @@ class Trainer:
                     device_busy.set()
                     with _LoopSpan("train.fetch") as fetch:
                         losses = np.asarray(metrics["loss"])
+                        self._fetch_model_stats(metrics, fetch.span)
                     meter.cycle(fetch.span.end_ns, payload, steps=scan_chunk)
                     steps_done += scan_chunk
                     loss = float(losses[-1])
@@ -1012,6 +1089,7 @@ class Trainer:
                         state, metrics = self.train_step(state, b)
                         with _LoopSpan("train.fetch") as fetch:
                             losses.append(float(np.asarray(metrics["loss"])))
+                            self._fetch_model_stats(metrics, fetch.span)
                         meter.cycle(fetch.span.end_ns, b, steps=1)
                     steps_done += len(payload)
                     loss = losses[-1]

@@ -10,18 +10,27 @@ Here the hot ops are first-class TPU kernels:
     mesh axis via ``ppermute`` (net-new capability, SURVEY.md §5
     "long-context"; the reference has none);
   * :mod:`ulysses_attention` — the all-to-all sequence-parallel strategy
-    (heads scatter, tokens gather, local full-context attention).
+    (heads scatter, tokens gather, local full-context attention);
+  * :mod:`sparse_attention` — learned sparse attention for training: an
+    indexer's exact top-k key set a query, tiled masked attention over it;
+  * :mod:`grouped_ffn` — one chip's share of a mixture of experts, dropless:
+    a grouped product over the routed (token, choice) pairs.
 """
 
 from .attention import flash_attention, reference_attention
+from .grouped_ffn import expert_share_ffn
 from .ring_attention import ring_attention, ring_attention_sharded
+from .sparse_attention import indexed_attention, topk_mask
 from .ulysses_attention import ulysses_attention, ulysses_attention_sharded
 
 __all__ = [
+    "expert_share_ffn",
     "flash_attention",
+    "indexed_attention",
     "reference_attention",
     "ring_attention",
     "ring_attention_sharded",
+    "topk_mask",
     "ulysses_attention",
     "ulysses_attention_sharded",
 ]

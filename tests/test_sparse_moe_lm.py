@@ -1,0 +1,394 @@
+"""A decoder with learned sparse attention (indexer, exact top-k) and one chip's
+share of the routed experts trains through `Trainer`: the program against the
+benchmark's plain float32 reference (`perfbench/reference/sparse_moe_lm.py`) at
+tiny widths on seeded random weights, the share test, exact routing under any
+imbalance, exact selection, and the counters a fit leaves behind."""
+
+import copy
+import dataclasses
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from perfbench.programs import sparse_moe_lm as adapter  # noqa: E402
+from perfbench.reference import sparse_moe_lm as ref  # noqa: E402
+from synapseml_tpu.core import observability as obs  # noqa: E402
+from synapseml_tpu.models.flax_nets.llama import LlamaLM, next_token_labels  # noqa: E402
+from synapseml_tpu.models.flax_nets.transformer import MoEBlock, TransformerConfig  # noqa: E402
+from synapseml_tpu.models.trainer import Trainer, TrainerConfig, cross_entropy_loss  # noqa: E402
+from synapseml_tpu.ops.grouped_ffn import expert_share_ffn  # noqa: E402
+from synapseml_tpu.ops.sparse_attention import indexed_attention, topk_mask  # noqa: E402
+
+VOCAB = 64
+OPT = {"learning_rate": 1e-3, "weight_decay": 0.01, "b1": 0.9, "b2": 0.999,
+       "eps": 1e-8, "grad_clip": 1.0}
+
+
+def tiny_config(topk=8, share="0 of 4", **over):
+    """The cell's configuration file at widths the CPU holds: 16 experts, 4
+    held (3 a token), 4 query heads over 2 key heads of 16, a 2 x 8 indexer."""
+    with open(os.path.join(ROOT, "perfbench", "configs", "keye_vl2_30b_a3b_ep8.json")) as f:
+        c = json.load(f)
+    c.update(hidden_size=32, num_hidden_layers=2, num_attention_heads=4,
+             num_key_value_heads=2, head_dim=16, moe_intermediate_size=24, num_experts=4,
+             published_num_experts=16, num_experts_per_tok=3, vocab_size=VOCAB,
+             rope_table_len=64, attn_q_tile=8, expert_share=share)
+    c["sa_config"] = dict(c["sa_config"], indexer_num_heads=2, indexer_head_dim=8, topk=topk)
+    c.update(over)
+    return c
+
+
+def float32_module(config):
+    module = adapter.build(config)
+    return module.clone(cfg=dataclasses.replace(module.cfg, dtype=jnp.float32))
+
+
+def rows(seed, n, t):
+    ids = np.random.default_rng(seed).integers(0, VOCAB, (n, t), dtype=np.int32)
+    return {"input_ids": ids, "labels": next_token_labels(ids)}
+
+
+def one_chip_mesh():
+    from synapseml_tpu.parallel.mesh import MeshConfig, create_mesh
+
+    return create_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
+
+
+# ---- the program against the reference -------------------------------------
+
+@pytest.mark.parametrize("t,topk,share,weights", [
+    (6, 8, "0 of 4", {}), (8, 8, "0 of 4", {}), (21, 8, "0 of 4", {}), (21, 5, "2 of 4", {}),
+    (21, 8, "0 of 4", {"moe_aux_weight": 0.05, "indexer_loss_weight": 0.25})],
+    ids=["T_below_topk", "T_at_topk", "T_above_topk", "another_share", "other_loss_weights"])
+def test_loss_and_every_gradient_leaf_match_the_reference(t, topk, share, weights):
+    # the file's two loss weights reach the reference through `ref.sizes` and
+    # the program through the adapter's `trainer_options`
+    config = tiny_config(topk, share, **weights)
+    sizes, seed = ref.sizes(config), 3
+    batch = rows(seed, 4, t)
+    want = ref.run_steps(sizes, OPT, seed, [batch], rows_per_block=2, keep_grads=True)
+    trainer = Trainer(float32_module(config), one_chip_mesh(),
+                      TrainerConfig(**adapter.trainer_options(config)))
+    params = adapter.to_program(ref.init_params(sizes, seed), config)
+
+    def loss_of(p):
+        loss, (_, new_vars) = trainer.default_loss(
+            {"params": p}, {k: jnp.asarray(v) for k, v in batch.items()}, train=True)
+        return loss, new_vars["step_stats"]
+
+    (loss, stats), grads = jax.jit(jax.value_and_grad(loss_of, has_aux=True))(params)
+    assert float(loss) == pytest.approx(want["loss"][0], rel=2e-6)
+    got = adapter.from_program(grads, config)
+    for (path, a), (_, b) in zip(jax.tree_util.tree_flatten_with_path(got)[0],
+                                 jax.tree_util.tree_flatten_with_path(want["grads"])[0]):
+        assert float(jnp.abs(a - b).max()) <= 2e-5 * float(jnp.abs(b).max()) + 1e-9, \
+            jax.tree_util.keystr(path)
+    selected = sum(min(i + 1, topk) for i in range(t)) / (t * (t + 1) / 2)
+    assert float(stats["sparse_attn_selected_share"]) == pytest.approx(selected, rel=1e-6)
+    assert float(stats["sparse_attn_indexer_kl"]) > 0
+
+
+def test_the_indexer_loss_reaches_the_indexer_alone_and_the_lm_loss_everything_else():
+    config = tiny_config()
+    sizes = ref.sizes(config)
+    module = float32_module(config)
+    params = adapter.to_program(ref.init_params(sizes, 5), config)
+    batch = rows(5, 2, 21)
+
+    def terms(p):
+        logits, inter = module.apply({"params": p}, batch["input_ids"],
+                                     mutable=["intermediates"])
+        kl = sum(v[0] for k, v in jax.tree_util.tree_flatten_with_path(
+            inter, is_leaf=lambda x: isinstance(x, tuple))[0] if "sparse_attn_indexer_kl" in str(k))
+        return cross_entropy_loss(logits, jnp.asarray(batch["labels"])), kl
+
+    g_lm = jax.grad(lambda p: terms(p)[0])(params)
+    g_kl = jax.grad(lambda p: terms(p)[1])(params)
+    for (path, a), (_, b) in zip(jax.tree_util.tree_flatten_with_path(g_lm)[0],
+                                 jax.tree_util.tree_flatten_with_path(g_kl)[0]):
+        name = jax.tree_util.keystr(path)
+        if "indexer_" in name:
+            assert float(jnp.abs(a).max()) == 0.0 and float(jnp.abs(b).max()) > 0, name
+        else:
+            assert float(jnp.abs(b).max()) == 0.0, name
+
+
+# ---- selection ---------------------------------------------------------------
+
+@pytest.mark.parametrize("t,topk", [(6, 8), (8, 8), (40, 8), (40, 13)])
+def test_selected_sets_equal_the_references(t, topk):
+    index = jax.random.normal(jax.random.PRNGKey(t + topk), (3, t, t), jnp.float32)
+    causal = jnp.broadcast_to(jnp.tril(jnp.ones((t, t), bool)), index.shape)
+    want = ref.key_sets({"topk": topk}, index, 0)
+    got = topk_mask(index, causal, topk)
+    assert bool(jnp.all(got == want))
+    assert [int(x) for x in jnp.sum(got[0], axis=-1)] == [min(i + 1, topk) for i in range(t)]
+
+
+def test_equal_scores_are_taken_lowest_index_first():
+    index = jnp.asarray([[[1.0, 0.5, 0.5, 0.5, 0.5, 2.0, -0.0, 0.0]]])
+    got = topk_mask(index, jnp.ones(index.shape, bool), 3)
+    assert got[0, 0].tolist() == [True, True, False, False, False, True, False, False]
+    _, picked = jax.lax.top_k(index, 3)
+    assert sorted(picked[0, 0].tolist()) == [0, 1, 5]
+    zeros = topk_mask(jnp.zeros((1, 1, 6)) * jnp.asarray([-1.0, 1, 1, -1, 1, 1]),
+                      jnp.asarray([[[False, True, True, True, True, True]]]), 2)
+    assert zeros[0, 0].tolist() == [False, True, True, False, False, False]
+
+
+def test_no_key_outside_the_selection_has_weight():
+    b, t, h, kv, d, hi, di, topk = 2, 24, 4, 2, 8, 2, 4, 5
+    keys = jax.random.split(jax.random.PRNGKey(0), 7)
+    q = jax.random.normal(keys[0], (b, t, h, d))
+    k = jax.random.normal(keys[1], (b, t, kv, d))
+    v = jax.random.normal(keys[2], (b, t, kv, d))
+    qi = jax.random.normal(keys[3], (b, t, hi, di))
+    ki = jax.random.normal(keys[4], (b, t, di))
+    wi = jax.random.normal(keys[5], (b, t, hi))
+    out, _, share = indexed_attention(q, k, v, qi, ki, wi, topk=topk, q_tile=8)
+    index = jnp.einsum("btj,bjts->bts", wi, jax.nn.relu(
+        jnp.einsum("btjd,bsd->bjts", qi, ki))) / np.sqrt(hi * di)
+    chosen = ref.key_sets({"topk": topk}, index, 0)
+    # the last query's unselected keys and values may hold anything
+    outside = ~chosen[:, -1]
+    noise = 1e3 * jax.random.normal(keys[6], v.shape)
+    v2 = jnp.where(outside[:, :, None, None], noise, v)
+    k2 = jnp.where(outside[:, :, None, None], noise, k)
+    out2, _, _ = indexed_attention(q, k2, v2, qi, ki, wi, topk=topk, q_tile=8)
+    assert bool(jnp.all(out[:, -1] == out2[:, -1]))
+    assert float(share) == pytest.approx(
+        sum(min(i + 1, topk) for i in range(t)) / (t * (t + 1) / 2))
+
+
+# ---- the experts' share --------------------------------------------------------
+
+def _moe_cfg(held, total, first, k=3, hidden=32, width=24):
+    return TransformerConfig(hidden=hidden, mlp_dim=width, n_heads=4, act="silu",
+                             gated_mlp=True, moe_experts=held, moe_total_experts=total,
+                             moe_first_expert=first, moe_top_k=k, moe_dispatch="grouped",
+                             moe_bias=False, dtype=jnp.float32)
+
+
+def _full_layer(seed, total=16, hidden=32, width=24):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return {"router": 0.5 * jax.random.normal(keys[0], (hidden, total)),
+            "wg": 0.3 * jax.random.normal(keys[1], (total, hidden, width)),
+            "wu": 0.3 * jax.random.normal(keys[2], (total, hidden, width)),
+            "wd": 0.3 * jax.random.normal(keys[3], (total, width, hidden))}, \
+        jax.random.normal(keys[4], (2, 20, hidden))
+
+
+def _share_output(lp, u, held, first, total=16):
+    cfg = _moe_cfg(held, total, first)
+    params = {"router": {"kernel": lp["router"]}, "w_gate": lp["wg"][first:first + held],
+              "w_up": lp["wu"][first:first + held], "w_dn": lp["wd"][first:first + held]}
+    return MoEBlock(cfg).apply({"params": params}, u, mutable=["intermediates"])
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer():
+    """What every chip of the deployment computes, added, is what the uncut
+    reference gives for the whole expert layer (16 experts, 8 shares of 2)."""
+    lp, u = _full_layer(1)
+    s = {"experts": 16, "per_token": 3, "norm_topk": True, "first_expert": 0}
+    flat = u.reshape(-1, u.shape[-1])
+    _, chosen, gates = ref.route(s, "float32", lp, flat)
+    whole = ref.experts(s, "float32", lp, flat, chosen, gates).reshape(u.shape)
+    parts, pairs = 0.0, 0.0
+    for share in range(8):
+        y, inter = _share_output(lp, u, 2, 2 * share)
+        parts = parts + y
+        pairs += float(inter["intermediates"]["moe_held_pairs"][0])
+    assert float(jnp.abs(parts - whole).max()) <= 1e-5 * float(jnp.abs(whole).max())
+    assert pairs == flat.shape[0] * 3           # every pair is held by exactly one share
+
+
+@pytest.mark.parametrize("skew", ["one_expert_takes_most", "every_pair_is_held"])
+def test_a_skewed_router_loses_no_pair(skew):
+    lp, u = _full_layer(2)
+    if skew == "one_expert_takes_most":      # expert 1 is every token's first choice
+        lp["router"] = lp["router"].at[:, 1].set(0.0)
+        u = u.at[..., 0].set(40.0)
+        lp["router"] = lp["router"].at[0, 1].set(1.0)
+    else:                                    # all three choices of every token are held
+        lp["router"] = lp["router"].at[:, 4:].add(-100.0 * jnp.sign(u.mean()))
+        u = jnp.abs(u)
+        lp["router"] = jnp.where(jnp.arange(16)[None, :] < 4, jnp.abs(lp["router"]),
+                                 -jnp.abs(lp["router"]))
+    s = {"experts": 16, "per_token": 3, "norm_topk": True, "first_expert": 0}
+    flat = u.reshape(-1, u.shape[-1])
+    _, chosen, gates = ref.route(s, "float32", lp, flat)
+    held_lp = {k: (v[:4] if k != "router" else v) for k, v in lp.items()}
+    want = ref.experts(s, "float32", held_lp, flat, chosen, gates).reshape(u.shape)
+    y, inter = _share_output(lp, u, 4, 0)
+    held_pairs = int(jnp.sum(chosen < 4))
+    if skew == "one_expert_takes_most":
+        assert int(jnp.sum(chosen == 1)) == flat.shape[0]
+        assert float(inter["intermediates"]["moe_expert_load_max_ratio"][0]) > 2.0
+    else:
+        assert held_pairs == flat.shape[0] * 3      # the worst case the buffers are sized for
+    assert float(inter["intermediates"]["moe_held_pairs"][0]) == held_pairs
+    assert float(jnp.abs(y - want).max()) <= 1e-5 * float(jnp.abs(want).max())
+
+
+def test_grouped_gradients_match_a_dense_computation():
+    keys = jax.random.split(jax.random.PRNGKey(4), 5)
+    s_, h, m, e, total, k = 37, 16, 24, 4, 16, 3
+    x = jax.random.normal(keys[0], (s_, h))
+    w = [0.3 * jax.random.normal(keys[i], shape) for i, shape in
+         ((1, (e, h, m)), (2, (e, h, m)), (3, (e, m, h)))]
+    logits = jax.random.normal(keys[4], (s_, total))
+
+    def gate(logits):
+        gv, gi = jax.lax.top_k(jax.nn.softmax(logits, -1), k)
+        return gv / gv.sum(-1, keepdims=True), gi
+
+    def dense(x, logits, wg, wu, wd):
+        gv, gi = gate(logits)
+        return sum(jnp.sum(jnp.where(gi == 4 + i, gv, 0), -1)[:, None]
+                   * ((jax.nn.silu(x @ wg[i]) * (x @ wu[i])) @ wd[i]) for i in range(e))
+
+    def grouped(x, logits, wg, wu, wd):
+        gv, gi = gate(logits)
+        return expert_share_ffn(x, gv, gi, wg, wu, wd, first_expert=4, block_rows=8)[0]
+
+    grad = lambda fn: jax.jit(jax.grad(  # noqa: E731
+        lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=(0, 1, 2, 3, 4)))(x, logits, *w)
+    for a, b in zip(grad(grouped), grad(dense)):
+        assert float(jnp.abs(a - b).max()) <= 1e-5 * float(jnp.abs(b).max())
+
+
+def test_a_share_needs_the_grouped_layout_and_biases_are_optional():
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 32))
+    with pytest.raises(ValueError, match="grouped"):
+        cfg = dataclasses.replace(_moe_cfg(4, 16, 0), moe_dispatch="scatter", moe_bias=True)
+        MoEBlock(cfg).init(jax.random.PRNGKey(1), x)
+    for dispatch in ("einsum", "scatter"):
+        cfg = TransformerConfig(hidden=32, mlp_dim=24, n_heads=4, moe_experts=4, moe_top_k=2,
+                                moe_dispatch=dispatch, moe_capacity_factor=2.0,
+                                dtype=jnp.float32)
+        with_bias = MoEBlock(cfg).init(jax.random.PRNGKey(1), x)["params"]
+        bare_cfg = dataclasses.replace(cfg, moe_bias=False)
+        made = MoEBlock(bare_cfg).init(jax.random.PRNGKey(1), x)["params"]
+        assert set(with_bias) - set(made) == {"b_up", "b_dn"}
+        bare = {k: v for k, v in with_bias.items() if k in made}
+        y0 = MoEBlock(cfg).apply({"params": with_bias}, x)
+        y1 = MoEBlock(bare_cfg).apply({"params": bare}, x)
+        assert bool(jnp.all(y0 == y1))      # zero biases: the same result
+
+
+def test_head_dim_is_a_field_that_defaults_to_hidden_over_heads():
+    assert TransformerConfig(hidden=768, n_heads=12).head_dim == 64
+    assert TransformerConfig(hidden=2048, n_heads=32, head_dim=128).head_dim == 128
+    cfg = TransformerConfig(hidden=32, n_heads=4, n_kv_heads=2, head_dim=16, vocab_size=VOCAB,
+                            n_layers=1, mlp_dim=16, causal=True, use_rope=True, max_len=16,
+                            dtype=jnp.float32)
+    v = LlamaLM(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    attn = jax.tree.map(jnp.shape, v["params"]["decoder"]["layer_0"]["attn"],
+                        is_leaf=lambda x: hasattr(x, "shape") and not isinstance(x, dict))
+    assert attn["q"]["kernel"].value == (32, 4, 16) and attn["o"]["kernel"].value == (4, 16, 32)
+
+
+# ---- the loss ---------------------------------------------------------------
+
+def test_a_row_mask_covers_every_token_of_its_row_and_negative_labels_are_left_out():
+    logits = jax.random.normal(jax.random.PRNGKey(0), (3, 5, 7))
+    labels = jnp.asarray(np.random.default_rng(0).integers(0, 7, (3, 5)))
+    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits, -1), labels[..., None], -1)[..., 0]
+    valid = jnp.asarray([1.0, 0.0, 1.0])
+    got = cross_entropy_loss(logits, labels, valid)
+    assert float(got) == pytest.approx(float((nll[0].sum() + nll[2].sum()) / 10), rel=1e-6)
+    last_out = labels.at[:, -1].set(-100)
+    got = cross_entropy_loss(logits, last_out, valid)
+    assert float(got) == pytest.approx(
+        float((nll[0, :4].sum() + nll[2, :4].sum()) / 8), rel=1e-6)
+    assert float(cross_entropy_loss(logits, last_out)) == pytest.approx(
+        float(nll[:, :4].mean()), rel=1e-6)
+    # a label a row, as the classifiers give it, is read as before
+    rows_ = cross_entropy_loss(logits[:, 0], labels[:, 0], valid)
+    assert float(rows_) == pytest.approx(float((nll[0, 0] + nll[2, 0]) / 2), rel=1e-6)
+
+
+# ---- through DataLoader and Trainer.fit ---------------------------------------
+
+@pytest.fixture(scope="module")
+def fitted():
+    """The tiny LM, two dispatches of 4 steps through `DataLoader` and
+    `Trainer.fit`'s chunked scan, from given weights."""
+    from synapseml_tpu.data import DataLoader
+    from synapseml_tpu.data.source import MemorySource
+
+    obs.reset_tracer()
+    obs.reset_registry()
+    config = tiny_config()
+    module = adapter.build(config)             # bfloat16 compute, as the cell
+    trainer = Trainer(module, one_chip_mesh(), TrainerConfig(learning_rate=1e-3))
+    sizes = ref.sizes(config)
+    state = trainer.resume_state(adapter.to_program(ref.init_params(sizes, 9), config))
+    loader = DataLoader(MemorySource(rows(9, 32, 21)), 4, seed=9, epochs=None,
+                        drop_remainder=True, shuffle_rows="full")
+    try:
+        state = trainer.fit(state, iter(loader), max_steps=8, scan_chunk=4, log_every=4)
+    finally:
+        loader.close()
+    return {"state": state, "spans": obs.get_tracer().finished_spans(),
+            "snapshot": obs.get_registry().snapshot(),
+            "exposition": obs.get_registry().exposition(), "metrics": list(trainer.metrics)}
+
+
+def test_the_tiny_lm_trains_two_dispatches_through_the_loader(fitted):
+    assert int(fitted["state"].step) == 8
+    dispatches = [s for s in fitted["spans"] if s.name == "train.dispatch"]
+    assert [s.attributes["program"] for s in dispatches] == ["scan", "scan"]
+    assert np.isfinite(fitted["metrics"][-1]["loss"])
+
+
+def test_the_fit_leaves_the_new_counters_and_the_fetch_spans_values(fitted):
+    snap = fitted["snapshot"]
+    tokens, layers = 4 * 21, 2
+    # 8 steps x 2 layers x pairs held: near tokens x 3 x 4/16 a layer, never over the worst case
+    assert 0 < snap["synapseml_moe_held_pairs_total"] <= 8 * layers * tokens * 3
+    assert snap["synapseml_moe_expert_load_max_ratio"] >= 1.0
+    assert snap["synapseml_sparse_attn_selected_share"] == pytest.approx(
+        sum(min(i + 1, 8) for i in range(21)) / (21 * 22 / 2), rel=1e-5)
+    assert snap["synapseml_sparse_attn_indexer_kl"] > 0
+    fetches = [s for s in fitted["spans"] if s.name == "train.fetch"]
+    assert len(fetches) == 2
+    assert sum(s.attributes["moe_held_pairs"] for s in fetches) \
+        == snap["synapseml_moe_held_pairs_total"]
+    last = fetches[-1].attributes
+    for key in ("moe_expert_load_max_ratio", "sparse_attn_selected_share",
+                "sparse_attn_indexer_kl"):
+        assert last[key] == snap["synapseml_" + key]
+
+
+def test_metrics_endpoint_shows_the_four_series(fitted):
+    for series in ("synapseml_moe_held_pairs_total", "synapseml_moe_expert_load_max_ratio",
+                   "synapseml_sparse_attn_selected_share", "synapseml_sparse_attn_indexer_kl"):
+        assert f"\n{series} " in fitted["exposition"], series
+
+
+def test_a_module_without_the_mechanism_keeps_its_two_step_metrics(mesh_dp8):
+    from synapseml_tpu.models.flax_nets.bert import BertClassifier, bert_tiny
+
+    trainer = Trainer(BertClassifier(bert_tiny(), num_classes=2), mesh_dp8, TrainerConfig())
+    batch = {"input_ids": np.zeros((8, 16), np.int32), "attention_mask": np.ones((8, 16), np.int32),
+             "labels": np.zeros((8,), np.int32)}
+    state = trainer.init_state(batch, jax.random.PRNGKey(0))
+    _, metrics = trainer.train_step(state, batch)
+    assert set(metrics) == {"loss", "grad_norm"}
+
+
+def test_next_token_labels():
+    ids = np.arange(12, dtype=np.int32).reshape(2, 6)
+    labels = next_token_labels(ids)
+    assert labels[:, :-1].tolist() == ids[:, 1:].tolist() and labels[:, -1].tolist() == [-100] * 2
+    assert copy.copy(labels).dtype == np.int32
