@@ -10,7 +10,11 @@ modules built for GSPMD):
   * attention is einsum-based with optional GQA + rotary embeddings and a
     decode-time KV cache; the sequence axis is ready for ring attention
     (``ops.ring_attention``) when seq-parallel is on;
-  * optional ``nn.remat`` on blocks trades FLOPs for HBM.
+  * optional ``nn.remat`` on blocks trades FLOPs for HBM: the backward pass
+    runs each block forward again and keeps nothing of it, except the indexed
+    attention's output and selection thresholds (``attn_topk > 0``), which
+    cost 134 MB a layer where running its tile loops again cost a fifth of
+    the step (``ops.sparse_attention``).
 """
 
 from __future__ import annotations
@@ -583,7 +587,15 @@ class Encoder(nn.Module):
         cfg = self.cfg
         block_cls = Block
         if cfg.remat:
-            block_cls = nn.remat(Block, static_argnums=())
+            policy = None
+            if cfg.attn_topk > 0:
+                # the backward pass re-runs the block without the indexed
+                # attention's tile loops: their output (the `o` projection's
+                # input) and the selection's thresholds are kept
+                from ...ops.sparse_attention import REMAT_SAVED_NAMES
+
+                policy = jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED_NAMES)
+            block_cls = nn.remat(Block, static_argnums=(), policy=policy)
         for i in range(cfg.n_layers):
             x = block_cls(cfg, decode=self.decode, name=f"layer_{i}")(x, mask, positions)
         if cfg.norm_position == "post":

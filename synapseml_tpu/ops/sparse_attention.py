@@ -14,6 +14,15 @@ the causal upper triangle is never computed (each segment is a loop of its own
 in the compiled step: two keep three quarters of the square and the compile
 time near a dense layer's).
 
+What a step recomputes: the backward pass of a tile loop computes each tile's
+scores a second time from the kept thresholds of its selection (a tile's
+float32 scores, 537 MB at the benchmark's size, cannot be kept). A caller
+that rematerialises the whole layer (``Encoder`` with ``cfg.remat``) keeps the
+values named in ``REMAT_SAVED_NAMES``, the loops' output and those
+thresholds, so that its re-run of the layer holds no tile loop: 134 MB a layer
+at [2, 8192, 32, 128] bf16 against a third forward pass of the attention, a
+fifth of the step (PERF.md, PR 30).
+
 Device op names carry the scopes ``attn.indexer`` (index scores and the
 indexer's loss), ``attn.select`` (the exact top-k) and ``attn.sparse`` (the
 masked score, softmax and value products).
@@ -28,11 +37,14 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-__all__ = ["topk_mask", "indexed_attention"]
+__all__ = ["topk_mask", "indexed_attention", "REMAT_SAVED_NAMES"]
 
 F32 = jnp.float32
 MAX_SEGMENTS = 2
 _THRESHOLD = "attn_select_threshold"
+_OUT = "attn_indexed_out"
+# what a rematerialised caller keeps in place of running the tile loops again
+REMAT_SAVED_NAMES = (_OUT, _THRESHOLD)
 
 
 def topk_mask(scores: jax.Array, candidates: jax.Array, k: int) -> jax.Array:
@@ -193,4 +205,5 @@ def indexed_attention(q, k, v, qi, ki, wi, *, topk: int, q_tile: int = 512,
     # [tiles, B*KV, G*tile, D] -> [B, T, H, D]
     out = jnp.concatenate(outs, axis=0).reshape(n_tiles, b, kv, g, tile, d)
     out = out.transpose(1, 0, 4, 2, 3, 5).reshape(b, t + pad, h, d)[:, :t]
+    out = checkpoint_name(out, _OUT)
     return out, kl / (b * t), n_chosen / jnp.maximum(n_candidates, 1.0)

@@ -2,14 +2,17 @@
 share of the routed experts trains through `Trainer`: the program against the
 benchmark's plain float32 reference (`perfbench/reference/sparse_moe_lm.py`) at
 tiny widths on seeded random weights, the share test, exact routing under any
-imbalance, exact selection, and the counters a fit leaves behind."""
+imbalance, exact selection, what the blocks' rematerialisation keeps of the
+attention, and the counters a fit leaves behind."""
 
+import collections
 import copy
 import dataclasses
 import json
 import os
 import sys
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,6 +26,7 @@ from perfbench.programs import sparse_moe_lm as adapter  # noqa: E402
 from perfbench.reference import sparse_moe_lm as ref  # noqa: E402
 from synapseml_tpu.core import observability as obs  # noqa: E402
 from synapseml_tpu.models.flax_nets.llama import LlamaLM, next_token_labels  # noqa: E402
+from synapseml_tpu.models.flax_nets import transformer  # noqa: E402
 from synapseml_tpu.models.flax_nets.transformer import MoEBlock, TransformerConfig  # noqa: E402
 from synapseml_tpu.models.trainer import Trainer, TrainerConfig, cross_entropy_loss  # noqa: E402
 from synapseml_tpu.ops.grouped_ffn import expert_share_ffn  # noqa: E402
@@ -120,6 +124,110 @@ def test_the_indexer_loss_reaches_the_indexer_alone_and_the_lm_loss_everything_e
             assert float(jnp.abs(a).max()) == 0.0 and float(jnp.abs(b).max()) > 0, name
         else:
             assert float(jnp.abs(b).max()) == 0.0, name
+
+
+# ---- what the block's rematerialisation keeps ---------------------------------
+
+PAIRS = 2 * 2      # layers x key segments of `tiny_lm_loss`
+
+
+def tiny_lm_loss(remat=True):
+    """(loss of the parameters, parameters) of the tiny LM on 4 rows of 40
+    tokens: 5 tiles of 8 queries in 2 segments, top-k 8."""
+    config = tiny_config()
+    module = float32_module(config)
+    module = module.clone(cfg=dataclasses.replace(module.cfg, remat=remat))
+    trainer = Trainer(module, one_chip_mesh(), TrainerConfig(**adapter.trainer_options(config)))
+    batch = {k: jnp.asarray(v) for k, v in rows(3, 4, 40).items()}
+    params = adapter.to_program(ref.init_params(ref.sizes(config), 3), config)
+    return (lambda p: trainer.default_loss({"params": p}, batch, train=True)[0]), params
+
+
+def attention_work(jaxpr, scopes="", in_scan=False, found=None):
+    """What a jaxpr holds of the indexed attention, nested jaxprs included:
+    ``tile_loops`` (scans with a product of scope ``attn.sparse`` beneath
+    them), ``products`` (those products) and ``searches`` (the 32 counting
+    passes of ``topk_mask`` inside a tile loop)."""
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        here = f"{scopes}/{eqn.source_info.name_stack}"
+        scan = eqn.primitive.name == "scan"
+        if eqn.primitive.name == "dot_general" and "attn.sparse" in here:
+            found["products"] += 1
+        if scan and in_scan and eqn.params["length"] == 32 and "attn.select" in here:
+            found["searches"] += 1
+        before = found["products"]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            attention_work(sub, here, in_scan or scan, found)
+        if scan and found["products"] > before:
+            found["tile_loops"] += 1
+    return found
+
+
+def test_a_step_runs_each_tile_loop_forward_and_once_more_in_its_backward_and_searches_once():
+    loss_of, params = tiny_lm_loss(remat=True)
+    found = attention_work(jax.make_jaxpr(jax.grad(loss_of))(params).jaxpr)
+    # a loop forward and the loop of its backward pass, which computes each
+    # tile's scores again; none in the rematerialised block between them
+    assert found["tile_loops"] == 2 * PAIRS
+    # forward the score and value products; backward the score product again
+    # and two gradient products of each
+    assert found["products"] == (2 + 1 + 4) * PAIRS
+    assert found["searches"] == PAIRS
+
+
+@pytest.mark.parametrize("other", ["no_remat", "remat_that_keeps_nothing"])
+def test_gradients_do_not_depend_on_what_the_remat_keeps(other, monkeypatch):
+    loss_of, params = tiny_lm_loss(remat=True)
+    got = jax.jit(jax.grad(loss_of))(params)
+    if other == "no_remat":
+        loss_of, _ = tiny_lm_loss(remat=False)
+        rel = 1e-6                     # another program: float32 sums in another order
+    else:
+        remat = nn.remat               # `transformer.nn` is this module
+        monkeypatch.setattr(nn, "remat", lambda cls, **_: remat(cls, static_argnums=()))
+        loss_of, _ = tiny_lm_loss(remat=True)
+        # the attention's loops run again in place of reading what was kept
+        found = attention_work(jax.make_jaxpr(jax.grad(loss_of))(params).jaxpr)
+        assert found["tile_loops"] == 3 * PAIRS
+        rel = 0.0                      # the same arithmetic on the same values
+    want = jax.jit(jax.grad(loss_of))(params)
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
+                            jax.tree_util.tree_leaves(want)):
+        assert float(jnp.abs(a - b).max()) <= rel * float(jnp.abs(b).max()), \
+            jax.tree_util.keystr(path)
+
+
+class BareRematEncoder(nn.Module):
+    """`Encoder` with ``remat=True`` as it was before its remat kept anything."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, mask=None, positions=None):
+        block = nn.remat(transformer.Block, static_argnums=())
+        for i in range(self.cfg.n_layers):
+            x = block(self.cfg, name=f"layer_{i}")(x, mask, positions)
+        return x if self.cfg.norm_position == "post" else transformer._norm(self.cfg)(x)
+
+
+@pytest.mark.parametrize("cfg", [
+    TransformerConfig(hidden=32, n_layers=2, n_heads=4, mlp_dim=64, max_len=16,
+                      norm_position="post", remat=True),
+    TransformerConfig(hidden=32, n_layers=2, n_heads=4, n_kv_heads=2, mlp_dim=64, max_len=16,
+                      causal=True, use_rope=True, norm="rmsnorm", gated_mlp=True, act="silu",
+                      remat=True)], ids=["bert_shaped", "llama_shaped"])
+def test_a_module_without_indexed_attention_keeps_its_rematerialised_program(cfg):
+    x = jnp.ones((2, 8, cfg.hidden), jnp.float32)
+    mask = jnp.ones((2, 1, 1, 8), bool)
+    texts = []
+    for module in (transformer.Encoder(cfg), BareRematEncoder(cfg)):
+        variables = module.init(jax.random.PRNGKey(0), x, mask)
+        grad = jax.grad(lambda v, x: jnp.sum(module.apply(v, x, mask) ** 2))
+        texts.append((str(jax.make_jaxpr(grad)(variables, x)),
+                      jax.jit(grad).lower(variables, x).as_text()))
+    assert texts[0][0] == texts[1][0]
+    assert texts[0][1] == texts[1][1]
 
 
 # ---- selection ---------------------------------------------------------------
