@@ -81,47 +81,6 @@ TINY = dict(
 # accounting: compile vs run seconds, cache hits, peak device memory
 # ---------------------------------------------------------------------------
 
-class CompileMeter:
-    """Sums jax's own compile events, so a leg can say how much of its wall
-    time was tracing/lowering/compiling (first calls) and how many programs
-    came out of the persistent cache."""
-
-    def __init__(self):
-        import jax.monitoring as mon
-
-        self.totals = {"trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
-                       "programs": 0, "cache_hits": 0, "cache_writes": 0}
-        durations = {
-            "/jax/core/compile/jaxpr_trace_duration": "trace_s",
-            "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
-            "/jax/core/compile/backend_compile_duration": "compile_s"}
-        counts = {"/jax/compilation_cache/cache_hits": "cache_hits",
-                  "/jax/compilation_cache/cache_misses": "cache_writes"}
-
-        def on_duration(event, duration, **_):
-            key = durations.get(event)
-            if key:
-                self.totals[key] += duration
-                if key == "compile_s":
-                    self.totals["programs"] += 1
-
-        def on_event(event, **_):
-            key = counts.get(event)
-            if key:
-                self.totals[key] += 1
-
-        mon.register_event_duration_secs_listener(on_duration)
-        mon.register_event_listener(on_event)
-
-    def snapshot(self) -> dict:
-        return dict(self.totals)
-
-    def since(self, before: dict) -> dict:
-        out = {k: self.totals[k] - before[k] for k in self.totals}
-        return {k: (round(v, 2) if isinstance(v, float) else v)
-                for k, v in out.items()}
-
-
 def memory_facts(device) -> dict:
     """``peak_bytes_in_use`` is the process's high-water mark so far (the
     backend cannot reset it), so a later leg reports at least an earlier
@@ -867,6 +826,7 @@ def main(argv: list[str] | None = None) -> int:
     os.makedirs(args.out, exist_ok=True)
     os.environ.setdefault("SYNAPSEML_TPU_NATIVE_DIR",
                           os.path.join(args.out, "native"))
+    from perfbench.lib.compile_meter import CompileMeter
     from synapseml_tpu.core import batching as cb
     from synapseml_tpu.core.platform import (_visible_tpu_chips,
                                              enable_compile_cache)
@@ -925,12 +885,13 @@ def main(argv: list[str] | None = None) -> int:
             shutil.rmtree(leg.scratch, ignore_errors=True)
         record = {"ok": ok, "error": error,
                   "wall_s": round(time.perf_counter() - t0, 1),
-                  **ctx.meter.since(before), **memory_facts(dev), **leg.facts}
+                  **{k: round(v, 2) for k, v in ctx.meter.since(before).items()},
+                  **memory_facts(dev), **leg.facts}
         summary["legs"][name] = record
         print(f"LEG {name} " + json.dumps(
             {k: record[k] for k in ("ok", "error", "wall_s", "trace_s",
                                     "lower_s", "compile_s", "programs",
-                                    "cache_hits", "cache_writes",
+                                    "cache_hits", "cache_misses",
                                     "peak_bytes_in_use")}), flush=True)
         # drop executables whose closures hold a leg's weights
         cb.reset_compiled_cache()

@@ -94,8 +94,7 @@ class _LightGBMParams:
     histogram_impl = Param("histogram_impl", "histogram backend: segment "
                            "(scatter-add) | onehot (XLA matmul) | pallas "
                            "(fused VMEM one-hot kernel); equivalent results, "
-                           "pick by measurement "
-                           "(benchmarks/gbdt_hist_backends.py)",
+                           "pick by measurement",
                            default="segment",
                            validator=lambda v: v in ("segment", "onehot",
                                                      "pallas"))

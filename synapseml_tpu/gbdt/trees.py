@@ -56,7 +56,6 @@ class GrowthConfig(NamedTuple):
     # (row-chunked one-hot matmul — MXU-shaped but XLA materializes the
     # one-hot in HBM), or 'pallas' (fused kernel generating one-hot tiles in
     # VMEM — .pallas_hist). Equivalent results; pick by measurement
-    # (benchmarks/gbdt_hist_backends.py)
     hist_impl: str = "segment"
     # categorical features (sorted feature indices; their bins ARE the raw
     # category codes). Split finding is LightGBM's many-vs-many: bins sorted

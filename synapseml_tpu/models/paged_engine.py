@@ -241,9 +241,9 @@ class PagedDecodeEngine:
                  temperature: float = 0.0, top_k: int | None = None,
                  top_p: float | None = None, seed: int = 0,
                  eos_id: int | None = None, bucketer=None,
-                 instance: Any = None, fn_prefix: str = "llama_paged",
-                 donate_pages: bool = True, prefix_cache: bool = False,
-                 draft_tokens: int = 0, draft_layers: int | None = None,
+                 instance: Any = None, donate_pages: bool = True,
+                 prefix_cache: bool = False, draft_tokens: int = 0,
+                 draft_layers: int | None = None,
                  drafter: tuple | None = None):
         import jax.numpy as jnp
 
@@ -267,7 +267,6 @@ class PagedDecodeEngine:
         self.top_p = top_p
         self.seed = int(seed)
         self.bucketer = bucketer or cb.default_bucketer()
-        self._fn_prefix = fn_prefix
         self._instance = instance if instance is not None \
             else cb.instance_token(self)
         # decode slot rungs: ladder rungs <= max_slots, plus max_slots itself
@@ -390,7 +389,7 @@ class PagedDecodeEngine:
             return jax.jit(fn, donate_argnums=donate)
 
         return cb.get_compiled_cache().get(
-            f"{self._fn_prefix}_prefill",
+            "llama_paged_prefill",
             (B, P, self.max_blocks) + self._cfg_key(), _build,
             instance=self._instance, dtype="int32")
 
@@ -414,7 +413,7 @@ class PagedDecodeEngine:
             return jax.jit(fn, donate_argnums=donate)
 
         return cb.get_compiled_cache().get(
-            f"{self._fn_prefix}_decode",
+            "llama_paged_decode",
             (S, self.max_blocks) + self._cfg_key(), _build,
             instance=self._instance, dtype="int32")
 
@@ -452,7 +451,7 @@ class PagedDecodeEngine:
             return jax.jit(fn, donate_argnums=donate)
 
         return cb.get_compiled_cache().get(
-            f"{self._fn_prefix}_extend",
+            "llama_paged_extend",
             (B, Q, self.max_blocks) + self._cfg_key(), _build,
             instance=self._instance, dtype="int32")
 
@@ -545,7 +544,7 @@ class PagedDecodeEngine:
         mode = ("ext", self._draft_window) if self._drafter is not None \
             else ("self", self.draft_layers)
         return cb.get_compiled_cache().get(
-            f"{self._fn_prefix}_spec",
+            "llama_paged_spec",
             (S, self.max_blocks, K) + mode + self._cfg_key(), _build,
             instance=self._instance, dtype="int32")
 

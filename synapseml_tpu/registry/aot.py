@@ -122,7 +122,9 @@ def aot_mechanism() -> str | None:
             jnp.zeros((2,), jnp.float32)).compile()
         rexec = comp.runtime_executable()
         blob = rexec.client.serialize_executable(rexec)
-        de = rexec.client.deserialize_executable(bytes(blob), None)
+        # jaxlib 0.9.0 requires the devices the executable runs on
+        de = rexec.client.deserialize_executable(
+            bytes(blob), jax.local_devices()[:1])
         out = de.execute([jax.device_put(np.ones(2, np.float32))])
         if float(np.asarray(out[0])[0]) == 2.0:
             return "xla"
@@ -424,8 +426,8 @@ def _build_xla_callable(blob: bytes, entry: dict):
     import jax
     from jax import tree_util as jtu
 
-    client = jax.local_devices()[0].client
-    rexec = client.deserialize_executable(bytes(blob), None)
+    device = jax.local_devices()[0]
+    rexec = device.client.deserialize_executable(bytes(blob), [device])
     in_specs = [(tuple(s["shape"]), np.dtype(s["dtype"]))
                 for s in entry["in_specs"]]
     template = entry["out_template"]

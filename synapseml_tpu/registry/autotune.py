@@ -5,20 +5,17 @@ The second half of the TVM lesson (PAPERS.md, arXiv:1802.04799): kernel
 and record the verdict in the manifest — ``deploy.py`` / ``/admin/load``
 then pin the winners at load instead of trusting hardcoded defaults.
 
-The search harness is the same measurement discipline the standing
-``benchmarks/attn_backends.py`` / ``benchmarks/gbdt_hist_backends.py``
-decision benches use — per-candidate timing on the real stage at each
-ladder rung, warm-first then min-of-N — applied to the stage being
-published: any stage class declaring ``_AUTOTUNE_PARAMS = {"param":
-(candidates...)}`` gets each candidate timed through the serve-loop warmup
-drive (``io.serving.run_warmup``) at every bucket rung, and the winner per
-``(platform, rung)`` lands in the manifest's ``autotune`` section.
+The search times each candidate on the real stage at each ladder rung,
+warm-first then min-of-N: any stage class declaring ``_AUTOTUNE_PARAMS =
+{"param": (candidates...)}`` gets each candidate timed through the
+serve-loop warmup drive (``io.serving.run_warmup``) at every bucket rung,
+and the winner per ``(platform, rung)`` lands in the manifest's ``autotune``
+section.
 
 Backends whose cost lives outside the transform path (e.g. the GBDT
-``histogram_impl`` — a *training*-time kernel the hist-backends bench
-decides) feed in through ``winners`` overrides: pass the bench's verdict to
-``publish(autotune={"winners": {...}})`` and the load path pins it the same
-way. Winners only apply on the platform they were measured on — a manifest
+``histogram_impl`` — a *training*-time kernel) feed in through ``winners``
+overrides: pass a measured verdict to ``publish(autotune={"winners":
+{...}})`` and the load path pins it the same way. Winners only apply on the platform they were measured on — a manifest
 tuned on TPU loading into a CPU worker keeps the stage's saved defaults.
 """
 

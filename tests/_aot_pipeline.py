@@ -1,9 +1,8 @@
-"""Shared servable pipeline for the AOT deploy tests and the
-deploy-coldstart bench: JSON request bodies -> features -> ONNX MLP (the
-CompiledCache-adopted stage whose executables the registry AOT-compiles) ->
-reply dicts. Module-level classes so publish/load round-trips by class
-reference across processes (subprocess drivers add ``tests/`` to
-``sys.path``)."""
+"""Shared servable pipeline for the AOT deploy tests: JSON request bodies
+-> features -> ONNX MLP (the CompiledCache-adopted stage whose executables
+the registry AOT-compiles) -> reply dicts. Module-level classes so
+publish/load round-trips by class reference across processes (subprocess
+drivers add ``tests/`` to ``sys.path``)."""
 
 import numpy as np
 
@@ -78,7 +77,7 @@ class TunableAffine(Transformer):
 def make_mlp_onnx(din=4, dout=3, width=8, depth=2, seed=0,
                   mini_batch_size=64):
     """Hand-built ONNX MLP (no external onnx dependency — the repo's own
-    proto codec), depth controls compile-time signal for the bench."""
+    proto codec); depth sets how much there is to compile."""
     from synapseml_tpu.onnx import ONNXModel
     from synapseml_tpu.onnx import proto as P
     from synapseml_tpu.onnx.proto import (AttributeProto, GraphProto,

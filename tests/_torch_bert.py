@@ -18,7 +18,7 @@ import math
 import torch
 from torch import nn
 
-from _torch_resnet import _install_onnx_shim
+from _torch_resnet import onnx_shim
 
 
 class EinsumSelfAttention(nn.Module):
@@ -89,13 +89,14 @@ class TorchBertEncoder(nn.Module):
 
 def export_bert_onnx_bytes(model: nn.Module, ids: torch.Tensor,
                            mask: torch.Tensor) -> bytes:
-    _install_onnx_shim()
     model.eval()
     buf = io.BytesIO()
-    torch.onnx.export(
-        model, (ids, mask), buf, dynamo=False,
-        input_names=["input_ids", "attention_mask"], output_names=["logits"],
-        dynamic_axes={"input_ids": {0: "N", 1: "T"},
-                      "attention_mask": {0: "N", 1: "T"},
-                      "logits": {0: "N"}})
+    with onnx_shim():
+        torch.onnx.export(
+            model, (ids, mask), buf, dynamo=False,
+            input_names=["input_ids", "attention_mask"],
+            output_names=["logits"],
+            dynamic_axes={"input_ids": {0: "N", 1: "T"},
+                          "attention_mask": {0: "N", 1: "T"},
+                          "logits": {0: "N"}})
     return buf.getvalue()

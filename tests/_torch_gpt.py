@@ -12,7 +12,7 @@ import math
 import torch
 from torch import nn
 
-from _torch_resnet import _install_onnx_shim
+from _torch_resnet import onnx_shim
 
 
 class CausalBlock(nn.Module):
@@ -69,12 +69,12 @@ class TorchTinyGPT(nn.Module):
 
 def export_gpt_onnx_bytes(model: nn.Module, ids: torch.Tensor,
                           gather_idx: torch.Tensor) -> bytes:
-    _install_onnx_shim()
     model.eval()
     buf = io.BytesIO()
-    torch.onnx.export(
-        model, (ids, gather_idx), buf, dynamo=False,
-        input_names=["ids", "gather_idx"], output_names=["logits"],
-        dynamic_axes={"ids": {0: "N", 1: "T"}, "gather_idx": {0: "N"},
-                      "logits": {0: "N"}})
+    with onnx_shim():
+        torch.onnx.export(
+            model, (ids, gather_idx), buf, dynamo=False,
+            input_names=["ids", "gather_idx"], output_names=["logits"],
+            dynamic_axes={"ids": {0: "N", 1: "T"}, "gather_idx": {0: "N"},
+                          "logits": {0: "N"}})
     return buf.getvalue()

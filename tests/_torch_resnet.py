@@ -5,6 +5,7 @@ functions, which standard convnets don't have)."""
 
 from __future__ import annotations
 
+import contextlib
 import io
 import sys
 import types
@@ -83,11 +84,16 @@ def resnet_small(num_classes=10):
     return TorchResNet((1, 1), num_classes, width0=8)
 
 
-def _install_onnx_shim():
-    """Minimal stand-in for the ``onnx`` package backed by our proto codec:
-    torch's TorchScript exporter imports it only to scan for custom
-    onnxscript functions (none in plain convnets)."""
-    if "onnx" in sys.modules:
+@contextlib.contextmanager
+def onnx_shim():
+    """Minimal stand-in for the ``onnx`` package backed by our proto codec,
+    in ``sys.modules`` for the length of a ``torch.onnx.export`` only:
+    torch's TorchScript exporter imports it to scan for custom onnxscript
+    functions (none in plain convnets). Left behind, a module without a
+    ``__spec__`` breaks every later ``importlib.util.find_spec("onnx")`` in
+    the process (``transformers`` makes one at import)."""
+    if "onnx" in sys.modules:  # the real package: nothing to stand in for
+        yield
         return
     from synapseml_tpu.onnx.proto import parse_model
 
@@ -99,13 +105,17 @@ def _install_onnx_shim():
     shim = types.ModuleType("onnx")
     shim.load_model_from_string = lambda b: _Model(parse_model(b))
     sys.modules["onnx"] = shim
+    try:
+        yield
+    finally:
+        sys.modules.pop("onnx", None)
 
 
 def export_onnx_bytes(model: nn.Module, example: torch.Tensor) -> bytes:
-    _install_onnx_shim()
     model.eval()
     buf = io.BytesIO()
-    torch.onnx.export(model, example, buf, dynamo=False,
-                      input_names=["input"], output_names=["logits"],
-                      dynamic_axes={"input": {0: "N"}, "logits": {0: "N"}})
+    with onnx_shim():
+        torch.onnx.export(model, example, buf, dynamo=False,
+                          input_names=["input"], output_names=["logits"],
+                          dynamic_axes={"input": {0: "N"}, "logits": {0: "N"}})
     return buf.getvalue()

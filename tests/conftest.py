@@ -44,6 +44,30 @@ def rng():
     return np.random.default_rng(42)
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _no_specless_modules_left_behind():
+    """A test file leaves ``sys.modules`` fit for the next file on its
+    worker: a stand-in planted without a ``__spec__`` makes every later
+    ``importlib.util.find_spec(name)`` in the process raise (``transformers``
+    probes its optional packages so at import), and which file meets it
+    hangs on how xdist deals the files out. Only public top-level names are
+    held to it, the ones such a probe asks for: extension modules register
+    private ABI modules (``_cython_3_2_4``) and dotted submodules
+    (``cv2.utils.fs``) without a spec, and nothing looks those up."""
+    import sys
+
+    before = set(sys.modules)
+    yield
+    left = sorted(name for name, mod in list(sys.modules.items())
+                  if name not in before and name != "__main__"
+                  and "." not in name and not name.startswith("_")
+                  and getattr(mod, "__spec__", None) is None)
+    if left:
+        pytest.fail(f"this file left modules without a __spec__ in "
+                    f"sys.modules: {left}; remove them after use or give "
+                    f"them an importlib.machinery.ModuleSpec", pytrace=False)
+
+
 def make_tabular_df(n=200, d=8, classes=2, seed=0, num_partitions=2):
     """Shared synthetic dataset builder (TestBase makeBasicDF analog)."""
     from synapseml_tpu.core import DataFrame

@@ -237,6 +237,13 @@ def test_store_gc_prunes_orphans_keeps_referenced(tmp_path, fresh_cache):
 # /admin/load: the zero-cold-start acceptance surface
 # ---------------------------------------------------------------------------
 
+def _why(wu, mechanism=None):
+    """What a failed zero-trace assertion must say: which mechanism the
+    probe found on this machine (``"export"`` compiles at load and keeps the
+    rung cap) and the whole warm-up summary."""
+    return f"aot_mechanism()={mechanism or raot.aot_mechanism()!r}, warmup={wu}"
+
+
 def _serve_placeholder():
     from synapseml_tpu.io.serving import serve_pipeline
 
@@ -257,10 +264,10 @@ def test_admin_load_aot_serves_first_request_with_zero_traces(tmp_path,
                                "model": "mlp", "ref": "v1"})
         assert status == 200 and reply["ok"]
         wu = reply["warmup"]
-        assert wu["mode"] == "aot" and wu["fallback_reason"] is None
-        assert wu["aot_hits"] == len(BUCKETS)
+        assert wu["mode"] == "aot" and wu["fallback_reason"] is None, _why(wu)
+        assert wu["aot_hits"] == len(BUCKETS), _why(wu)
         assert wu["executables_loaded"] == len(BUCKETS)
-        assert wu["executables_traced"] == 0
+        assert wu["executables_traced"] == 0, _why(wu)
         assert wu["compile_ms"] == 0.0 and wu["io_ms"] > 0
         # first post-swap request over HTTP, then direct transforms at
         # every ladder rung (7->8, 12->16, 30->32): ZERO new traces —
@@ -336,7 +343,8 @@ def test_warmup_cap_lifted_when_aot_present(tmp_path, fresh_cache):
         wu = reply["warmup"]
         # default JIT warmup stops at rungs <= 64; with AOT blobs the full
         # published ladder (incl. 128/256) maps in with zero compiles
-        assert wu["aot_hits"] == len(big) and wu["executables_traced"] == 0
+        assert wu["aot_hits"] == len(big) and wu["executables_traced"] == 0, \
+            _why(wu)
         misses0 = cb.get_compiled_cache().miss_count("onnx_model")
         status, out = _post(srv.address, "/",
                             sample_rows(1, seed=9)[0])
@@ -562,6 +570,7 @@ _SERVE_DRIVER = textwrap.dedent("""
     from synapseml_tpu.core import batching as cb
     from synapseml_tpu.core.pipeline import Transformer
     from synapseml_tpu.io.serving import serve_pipeline
+    from synapseml_tpu.registry.aot import aot_mechanism
 
     class Placeholder(Transformer):
         def _transform(self, df):
@@ -587,6 +596,7 @@ _SERVE_DRIVER = textwrap.dedent("""
     preds = [post("/", b) for b in sample_rows(6, seed=42)]
     print(json.dumps({{
         "warmup": reply["warmup"],
+        "mechanism": aot_mechanism(),
         "miss_delta": cache.miss_count("onnx_model") - misses0,
         "aot_hits": cache.stats()["aot_hits"],
         "preds": preds,
@@ -639,8 +649,9 @@ def test_cross_process_publish_then_zero_trace_serve(tmp_path):
     wu = serve_out["warmup"]
     # the acceptance criterion: a FRESH process serves the ladder with
     # zero traces — every executable came from the artifact's blobs
-    assert wu["mode"] == "aot", wu
-    assert wu["executables_traced"] == 0 and wu["compile_ms"] == 0.0
+    why = _why(wu, serve_out["mechanism"])
+    assert wu["mode"] == "aot", why
+    assert wu["executables_traced"] == 0 and wu["compile_ms"] == 0.0, why
     assert serve_out["miss_delta"] == 0
     assert serve_out["aot_hits"] == 3
     # and the served predictions equal the publisher's direct transform

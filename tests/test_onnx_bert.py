@@ -17,6 +17,7 @@ sys.path.insert(0, str(Path(__file__).parent))
 torch = pytest.importorskip("torch")
 
 from _torch_bert import TorchBertEncoder, export_bert_onnx_bytes  # noqa: E402
+from _torch_resnet import onnx_shim  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -101,9 +102,10 @@ def test_sentence_transformer_head_export_parity():
     mask = torch.ones(3, 12, dtype=torch.long)
     mask[2, 7:] = 0
     buf = io.BytesIO()
-    torch.onnx.export(model, (ids, mask), buf,
-                      input_names=["input_ids", "attention_mask"],
-                      output_names=["embedding"], dynamo=False)
+    with onnx_shim():
+        torch.onnx.export(model, (ids, mask), buf,
+                          input_names=["input_ids", "attention_mask"],
+                          output_names=["embedding"], dynamo=False)
     with torch.no_grad():
         want = model(ids, mask).numpy()
     conv = convert_graph(buf.getvalue())

@@ -19,7 +19,7 @@ torch = pytest.importorskip("torch")
 from torch import nn  # noqa: E402
 import torch.nn.functional as F  # noqa: E402
 
-from _torch_resnet import _install_onnx_shim  # noqa: E402
+from _torch_resnet import onnx_shim  # noqa: E402
 
 
 class MixedNet(nn.Module):
@@ -46,13 +46,14 @@ class MixedNet(nn.Module):
 
 @pytest.fixture(scope="module")
 def exported():
-    _install_onnx_shim()
     torch.manual_seed(0)
     model = MixedNet().eval()
     buf = io.BytesIO()
-    torch.onnx.export(model, (torch.randn(2, 3, 4, 4),), buf, dynamo=False,
-                      input_names=["x"], output_names=["vals", "idx"],
-                      dynamic_axes={"x": {0: "N"}})
+    with onnx_shim():
+        torch.onnx.export(model, (torch.randn(2, 3, 4, 4),), buf,
+                          dynamo=False, input_names=["x"],
+                          output_names=["vals", "idx"],
+                          dynamic_axes={"x": {0: "N"}})
     return model, buf.getvalue()
 
 
@@ -180,8 +181,9 @@ def test_unet_style_export_parity(tmp_path):
     model = MiniUNet().eval()
     x = np.random.default_rng(12).normal(size=(1, 3, 16, 16)).astype(np.float32)
     buf = io.BytesIO()
-    torch.onnx.export(model, (torch.tensor(x),), buf, input_names=["x"],
-                      output_names=["y"], dynamo=False)
+    with onnx_shim():
+        torch.onnx.export(model, (torch.tensor(x),), buf, input_names=["x"],
+                          output_names=["y"], dynamo=False)
     with torch.no_grad():
         want = model(torch.tensor(x)).numpy()
     from synapseml_tpu.onnx import convert_graph

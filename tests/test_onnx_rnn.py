@@ -17,7 +17,7 @@ sys.path.insert(0, str(Path(__file__).parent))
 torch = pytest.importorskip("torch")
 from torch import nn  # noqa: E402
 
-from _torch_resnet import _install_onnx_shim  # noqa: E402
+from _torch_resnet import onnx_shim  # noqa: E402
 
 
 class RecNet(nn.Module):
@@ -34,10 +34,10 @@ class RecNet(nn.Module):
 
 
 def _export(model, args, **kw):
-    _install_onnx_shim()
     model.eval()
     buf = io.BytesIO()
-    torch.onnx.export(model, args, buf, dynamo=False, **kw)
+    with onnx_shim():
+        torch.onnx.export(model, args, buf, dynamo=False, **kw)
     return buf.getvalue()
 
 
@@ -48,6 +48,20 @@ def exported():
     data = _export(model, (torch.randn(10, 3, 8),), input_names=["x"],
                    output_names=["y"])
     return model, data
+
+
+def test_export_leaves_sys_modules_as_it_found_them():
+    """The ``onnx`` stand-in lives for the export only: afterwards the name
+    resolves (or fails to) exactly as before, and ``find_spec`` does not meet
+    a module without a ``__spec__`` (what ``transformers`` died of when it
+    shared a worker with an exporting file)."""
+    import importlib.util
+
+    before = sys.modules.get("onnx")
+    _export(nn.Linear(4, 2), (torch.randn(3, 4),), input_names=["x"],
+            output_names=["y"])
+    assert sys.modules.get("onnx") is before
+    importlib.util.find_spec("onnx")  # ValueError at the parent
 
 
 def test_rnn_export_ops_all_supported(exported):

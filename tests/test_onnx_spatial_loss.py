@@ -16,7 +16,7 @@ torch = pytest.importorskip("torch")
 import torch.nn.functional as F  # noqa: E402
 from torch import nn  # noqa: E402
 
-from _torch_resnet import _install_onnx_shim  # noqa: E402
+from _torch_resnet import onnx_shim  # noqa: E402
 
 from synapseml_tpu.onnx.convert import OP_REGISTRY  # noqa: E402
 
@@ -46,16 +46,16 @@ class SamplerNet(nn.Module):
 def test_grid_sample_matches_torch_export(mode, padding_mode, align_corners):
     from synapseml_tpu.onnx import convert_graph
 
-    _install_onnx_shim()
     torch.manual_seed(0)
     model = SamplerNet(mode, padding_mode, align_corners).eval()
     x = torch.randn(2, 3, 5, 7)
     # grid spills past [-1, 1] so the padding mode actually matters
     grid = (torch.rand(2, 4, 6, 2) * 2.6 - 1.3)
     buf = io.BytesIO()
-    torch.onnx.export(model, (x, grid), buf, dynamo=False,
-                      input_names=["x", "grid"], output_names=["y"],
-                      opset_version=16)
+    with onnx_shim():
+        torch.onnx.export(model, (x, grid), buf, dynamo=False,
+                          input_names=["x", "grid"], output_names=["y"],
+                          opset_version=16)
     conv = convert_graph(buf.getvalue())
     got = np.asarray(conv(x=x.numpy(), grid=grid.numpy())["y"])
     with torch.no_grad():
@@ -82,7 +82,6 @@ class CELossNet(nn.Module):
 def test_softmax_ce_loss_matches_torch_export(reduction, weighted):
     from synapseml_tpu.onnx import convert_graph
 
-    _install_onnx_shim()
     torch.manual_seed(1)
     weight = torch.rand(5) + 0.5 if weighted else None
     model = CELossNet(weight=weight, ignore_index=3,
@@ -90,8 +89,10 @@ def test_softmax_ce_loss_matches_torch_export(reduction, weighted):
     scores = torch.randn(8, 5)
     labels = torch.tensor([0, 1, 2, 3, 4, 0, 3, 2])  # two ignored rows
     buf = io.BytesIO()
-    torch.onnx.export(model, (scores, labels), buf, dynamo=False,
-                      input_names=["scores", "labels"], output_names=["loss"])
+    with onnx_shim():
+        torch.onnx.export(model, (scores, labels), buf, dynamo=False,
+                          input_names=["scores", "labels"],
+                          output_names=["loss"])
     conv = convert_graph(buf.getvalue())
     got = np.asarray(conv(scores=scores.numpy(), labels=labels.numpy())["loss"])
     with torch.no_grad():
