@@ -11,10 +11,13 @@ modules built for GSPMD):
     decode-time KV cache; the sequence axis is ready for ring attention
     (``ops.ring_attention``) when seq-parallel is on;
   * optional ``nn.remat`` on blocks trades FLOPs for HBM: the backward pass
-    runs each block forward again and keeps nothing of it, except the indexed
-    attention's output and selection thresholds (``attn_topk > 0``), which
-    cost 134 MB a layer where running its tile loops again cost a fifth of
-    the step (``ops.sparse_attention``).
+    runs each block forward again and keeps nothing of it, except what the
+    indexed attention's hand-written backward pass reads (``attn_topk > 0``):
+    its output, its selection's thresholds and two row statistics, 136 MB a
+    layer where running its tile loops again cost a fifth of the step. That
+    backward pass computes the indexer's head products, the index scores and
+    the attention scores again, and no statistic or search
+    (``ops.sparse_attention``).
 """
 
 from __future__ import annotations
@@ -590,8 +593,8 @@ class Encoder(nn.Module):
             policy = None
             if cfg.attn_topk > 0:
                 # the backward pass re-runs the block without the indexed
-                # attention's tile loops: their output (the `o` projection's
-                # input) and the selection's thresholds are kept
+                # attention's tile loops: what their own backward pass reads
+                # (output, thresholds, row statistics) is kept
                 from ...ops.sparse_attention import REMAT_SAVED_NAMES
 
                 policy = jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED_NAMES)

@@ -3,7 +3,8 @@ share of the routed experts trains through `Trainer`: the program against the
 benchmark's plain float32 reference (`perfbench/reference/sparse_moe_lm.py`) at
 tiny widths on seeded random weights, the share test, exact routing under any
 imbalance, exact selection, what the blocks' rematerialisation keeps of the
-attention, and the counters a fit leaves behind."""
+attention, the tile's hand-written backward pass against autodiff, and the
+counters a fit leaves behind."""
 
 import collections
 import copy
@@ -131,12 +132,12 @@ def test_the_indexer_loss_reaches_the_indexer_alone_and_the_lm_loss_everything_e
 PAIRS = 2 * 2      # layers x key segments of `tiny_lm_loss`
 
 
-def tiny_lm_loss(remat=True):
+def tiny_lm_loss(remat=True, dtype=jnp.float32):
     """(loss of the parameters, parameters) of the tiny LM on 4 rows of 40
     tokens: 5 tiles of 8 queries in 2 segments, top-k 8."""
     config = tiny_config()
-    module = float32_module(config)
-    module = module.clone(cfg=dataclasses.replace(module.cfg, remat=remat))
+    module = adapter.build(config)
+    module = module.clone(cfg=dataclasses.replace(module.cfg, remat=remat, dtype=dtype))
     trainer = Trainer(module, one_chip_mesh(), TrainerConfig(**adapter.trainer_options(config)))
     batch = {k: jnp.asarray(v) for k, v in rows(3, 4, 40).items()}
     params = adapter.to_program(ref.init_params(ref.sizes(config), 3), config)
@@ -146,26 +147,43 @@ def tiny_lm_loss(remat=True):
 def attention_work(jaxpr, scopes="", in_scan=False, found=None):
     """What a jaxpr holds of the indexed attention, nested jaxprs included:
     ``tile_loops`` (scans with a product of scope ``attn.sparse`` beneath
-    them), ``products`` (those products) and ``searches`` (the 32 counting
-    passes of ``topk_mask`` inside a tile loop)."""
+    them), ``products`` (those products), ``searches`` (the 32 counting
+    passes of ``topk_mask`` inside a tile loop), ``operands_<dtype>`` (operands
+    of that dtype of the attention's and the indexer's products) and, inside the
+    tile loops of the forward pass (two products) and of the backward pass
+    (more), ``forward_`` / ``backward_row_maxima`` (``reduce_max`` over the key
+    axis) and ``_score_exps`` (``exp`` of a [batch, head, query, key] value)."""
     found = collections.Counter() if found is None else found
     for eqn in jaxpr.eqns:
         here = f"{scopes}/{eqn.source_info.name_stack}"
-        scan = eqn.primitive.name == "scan"
-        if eqn.primitive.name == "dot_general" and "attn.sparse" in here:
+        name = eqn.primitive.name
+        scan = name == "scan"
+        if name == "dot_general" and "attn.sparse" in here:
             found["products"] += 1
+        if name == "dot_general" and ("attn.sparse" in here or "attn.indexer" in here):
+            found.update(f"operands_{v.aval.dtype.name}" for v in eqn.invars)
         if scan and in_scan and eqn.params["length"] == 32 and "attn.select" in here:
             found["searches"] += 1
-        before = found["products"]
+        if name == "reduce_max" and eqn.invars[0].aval.ndim - 1 in eqn.params["axes"]:
+            found["row_maxima"] += 1
+        if name == "exp" and eqn.outvars[0].aval.ndim == 4:
+            found["score_exps"] += 1
+        before = dict(found)
         for sub in jax.core.jaxprs_in_params(eqn.params):
             attention_work(sub, here, in_scan or scan, found)
-        if scan and found["products"] > before:
+        if scan and found["products"] > before.get("products", 0):
             found["tile_loops"] += 1
+            kind = "forward_" if found["products"] - before.get("products", 0) == 2 \
+                else "backward_"
+            for key in ("row_maxima", "score_exps"):
+                found[kind + key] += found[key] - before.get(key, 0)
     return found
 
 
-def test_a_step_runs_each_tile_loop_forward_and_once_more_in_its_backward_and_searches_once():
-    loss_of, params = tiny_lm_loss(remat=True)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_a_step_runs_each_tile_loop_forward_and_once_more_in_its_backward_and_searches_once(
+        dtype):
+    loss_of, params = tiny_lm_loss(remat=True, dtype=dtype)
     found = attention_work(jax.make_jaxpr(jax.grad(loss_of))(params).jaxpr)
     # a loop forward and the loop of its backward pass, which computes each
     # tile's scores again; none in the rematerialised block between them
@@ -174,6 +192,14 @@ def test_a_step_runs_each_tile_loop_forward_and_once_more_in_its_backward_and_se
     # and two gradient products of each
     assert found["products"] == (2 + 1 + 4) * PAIRS
     assert found["searches"] == PAIRS
+    # the backward pass forms the probabilities from the kept row statistics,
+    # in one pass over the scores: no row maximum (the forward's two a loop:
+    # scores and index scores), one exponential of score shape
+    assert found["forward_row_maxima"] == 2 * PAIRS and found["backward_row_maxima"] == 0
+    assert found["forward_score_exps"] == found["backward_score_exps"] == PAIRS
+    # no product takes a float32 operand where the inputs are bfloat16: the
+    # score and head gradients leave their fusions in the keys' dtype
+    assert {k for k in found if k.startswith("operands_")} == {f"operands_{dtype.__name__}"}
 
 
 @pytest.mark.parametrize("other", ["no_remat", "remat_that_keeps_nothing"])
@@ -275,6 +301,91 @@ def test_no_key_outside_the_selection_has_weight():
     assert bool(jnp.all(out[:, -1] == out2[:, -1]))
     assert float(share) == pytest.approx(
         sum(min(i + 1, topk) for i in range(t)) / (t * (t + 1) / 2))
+
+
+# ---- the tile's hand-written backward pass --------------------------------------
+
+def plain_indexed_attention(q, k, v, qi, ki, wi, topk, kv_mask=None):
+    """``indexed_attention``'s arithmetic over the whole causal square at
+    once, for autodiff to differentiate: no tiles, no segments, nothing kept
+    and nothing written by hand. Returns ``(out, kl)``."""
+    b, t, h, d = q.shape
+    hi, di = qi.shape[2:]
+    f32 = jnp.float32
+
+    def masked_softmax(x, mask, log=False):
+        x = jnp.where(mask, x, jnp.finfo(f32).min)
+        shifted = x - jax.lax.stop_gradient(jnp.max(x, axis=-1, keepdims=True))
+        if log:
+            return shifted - jnp.log(jnp.sum(jnp.exp(shifted), axis=-1, keepdims=True))
+        return jnp.exp(shifted) / jnp.sum(jnp.exp(shifted), axis=-1, keepdims=True)
+
+    candidates = jnp.broadcast_to(jnp.tril(jnp.ones((t, t), bool)), (b, t, t))
+    if kv_mask is not None:
+        candidates = candidates & kv_mask[:, None, :]
+    head = jnp.einsum("btjd,bsd->btjs", qi, ki, preferred_element_type=f32)
+    index = jnp.sum(jax.nn.relu(head) * wi.astype(f32)[..., None], axis=2) / np.sqrt(hi * di)
+    chosen = topk_mask(index, candidates, topk)
+    keys, values = (jnp.repeat(x, h // k.shape[2], axis=2) for x in (k, v))
+    scores = jnp.einsum("bthd,bshd->bhts", q, keys, preferred_element_type=f32) / np.sqrt(d)
+    probs = masked_softmax(scores, chosen[:, None])
+    out = jnp.einsum("bhts,bshd->bthd", probs.astype(v.dtype), values)
+    target = jax.lax.stop_gradient(jnp.mean(probs, axis=1))
+    live = chosen & (target > 0)
+    kl = jnp.where(live, target * (jnp.log(jnp.where(live, target, 1.0))
+                                   - masked_softmax(index, chosen, log=True)), 0.0)
+    return out, jnp.sum(kl) / (b * t)
+
+
+def attention_inputs(t, kv, dtype=jnp.float32, b=2, h=4, d=8, hi=2, di=4):
+    keys = jax.random.split(jax.random.PRNGKey(t + kv), 7)
+    shapes = [(b, t, h, d), (b, t, kv, d), (b, t, kv, d), (b, t, hi, di), (b, t, di), (b, t, hi)]
+    inputs = [jax.random.normal(key, shape).astype(dtype) for key, shape in zip(keys, shapes)]
+    return inputs, jax.random.normal(keys[6], (b, t, h, d)).astype(dtype)
+
+
+def attention_gradients(fn, inputs, d_out, d_kl):
+    """Gradients to all six inputs of ``sum(out * d_out) + d_kl * kl``."""
+    def scalar(*xs):
+        out, kl = fn(*xs)[:2]
+        return jnp.sum(out.astype(jnp.float32) * d_out.astype(jnp.float32)) + d_kl * kl
+
+    return jax.jit(jax.grad(scalar, argnums=tuple(range(6))))(*inputs)
+
+
+@pytest.mark.parametrize("t,topk,kv,padded", [
+    (6, 8, 2, 0), (8, 8, 2, 0), (24, 8, 2, 0), (21, 8, 2, 0), (24, 8, 2, 5), (24, 5, 4, 0),
+    (40, 8, 2, 0)],
+    ids=["T_below_topk", "T_at_topk", "T_above_topk", "T_not_a_multiple_of_the_tile",
+         "padded_keys", "one_query_head_a_key_head", "five_tiles_in_two_segments"])
+def test_the_hand_written_backward_pass_gives_autodiffs_gradients(t, topk, kv, padded):
+    # 4 query heads over `kv` key heads, tiles of 8 queries: every case but
+    # the first two has two key segments
+    inputs, d_out = attention_inputs(t, kv)
+    kv_mask = None if not padded else \
+        jnp.arange(t)[None, :] < jnp.asarray([[t], [t - padded]])
+    got = attention_gradients(
+        lambda *xs: indexed_attention(*xs, topk=topk, q_tile=8, kv_mask=kv_mask),
+        inputs, d_out, 0.7)
+    want = attention_gradients(
+        lambda *xs: plain_indexed_attention(*xs, topk, kv_mask), inputs, d_out, 0.7)
+    for name, a, b in zip(("q", "k", "v", "qi", "ki", "wi"), got, want):
+        assert float(jnp.abs(b).max()) > 0, name
+        assert float(jnp.abs(a - b).max()) <= 2e-6 * float(jnp.abs(b).max()), name
+
+
+def test_in_bfloat16_the_gradients_stay_within_its_rounding_of_float32s():
+    # the products take bfloat16 operands and add in float32: the float32
+    # gradients of the same (bfloat16) numbers are the measure
+    inputs, d_out = attention_inputs(24, 2, jnp.bfloat16)
+    attend = lambda *xs: indexed_attention(*xs, topk=8, q_tile=8)  # noqa: E731
+    got = attention_gradients(attend, inputs, d_out, 0.7)
+    want = attention_gradients(attend, [x.astype(jnp.float32) for x in inputs],
+                               d_out.astype(jnp.float32), 0.7)
+    for name, a, b in zip(("q", "k", "v", "qi", "ki", "wi"), got, want):
+        assert a.dtype == jnp.bfloat16, name
+        assert float(jnp.abs(a.astype(jnp.float32) - b).max()) \
+            <= 2 ** -6 * float(jnp.abs(b).max()), name
 
 
 # ---- the experts' share --------------------------------------------------------
