@@ -4,6 +4,11 @@ Reference analog: ``hf/HuggingFaceCausalLMTransform.py:103-331`` loads torch
 models per-partition; here a native Flax decoder (RMSNorm + SwiGLU + RoPE +
 GQA) whose weights shard over the tensor/fsdp mesh axes — the Llama-2-7B
 sharded-inference target of BASELINE.md rides this module.
+
+Two builders give `LlamaLM` a decoder that is no Llama: `sparse_moe_lm`
+(learned sparse attention, routed experts in every layer) and
+`hybrid_conv_moe_lm` (gated short-convolution layers among full-attention
+layers, a dense lead, sigmoid-routed experts, embedding and head tied).
 """
 
 from __future__ import annotations
@@ -17,7 +22,8 @@ from .transformer import (Encoder, MlpBlock, MoEBlock, TransformerConfig,
                           _norm, apply_rope, make_causal_mask,
                           rope_frequencies)
 
-__all__ = ["llama2_7b", "llama_tiny", "sparse_moe_lm", "next_token_labels", "LlamaLM",
+__all__ = ["llama2_7b", "llama_tiny", "sparse_moe_lm", "hybrid_conv_moe_lm",
+           "next_token_labels", "LlamaLM",
            "generate", "greedy_generate",
            "PagedLlamaLM", "paged_prefill", "paged_decode_step",
            "paged_extend", "paged_verify", "early_exit_params"]
@@ -59,6 +65,34 @@ def sparse_moe_lm(**kw) -> TransformerConfig:
     return TransformerConfig(**defaults)
 
 
+def hybrid_conv_moe_lm(**kw) -> TransformerConfig:
+    """A decoder of gated short-convolution layers with a full-attention layer
+    every fourth, at the published sizes of LFM2-24B-A2B (config.json of
+    LiquidAI/LFM2-24B-A2B, `lfm2_moe`): 40 layers, 30 'conv' (3 taps) and 10
+    GQA 32/8 heads of 64 with per-head q/k RMSNorm through the flash kernel;
+    the two leading layers a dense gated MLP of width 11776, the others 64
+    gated experts of width 1536, 4 a token, chosen by sigmoid score plus a
+    constant selection bias; no biases; embedding and head tied. One chip's
+    share is ``moe_experts`` under ``moe_total_experts`` from
+    ``moe_first_expert``, a smaller ``vocab_size``, and fewer layers
+    (``n_layers``, ``layer_types``, ``moe_dense_layers`` together)."""
+    defaults = dict(vocab_size=65536, hidden=2048, n_layers=40, n_heads=32,
+                    n_kv_heads=8, head_dim=64, mlp_dim=11776, moe_mlp_dim=1536,
+                    max_len=128000, norm="rmsnorm", norm_eps=1e-5, act="silu",
+                    gated_mlp=True, mlp_bias=False, causal=True, use_rope=True,
+                    rope_theta=1e6, attn_bias=False, qk_norm=True, attn_impl="flash",
+                    flash_block=512, moe_dense_layers=2,
+                    moe_experts=64, moe_total_experts=64, moe_top_k=4,
+                    moe_dispatch="grouped", moe_bias=False, moe_router="sigmoid",
+                    tie_embeddings=True)
+    defaults.update(kw)
+    if "layer_types" not in defaults:   # the published pattern, as far as n_layers goes
+        defaults["layer_types"] = tuple(
+            "full_attention" if i % 4 == 2 else "conv"
+            for i in range(defaults["n_layers"]))
+    return TransformerConfig(**defaults)
+
+
 def next_token_labels(input_ids, ignore: int = -100):
     """Labels of a packed row for `Trainer`'s loss: ``labels[:, t]`` is
     ``input_ids[:, t + 1]``, the last position ``ignore`` (negative: left out
@@ -79,10 +113,12 @@ class LlamaLM(nn.Module):
     @nn.compact
     def __call__(self, input_ids, positions=None, attention_mask=None):
         cfg = self.cfg
-        x = nn.Embed(cfg.vocab_size, cfg.hidden, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                     embedding_init=nn.with_logical_partitioning(
-                         nn.initializers.normal(0.02), ("vocab", "embed")),
-                     name="embed")(input_ids)
+        embed = nn.Embed(cfg.vocab_size, cfg.hidden, dtype=cfg.dtype,
+                         param_dtype=cfg.param_dtype,
+                         embedding_init=nn.with_logical_partitioning(
+                             nn.initializers.normal(0.02), ("vocab", "embed")),
+                         name="embed")
+        x = embed(input_ids)
         if cfg.learned_pos:  # GPT-2-family absolute position embeddings
             B, T = input_ids.shape
             pos = (positions if positions is not None
@@ -97,12 +133,16 @@ class LlamaLM(nn.Module):
         if attention_mask is not None:
             mask = attention_mask[:, None, None, :].astype(bool)
         x = Encoder(cfg, decode=self.decode, name="decoder")(x, mask, positions)
-        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
-                          param_dtype=cfg.param_dtype,
-                          kernel_init=nn.with_logical_partitioning(
-                              nn.initializers.normal(0.02), ("embed", "vocab")),
-                          name="lm_head")(x)
-        return logits
+        if cfg.tie_embeddings:
+            # one leaf, used twice: its gradient is the sum of both uses. The
+            # product as the untied head's (float32 operands and result)
+            return jnp.einsum("bth,vh->btv", x.astype(jnp.float32),
+                              embed.embedding.astype(jnp.float32))
+        return nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
+                        param_dtype=cfg.param_dtype,
+                        kernel_init=nn.with_logical_partitioning(
+                            nn.initializers.normal(0.02), ("embed", "vocab")),
+                        name="lm_head")(x)
 
 
 def _make_selector(temperature: float, top_k: int | None, top_p: float | None):

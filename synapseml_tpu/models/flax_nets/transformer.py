@@ -10,6 +10,9 @@ modules built for GSPMD):
   * attention is einsum-based with optional GQA + rotary embeddings and a
     decode-time KV cache; the sequence axis is ready for ring attention
     (``ops.ring_attention``) when seq-parallel is on;
+  * a stack's layers may differ (``layer_types``, ``moe_dense_layers``): a
+    gated short convolution (``ShortConv``, ``ops.short_conv``) or attention
+    as the token mixer, a dense MLP or routed experts as the feed-forward;
   * optional ``nn.remat`` on blocks trades FLOPs for HBM: the backward pass
     runs each block forward again and keeps nothing of it, except what the
     indexed attention's hand-written backward pass reads (``attn_topk > 0``):
@@ -22,6 +25,7 @@ modules built for GSPMD):
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Callable
 
@@ -30,8 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["TransformerConfig", "Attention", "MlpBlock", "Block", "Encoder", "RMSNorm",
-           "apply_rope", "make_causal_mask"]
+__all__ = ["TransformerConfig", "Attention", "MlpBlock", "ShortConv", "Block", "Encoder",
+           "RMSNorm", "apply_rope", "make_causal_mask"]
 
 Dtype = Any
 
@@ -92,10 +96,32 @@ class TransformerConfig:
     # `moe_experts` consecutive ones from `moe_first_expert` and returns their
     # part of the result (what experts held on other chips would add is left
     # out: one chip of an expert-parallel deployment, without its exchange).
+    # Router forms by layout: 'einsum' and 'scatter' take the softmax router
+    # alone; 'grouped' takes either (`moe_router`). Layer kinds: every layout
+    # serves the expert layers of a stack with a dense lead
+    # (`moe_dense_layers`) and mixed token mixers (`layer_types`).
     moe_dispatch: str = "einsum"
     moe_total_experts: int = 0
     moe_first_expert: int = 0
     moe_bias: bool = True  # b_up / b_dn on the experts
+    # 'softmax': probabilities over all the router's experts, the top-k
+    # renormalised (Mixtral; switch for k = 1). 'sigmoid' ('grouped' only):
+    # scores by sigmoid, the top-k CHOSEN by score + a per-expert selection
+    # bias (a constant of the model: collection 'constants', zeros unless
+    # given, no gradient, held by the Trainer's state), the gates the
+    # unbiased scores over (their sum + 1e-6); it sows no load-balance term.
+    moe_router: str = "softmax"
+    # per-layer kinds. `layer_types`: the token mixer of each layer, 'conv'
+    # (gated short convolution of 3 taps, ops.short_conv) or
+    # 'full_attention'; () = attention everywhere. `moe_dense_layers`: that
+    # many leading layers keep the dense MLP of width `mlp_dim` when
+    # `moe_experts > 0`; the experts' width is `moe_mlp_dim` (0 -> mlp_dim).
+    layer_types: tuple = ()
+    moe_dense_layers: int = 0
+    moe_mlp_dim: int = 0
+    mlp_bias: bool = True  # biases on the dense MLP's projections
+    tie_embeddings: bool = False  # the LM head is the embedding matrix (LlamaLM)
+    flash_block: int = 128  # query and key block of attn_impl='flash'
     attn_bias: bool = True  # biases on the q, k, v, o projections
     qk_norm: bool = False  # per-head RMSNorm on q and k, before RoPE
     # learned sparse attention (ops.sparse_attention): 0 = every causal key.
@@ -110,10 +136,21 @@ class TransformerConfig:
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.hidden // self.n_heads)
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types and len(self.layer_types) != self.n_layers:
+            raise ValueError(f"layer_types names {len(self.layer_types)} layers, "
+                             f"n_layers is {self.n_layers}")
+        unknown = set(self.layer_types) - {"conv", "full_attention"}
+        if unknown:
+            raise ValueError(f"layer_types holds 'conv' or 'full_attention', got {unknown}")
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def expert_mlp_dim(self) -> int:
+        return self.moe_mlp_dim or self.mlp_dim
 
 
 def _act_fn(name: str) -> Callable:
@@ -226,7 +263,9 @@ class Attention(nn.Module):
         if impl == "flash" and eligible:
             from ...ops import flash_attention
 
-            return flash_attention(q, k, v, kv_mask=kv_mask, causal=cfg.causal)
+            # the op names its scope, `attn.flash`
+            return flash_attention(q, k, v, kv_mask=kv_mask, causal=cfg.causal,
+                                   block_q=cfg.flash_block, block_k=cfg.flash_block)
 
         if cfg.causal and not self.decode:
             causal = make_causal_mask(q.shape[1], k.shape[1])
@@ -341,7 +380,7 @@ class MlpBlock(nn.Module):
     def __call__(self, x):
         cfg = self.cfg
         dense = lambda name, feat, in_axis, out_axis: nn.Dense(  # noqa: E731
-            feat, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            feat, dtype=cfg.dtype, param_dtype=cfg.param_dtype, use_bias=cfg.mlp_bias,
             kernel_init=nn.with_logical_partitioning(nn.initializers.xavier_uniform(),
                                                      (in_axis, out_axis)),
             bias_init=nn.with_logical_partitioning(nn.initializers.zeros, (out_axis,)),
@@ -371,11 +410,14 @@ class MoEBlock(nn.Module):
     work that follows the routed pairs, and the one layout that can hold a
     share of the experts (``moe_total_experts``, ``moe_first_expert``): the
     router scores all of them (its product at highest precision), the result
-    is the held experts' part.
+    is the held experts' part. The grouped layout also takes the sigmoid
+    router with its constant selection bias (``cfg.moe_router``).
 
-    Sown under ``intermediates``: ``moe_aux_loss`` (the load-balance term over
-    all the router's experts; mean over layers = the switch aux term) and, on
-    the grouped path, ``moe_held_pairs`` and ``moe_expert_load_max_ratio``.
+    Sown under ``intermediates``: ``moe_aux_loss`` (softmax router: the
+    load-balance term over all the router's experts; mean over layers = the
+    switch aux term) and, on the grouped path, ``moe_held_pairs``,
+    ``moe_expert_load_max_ratio`` and, from the sigmoid router,
+    ``moe_bias_steered_share``.
     """
 
     cfg: TransformerConfig
@@ -401,6 +443,11 @@ class MoEBlock(nn.Module):
         # near-tied choices. 'einsum' / 'scatter' keep the default product
         # (their users' results stay as they were)
         grouped = cfg.moe_dispatch == "grouped"
+        if cfg.moe_router not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_router must be 'softmax' or 'sigmoid', got "
+                             f"{cfg.moe_router!r}")
+        if not grouped and cfg.moe_router != "softmax":
+            raise ValueError("the sigmoid router needs moe_dispatch='grouped'")
         with jax.named_scope("moe.route"):
             router = nn.Dense(
                 R, dtype=jnp.float32, param_dtype=cfg.param_dtype, use_bias=False,
@@ -409,23 +456,26 @@ class MoEBlock(nn.Module):
                     nn.initializers.xavier_uniform(), ("embed", None)),
                 name="router")
             logits = router(xf.astype(jnp.float32))           # [S, R] f32
-            probs = jax.nn.softmax(logits, axis=-1)
-            gate_vals, gate_idx = jax.lax.top_k(probs, k)      # [S, k]
-            if k > 1:
-                # renormalize over the selected experts — identical to Mixtral's
-                # softmax-then-topk-then-divide. k=1 keeps the RAW router
-                # probability (switch-transformer semantics: the gate carries
-                # the router gradient); Mixtral never ships k=1 configs.
-                gate_vals = gate_vals / jnp.maximum(
-                    jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
-            # load-balance aux loss: R * sum_e f_e * P_e over ALL the router's
-            # experts, with f_e the token fraction averaged over ALL k routing
-            # choices (the Mixtral/switch formulation — top-1-only would let
-            # second choices escape balancing pressure when k > 1)
-            frac_tokens = jnp.mean(
-                jax.nn.one_hot(gate_idx, R, dtype=jnp.float32), axis=(0, 1))
-            self.sow("intermediates", "moe_aux_loss",
-                     R * jnp.sum(frac_tokens * jnp.mean(probs, axis=0)))
+            if cfg.moe_router == "sigmoid":
+                gate_vals, gate_idx = self._sigmoid_route(logits)
+            else:
+                probs = jax.nn.softmax(logits, axis=-1)
+                gate_vals, gate_idx = jax.lax.top_k(probs, k)      # [S, k]
+                if k > 1:
+                    # renormalize over the selected experts — identical to Mixtral's
+                    # softmax-then-topk-then-divide. k=1 keeps the RAW router
+                    # probability (switch-transformer semantics: the gate carries
+                    # the router gradient); Mixtral never ships k=1 configs.
+                    gate_vals = gate_vals / jnp.maximum(
+                        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+                # load-balance aux loss: R * sum_e f_e * P_e over ALL the router's
+                # experts, with f_e the token fraction averaged over ALL k routing
+                # choices (the Mixtral/switch formulation — top-1-only would let
+                # second choices escape balancing pressure when k > 1)
+                frac_tokens = jnp.mean(
+                    jax.nn.one_hot(gate_idx, R, dtype=jnp.float32), axis=(0, 1))
+                self.sow("intermediates", "moe_aux_loss",
+                         R * jnp.sum(frac_tokens * jnp.mean(probs, axis=0)))
 
         def w(name, shape, axes):
             return self.param(name, nn.with_logical_partitioning(
@@ -492,9 +542,9 @@ class MoEBlock(nn.Module):
                 nn.initializers.zeros, ("expert", axis)), (E, width),
                 cfg.param_dtype)[:, None, :].astype(cfg.dtype)
 
-        w_up = w("w_up", (E, H, cfg.mlp_dim), ("expert", "embed", "mlp"))
-        b_up = bias("b_up", cfg.mlp_dim, "mlp")
-        w_dn = w("w_dn", (E, cfg.mlp_dim, H), ("expert", "mlp", "embed"))
+        w_up = w("w_up", (E, H, cfg.expert_mlp_dim), ("expert", "embed", "mlp"))
+        b_up = bias("b_up", cfg.expert_mlp_dim, "mlp")
+        w_dn = w("w_dn", (E, cfg.expert_mlp_dim, H), ("expert", "mlp", "embed"))
         b_dn = bias("b_dn", H, "embed")
 
         act = _act_fn(cfg.act)
@@ -503,7 +553,7 @@ class MoEBlock(nn.Module):
             + b_up
         if cfg.gated_mlp:
             # SwiGLU experts (the Mixtral block): act(x W_gate) * (x W_up)
-            w_g = w("w_gate", (E, H, cfg.mlp_dim), ("expert", "embed", "mlp"))
+            w_g = w("w_gate", (E, H, cfg.expert_mlp_dim), ("expert", "embed", "mlp"))
             gate = jnp.einsum("ech,ehm->ecm", expert_in, w_g.astype(cfg.dtype),
                               preferred_element_type=jnp.float32).astype(cfg.dtype)
             h = act(gate) * up
@@ -529,6 +579,25 @@ class MoEBlock(nn.Module):
 
         return y.reshape(B, T, H).astype(cfg.dtype)
 
+    def _sigmoid_route(self, logits):
+        """(gates, chosen experts) of the sigmoid router: the ``moe_top_k``
+        largest of score + selection bias, weighed by the unbiased scores over
+        their sum. The bias is a constant of the model: no gradient reaches
+        it, and the choice passes none. Sows the share of (token, choice)
+        pairs whose expert the scores alone would not have chosen."""
+        k = self.cfg.moe_top_k
+        scores = jax.nn.sigmoid(logits)
+        bias = self.variable("constants", "select_bias", jnp.zeros,
+                             (logits.shape[-1],), jnp.float32).value
+        _, gate_idx = jax.lax.top_k(
+            jax.lax.stop_gradient(scores + bias.astype(jnp.float32)), k)
+        gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
+        _, unbiased = jax.lax.top_k(jax.lax.stop_gradient(scores), k)
+        own = jnp.any(gate_idx[:, :, None] == unbiased[:, None, :], axis=-1)
+        self.sow("intermediates", "moe_bias_steered_share",
+                 1.0 - jnp.mean(own.astype(jnp.float32)))
+        return gate_vals / (jnp.sum(gate_vals, axis=-1, keepdims=True) + 1e-6), gate_idx
+
     def _grouped(self, x, xf, gate_vals, gate_idx, w):
         """The held experts' part of the result, dropless (ops.grouped_ffn).
         Sows the pairs routed to the held experts and the most-loaded held
@@ -540,9 +609,9 @@ class MoEBlock(nn.Module):
         if not cfg.gated_mlp or cfg.moe_bias or cfg.dropout > 0:
             raise ValueError("moe_dispatch='grouped' runs gated experts without "
                              "biases or dropout (gated_mlp=True, moe_bias=False)")
-        w_up = w("w_up", (E, H, cfg.mlp_dim), ("expert", "embed", "mlp"))
-        w_dn = w("w_dn", (E, cfg.mlp_dim, H), ("expert", "mlp", "embed"))
-        w_g = w("w_gate", (E, H, cfg.mlp_dim), ("expert", "embed", "mlp"))
+        w_up = w("w_up", (E, H, cfg.expert_mlp_dim), ("expert", "embed", "mlp"))
+        w_dn = w("w_dn", (E, cfg.expert_mlp_dim, H), ("expert", "mlp", "embed"))
+        w_g = w("w_gate", (E, H, cfg.expert_mlp_dim), ("expert", "embed", "mlp"))
         with jax.named_scope("moe.experts"):
             y, counts = expert_share_ffn(
                 xf.astype(cfg.dtype), gate_vals, gate_idx, w_g, w_up, w_dn,
@@ -554,28 +623,86 @@ class MoEBlock(nn.Module):
         return y.reshape(x.shape).astype(cfg.dtype)
 
 
+class ShortConv(nn.Module):
+    """Gated short convolution (ops.short_conv), the token mixer of a 'conv'
+    layer: one input projection to three streams b, c, u (in that order along
+    the last axis), ``c * conv(b * u)`` with a depthwise causal convolution of
+    ``TAPS`` taps, an output projection. No biases. Training and
+    prefill only: a decode cache would hold the last taps' inputs beside the
+    attention layers' keys."""
+
+    cfg: TransformerConfig
+    TAPS = 3    # every configuration so far; tap TAPS - 1 weighs the current position
+
+    @nn.compact
+    def __call__(self, x, mask=None, positions=None):
+        # a causal convolution needs no positions, and a padded tail cannot
+        # reach the positions before it: both are taken as `Attention` takes them
+        from ...ops.short_conv import gated_short_conv
+
+        cfg = self.cfg
+        H = cfg.hidden
+        proj = lambda name, feat, axes: nn.Dense(  # noqa: E731
+            feat, dtype=cfg.dtype, param_dtype=cfg.param_dtype, use_bias=False,
+            kernel_init=nn.with_logical_partitioning(
+                nn.initializers.xavier_uniform(), axes), name=name)
+        with jax.named_scope("conv.proj"):
+            bcu = proj("in_proj", 3 * H, ("embed", "mlp"))(x)
+        taps = self.param("conv", nn.with_logical_partitioning(
+            nn.initializers.normal(0.02), ("mlp", None)), (H, self.TAPS),
+            cfg.param_dtype)
+        with jax.named_scope("conv.mix"):
+            y = gated_short_conv(bcu[..., :H], bcu[..., H:2 * H], bcu[..., 2 * H:], taps)
+        with jax.named_scope("conv.proj"):
+            return proj("out_proj", H, ("mlp", "embed"))(y)
+
+
 class Block(nn.Module):
+    """One layer. ``layer`` is its place in the stack, which names its kinds
+    (``cfg.layer_types``, ``cfg.moe_dense_layers``)."""
+
     cfg: TransformerConfig
     decode: bool = False
+    layer: int = 0
+
+    def _parts(self):
+        """(token mixer, feed-forward) of this layer, by its kinds."""
+        cfg = self.cfg
+        conv = bool(cfg.layer_types) and cfg.layer_types[self.layer] == "conv"
+        if conv and self.decode:
+            raise ValueError("a 'conv' layer has no decode cache: training and "
+                             "prefill only")
+        experts = cfg.moe_experts > 0 and self.layer >= cfg.moe_dense_layers
+        return (ShortConv(cfg, name="conv") if conv
+                else Attention(cfg, decode=self.decode, name="attn"),
+                MoEBlock(cfg, name="mlp") if experts else MlpBlock(cfg, name="mlp"))
 
     @nn.compact
     def __call__(self, x, mask=None, positions=None):
         cfg = self.cfg
-        mlp_cls = MoEBlock if cfg.moe_experts > 0 else MlpBlock
+        mixer, mlp = self._parts()
         if cfg.norm_position == "post":
             # original-BERT residual structure: add then norm
-            h = Attention(cfg, decode=self.decode, name="attn")(x, mask, positions)
+            h = mixer(x, mask, positions)
             x = _norm(cfg)(x + h)
-            h = mlp_cls(cfg, name="mlp")(x)
+            with _mlp_scope(mlp):
+                h = mlp(x)
             x = _norm(cfg)(x + h)
         else:
             h = _norm(cfg)(x)
-            h = Attention(cfg, decode=self.decode, name="attn")(h, mask, positions)
+            h = mixer(h, mask, positions)
             x = x + h
             h = _norm(cfg)(x)
-            h = mlp_cls(cfg, name="mlp")(h)
+            with _mlp_scope(mlp):
+                h = mlp(h)
             x = x + h
         return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+
+
+def _mlp_scope(mlp):
+    """The scope `mlp.dense` around a dense MLP; the experts name their own."""
+    return jax.named_scope("mlp.dense") if isinstance(mlp, MlpBlock) \
+        else contextlib.nullcontext()
 
 
 class Encoder(nn.Module):
@@ -585,22 +712,27 @@ class Encoder(nn.Module):
     cfg: TransformerConfig
     decode: bool = False
 
+    def _block_cls(self):
+        cfg = self.cfg
+        if not cfg.remat:
+            return Block
+        policy = None
+        if cfg.attn_topk > 0:
+            # the backward pass re-runs the block without the indexed
+            # attention's tile loops: what their own backward pass reads
+            # (output, thresholds, row statistics) is kept
+            from ...ops.sparse_attention import REMAT_SAVED_NAMES
+
+            policy = jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED_NAMES)
+        return nn.remat(Block, static_argnums=(), policy=policy)
+
     @nn.compact
     def __call__(self, x, mask=None, positions=None):
         cfg = self.cfg
-        block_cls = Block
-        if cfg.remat:
-            policy = None
-            if cfg.attn_topk > 0:
-                # the backward pass re-runs the block without the indexed
-                # attention's tile loops: what their own backward pass reads
-                # (output, thresholds, row statistics) is kept
-                from ...ops.sparse_attention import REMAT_SAVED_NAMES
-
-                policy = jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED_NAMES)
-            block_cls = nn.remat(Block, static_argnums=(), policy=policy)
+        block_cls = self._block_cls()
         for i in range(cfg.n_layers):
-            x = block_cls(cfg, decode=self.decode, name=f"layer_{i}")(x, mask, positions)
+            x = block_cls(cfg, decode=self.decode, layer=i, name=f"layer_{i}")(
+                x, mask, positions)
         if cfg.norm_position == "post":
             return x  # post-norm blocks already end normalized
         return _norm(cfg)(x)

@@ -55,12 +55,23 @@ class TrainState:
     opt_state: Any
     step: jax.Array
     batch_stats: Any | None = None
+    # model variables that are neither trained nor optimizer state (collection
+    # 'constants': a router's selection bias): given to every step, returned
+    # by it as they went in, saved and restored with the rest
+    constants: Any | None = None
 
     def as_dict(self) -> dict:
         d = {"params": self.params, "opt_state": self.opt_state, "step": self.step}
         if self.batch_stats is not None:
             d["batch_stats"] = self.batch_stats
+        if self.constants is not None:
+            d["constants"] = self.constants
         return d
+
+    def _step_input(self) -> dict:
+        """What the jitted step takes and returns: every field, None where the
+        state has none (the scanned step's carry keeps one structure)."""
+        return self.as_dict() | {"batch_stats": self.batch_stats, "constants": self.constants}
 
 
 @dataclasses.dataclass
@@ -149,12 +160,18 @@ _TRAIN_METRICS = obs.HandleCache(lambda reg: {
     "sparse_attn_indexer_kl": reg.gauge(
         "synapseml_sparse_attn_indexer_kl",
         "the indexer's loss summed over the layers, newest step"),
+    "moe_bias_steered_share": reg.gauge(
+        "synapseml_moe_bias_steered_share",
+        "(token, choice) pairs whose expert the router's scores alone would "
+        "not have chosen (the selection bias steered them), mean over the "
+        "layers of the newest step"),
 })
 
 # sown name -> how the layers' values become one number of a step's metrics
 _MODEL_STATS = {"moe_held_pairs": jnp.sum, "moe_expert_load_max_ratio": jnp.max,
                 "sparse_attn_selected_share": jnp.mean,
-                "sparse_attn_indexer_kl": jnp.sum}
+                "sparse_attn_indexer_kl": jnp.sum,
+                "moe_bias_steered_share": jnp.mean}
 
 
 class _LoopSpan:
@@ -467,7 +484,7 @@ class Trainer:
         self._tx = _make_optimizer(self.cfg, params)
 
     def resume_state(self, params, opt_state=None, step: int = 0,
-                     batch_stats=None) -> TrainState:
+                     batch_stats=None, constants=None) -> TrainState:
         """Build a TrainState from restored host/device pytrees (see
         parallel.checkpoint.restore_checkpoint) without re-initializing.
 
@@ -499,7 +516,8 @@ class Trainer:
                 treedef, list(_align_restored(fresh, opt_state, "opt_state")))
         opt_state = self._rule_place_opt_state(params, opt_state)
         return TrainState(params=params, opt_state=opt_state,
-                          step=jnp.asarray(step, jnp.int32), batch_stats=batch_stats)
+                          step=jnp.asarray(step, jnp.int32), batch_stats=batch_stats,
+                          constants=constants)
 
     def init_state(self, example_batch: dict, rng: jax.Array | None = None,
                    init_params=None, init_batch_stats=None) -> TrainState:
@@ -522,11 +540,15 @@ class Trainer:
             batch_stats = self._unbox_with_sharding(
                 _graft_params(variables["batch_stats"], init_batch_stats)
                 if init_batch_stats is not None else variables["batch_stats"])
+        constants = None
+        if "constants" in variables:
+            constants = self._unbox_with_sharding(variables["constants"])
         tx = _make_optimizer(self.cfg, params)
         self._tx = tx
         opt_state = self._rule_place_opt_state(params, tx.init(params))
         return TrainState(params=params, opt_state=opt_state,
-                          step=jnp.zeros((), jnp.int32), batch_stats=batch_stats)
+                          step=jnp.zeros((), jnp.int32), batch_stats=batch_stats,
+                          constants=constants)
 
     def _model_inputs(self, batch: dict) -> dict:
         drop = {"labels", "label", "mask", "_valid"}
@@ -605,6 +627,8 @@ class Trainer:
                 variables = {"params": params}
                 if state.get("batch_stats") is not None:
                     variables["batch_stats"] = state["batch_stats"]
+                if state.get("constants") is not None:
+                    variables["constants"] = state["constants"]
                 with jax.named_scope("forward"):
                     if self._loss_fn is not None:
                         loss = self._loss_fn(variables, batch)
@@ -627,6 +651,8 @@ class Trainer:
                 new_state["batch_stats"] = new_vars.get("batch_stats", state["batch_stats"])
             else:
                 new_state["batch_stats"] = None
+            if "constants" in state:      # as given: the carry keeps its structure
+                new_state["constants"] = state["constants"]
             with jax.named_scope("step_metrics"):
                 grad_norm = optax.global_norm(grads).astype(jnp.float32)
             # beside them, for a module that has the mechanism: _MODEL_STATS
@@ -671,10 +697,9 @@ class Trainer:
         with _LoopSpan("train.place", _place_attrs(batch)):
             placed = self.mesh.shard_batch(batch)
         with self._dispatching("step", self._train_step, 1), self.mesh.scope():
-            sd, metrics = self._train_step(state.as_dict() | {"batch_stats": state.batch_stats},
-                                           placed)
+            sd, metrics = self._train_step(state._step_input(), placed)
         return TrainState(params=sd["params"], opt_state=sd["opt_state"], step=sd["step"],
-                          batch_stats=sd.get("batch_stats")), metrics
+                          batch_stats=sd["batch_stats"], constants=sd["constants"]), metrics
 
     # ---- scanned multi-step: K optimizer steps in ONE dispatch ----
     # Host dispatch overhead disappears: the train loop itself lives
@@ -694,10 +719,9 @@ class Trainer:
             placed = self.mesh.shard_stacked_batch(stacked_batches)
         steps = int(np.shape(jax.tree.leaves(stacked_batches)[0])[0])
         with self._dispatching("scan", self._scan_step, steps), self.mesh.scope():
-            sd, metrics = self._scan_step(
-                state.as_dict() | {"batch_stats": state.batch_stats}, placed)
+            sd, metrics = self._scan_step(state._step_input(), placed)
         return (TrainState(params=sd["params"], opt_state=sd["opt_state"], step=sd["step"],
-                           batch_stats=sd.get("batch_stats")), metrics)
+                           batch_stats=sd["batch_stats"], constants=sd["constants"]), metrics)
 
     # ---- non-finite loss guard ----
     def _observe_losses(self, losses, last_step: int) -> None:
@@ -1267,7 +1291,8 @@ def fit_source(trainer: "Trainer", source, *, batch_size: int, total_steps: int,
             state = trainer.resume_state(
                 tree["params"], tree.get("opt_state"),
                 step=int(np.asarray(tree["step"])),
-                batch_stats=tree.get("batch_stats"))
+                batch_stats=tree.get("batch_stats"),
+                constants=tree.get("constants"))
             if data_state is None:
                 data_state = tree.get("data_iter")
     if checkpointer is not None \
@@ -1423,7 +1448,8 @@ def fit_gang_source(trainer: "Trainer", source, *, batch_size: int,
         state = trainer.resume_state(
             tree["params"], tree.get("opt_state"),
             step=int(np.asarray(tree["step"])),
-            batch_stats=tree.get("batch_stats"))
+            batch_stats=tree.get("batch_stats"),
+            constants=tree.get("constants"))
     else:
         plan = ElasticPlan.fresh(world, seed)
         done, state = 0, None

@@ -14,18 +14,22 @@ Here the hot ops are first-class TPU kernels:
   * :mod:`sparse_attention` — learned sparse attention for training: an
     indexer's exact top-k key set a query, tiled masked attention over it;
   * :mod:`grouped_ffn` — one chip's share of a mixture of experts, dropless:
-    a grouped product over the routed (token, choice) pairs.
+    a grouped product over the routed (token, choice) pairs;
+  * :mod:`short_conv` — the gated short convolution of hybrid conv-attention
+    decoders: two gates around a depthwise causal convolution of a few taps.
 """
 
 from .attention import flash_attention, reference_attention
 from .grouped_ffn import expert_share_ffn
 from .ring_attention import ring_attention, ring_attention_sharded
+from .short_conv import gated_short_conv
 from .sparse_attention import indexed_attention, topk_mask
 from .ulysses_attention import ulysses_attention, ulysses_attention_sharded
 
 __all__ = [
     "expert_share_ffn",
     "flash_attention",
+    "gated_short_conv",
     "indexed_attention",
     "reference_attention",
     "ring_attention",

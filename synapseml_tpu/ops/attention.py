@@ -14,6 +14,8 @@ attention) — padding rows carry no gradient and are sliced away downstream.
 
 Backward pass: a custom VJP recomputes attention blockwise in XLA from the
 saved log-sum-exp — no [T, T] materialization, no second Pallas kernel needed.
+Under a causal mask its loops run over the blocks on and below the diagonal
+only (the others hold no pair and would add exact zeros).
 """
 
 from __future__ import annotations
@@ -225,7 +227,9 @@ def _flash_core_bwd(causal, block_q, block_k, scale, res, g):
             return dq_acc + jnp.einsum("bqk,bkd->bqd", ds.astype(kb.dtype), kb,
                                        preferred_element_type=jnp.float32) * scale
 
-        dq_blk = jax.lax.fori_loop(0, n_kb, inner,
+        # causal: key blocks past the query block's last row hold no pair
+        last_kb = jnp.minimum(n_kb, (qi0 + block_q - 1) // block_k + 1) if causal else n_kb
+        dq_blk = jax.lax.fori_loop(0, last_kb, inner,
                                    jnp.zeros((BH, block_q, Dp), jnp.float32))
         return None, dq_blk
 
@@ -256,9 +260,11 @@ def _flash_core_bwd(causal, block_q, block_k, scale, res, g):
                                          preferred_element_type=jnp.float32) * scale
             return dk_acc, dv_acc
 
+        # causal: query blocks that end before the key block's first row hold no pair
         dk_blk, dv_blk = jax.lax.fori_loop(
-            0, n_qb, inner, (jnp.zeros((BH, block_k, Dp), jnp.float32),
-                             jnp.zeros((BH, block_k, Dp), jnp.float32)))
+            ki0 // block_q if causal else 0, n_qb, inner,
+            (jnp.zeros((BH, block_k, Dp), jnp.float32),
+             jnp.zeros((BH, block_k, Dp), jnp.float32)))
         return None, (dk_blk, dv_blk)
 
     _, (dk_blocks, dv_blocks) = jax.lax.scan(dkv_one, None, jnp.arange(n_kb))
@@ -278,6 +284,11 @@ def flash_attention(q, k, v, kv_mask=None, causal: bool = False,
     leaves dot products unchanged; padded kv positions are masked; padded q
     rows are sliced away).
     """
+    with jax.named_scope("attn.flash"):     # kernel, pads and the backward's loops alike
+        return _flash_attention(q, k, v, kv_mask, causal, block_q, block_k)
+
+
+def _flash_attention(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int):
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
     if causal and Tq != Tk:

@@ -63,6 +63,22 @@ def test_flash_attention_lowers_for_tpu(compiled_pallas, B, T, H, D, causal):
         assert "tpu_custom_call" in _tpu_module(fn, qkv, qkv, qkv, mask)
 
 
+def test_flash_attention_lowers_for_tpu_at_the_long_context_cells_shape(compiled_pallas):
+    """`lfm2_24b_a2b_ep8.lm_32k`: one 32,768-token row, 32 heads of 64 (padded
+    to the 128 lanes), causal, blocks of 512, forward and gradient."""
+    from synapseml_tpu.ops import flash_attention
+
+    qkv = jax.ShapeDtypeStruct((1, 32768, 32, 64), jnp.bfloat16)
+
+    def grad(q, k, v):
+        return jax.grad(lambda q_, k_, v_: jnp.sum(flash_attention(
+            q_, k_, v_, causal=True, block_q=512, block_k=512).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = _tpu_module(grad, qkv, qkv, qkv)
+    assert "tpu_custom_call" in text and "32768x128" in text
+
+
 @pytest.mark.parametrize("N,WB", [
     (1_000_000, 32 * 256),      # chip_smoke Leg B's shape (Higgs-1M, depth-5 level)
     (1001, 300),                # ragged rows and bins
