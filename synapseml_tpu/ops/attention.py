@@ -12,6 +12,15 @@ Layout contract: ``q, k, v: [B, T, H, D]`` (same as :mod:`models.flax_nets`),
 exactly zero (same contract as :func:`reference_attention` and ring
 attention) — padding rows carry no gradient and are sliced away downstream.
 
+Two variants of the forward kernel, chosen from what the call passes (counted
+in ``synapseml_flash_kernel_builds_total{variant}``, once a trace of the op):
+``unmasked`` when ``kv_mask`` is None and the keys are a whole number of key
+blocks of whole 128-lane tiles, ``masked`` for every other call. The unmasked
+one has no mask operand and no masked-row guard: under a causal mask every
+row's first block holds key 0, so the running maximum is finite before any
+``-1e30`` entry meets it and ``exp`` gives an exact 0; without ``causal`` no
+entry is masked at all. Both compute the same numbers wherever both apply.
+
 Backward pass: a custom VJP recomputes attention blockwise in XLA from the
 saved log-sum-exp — no [T, T] materialization, no second Pallas kernel needed.
 Under a causal mask its loops run over the blocks on and below the diagonal
@@ -26,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import observability as obs
 from ..core import platform
 
 _NEG_INF = -1e30
@@ -122,65 +132,176 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         lse_ref[0, :, 0] = m_scr[:, 0] + jnp.log(safe_l)
 
 
+def _diagonal_kv_block(q_blk, block_q: int, block_k: int):
+    """The key block that holds the position of query block ``q_blk``'s last
+    row: under a causal mask the last one it needs (an int, an array of them
+    or a traced scalar)."""
+    return ((q_blk + 1) * block_q - 1) // block_k
+
+
+def _flash_fwd_kernel_unmasked(q_blk_ref, kv_blk_ref, q_ref, k_ref, v_ref, o_ref,
+                               lse_ref, m_scr, l_scr, acc_scr, *,
+                               block_q: int, block_k: int, n_kblocks: int,
+                               scale: float, causal: bool):
+    """One (batch*head, step) program of a call without a key mask. The step's
+    query and key block come from the two scalar-prefetched tables, which list
+    the pairs that hold work in row order (under ``causal`` the lower triangle:
+    no step is spent and no block fetched above the diagonal). Differs from
+    :func:`_flash_fwd_kernel` in what it leaves out (the key mask's select and
+    the masked-row guard: module docstring) and in how the row statistics
+    meet the score tile: they stay replicated over the 128 lanes and are tiled
+    along them, where a ``[block_q, 1]`` column broadcast over the lanes cost
+    40 of 113 ms a launch on a v5e (PERF.md section 6, PR 34). The causal
+    select stays on every block: its passes run in the products' shadow, and
+    a second body for the blocks below the diagonal measured 1.6 ms slower."""
+    from jax.experimental import pallas as pl
+
+    step = pl.program_id(1)
+    q_blk, kv_blk = q_blk_ref[step], kv_blk_ref[step]
+
+    @pl.when(kv_blk == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if causal:
+        q_pos = q_blk * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        kv_pos = kv_blk * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(kv_pos <= q_pos, s, _NEG_INF)
+    m = m_scr[...]                                      # [block_q, 128], lanes equal
+    new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m - new_m)
+    p = jnp.exp(s - jnp.tile(new_m, (1, block_k // 128)))
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    v_blk = v_ref[0]
+    acc_scr[...] = (acc_scr[...] * jnp.tile(alpha, (1, acc_scr.shape[1] // 128))
+                    + jax.lax.dot_general(p.astype(v_blk.dtype), v_blk,
+                                          (((1,), (0,)), ((), ())),
+                                          preferred_element_type=jnp.float32))
+    m_scr[...] = new_m
+
+    last = n_kblocks - 1
+    if causal:
+        last = jnp.minimum(_diagonal_kv_block(q_blk, block_q, block_k), last)
+
+    @pl.when(kv_blk == last)
+    def _finalize():
+        l = jnp.maximum(l_scr[:, :1], 1e-30)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[0] = m_scr[:, :1] + jnp.log(l)
+
+
+# the unmasked variant's two step tables live in SMEM (1 MiB on a v5e; two
+# int32 tables of 65,536 steps, 512 KiB, compile for it; 131,328 do not)
+_MAX_TABLE_STEPS = 1 << 16
+
+
+def _work_steps(n_qblocks: int, n_kblocks: int, block_q: int, block_k: int,
+                causal: bool) -> tuple[np.ndarray, np.ndarray]:
+    """(query block, key block) of every grid step of the unmasked variant:
+    each query block's needed key blocks in order, query blocks in order."""
+    counts = np.full(n_qblocks, n_kblocks)
+    if causal:
+        counts = np.minimum(_diagonal_kv_block(np.arange(n_qblocks), block_q, block_k) + 1,
+                            counts)
+    q_blks = np.repeat(np.arange(n_qblocks), counts)
+    kv_blks = np.arange(counts.sum()) - np.repeat(np.cumsum(counts) - counts, counts)
+    return q_blks.astype(np.int32), kv_blks.astype(np.int32)
+
+
 def _ceil_to(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_core(q, k, v, kv_mask, causal, block_q, block_k, scale):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_core(q, k, v, kv_mask, causal, block_q, block_k, scale, unmasked):
     out, _ = _flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k,
-                                  scale)
+                                  scale, unmasked)
     return out
 
 
-def _flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k, scale):
-    """q,k,v: [BH, T, Dp]; kv_mask: [BH, Tk] bool. ``scale`` is 1/sqrt of the
-    TRUE head dim (D may be lane-padded here). Returns (out, lse)."""
+def _flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k, scale,
+                         unmasked):
+    """q,k,v: [BH, T, Dp]; kv_mask: [BH, Tk] bool, which the ``unmasked``
+    variant (all True then: :func:`_flash_attention` decides) leaves out of
+    the kernel. ``scale`` is 1/sqrt of the TRUE head dim (D may be
+    lane-padded here). Returns (out, lse)."""
     from jax.experimental import pallas as pl
 
     from jax.experimental.pallas import tpu as pltpu
 
     BH, Tq, Dp = q.shape
     Tk = k.shape[1]
-    n_kblocks = Tk // block_k
-    kernel = functools.partial(_flash_fwd_kernel, block_k=block_k,
-                               n_kblocks=n_kblocks, scale=scale, causal=causal,
-                               block_q=block_q)
-    grid = (BH, Tq // block_q, n_kblocks)
+    n_qblocks, n_kblocks = Tq // block_q, Tk // block_k
+    static = dict(block_q=block_q, block_k=block_k, n_kblocks=n_kblocks,
+                  scale=scale, causal=causal)
+    scratch_shapes = [
+        pltpu.VMEM((block_q, 128), jnp.float32),   # running max (lane-bcast)
+        pltpu.VMEM((block_q, 128), jnp.float32),   # running sum (lane-bcast)
+        pltpu.VMEM((block_q, Dp), jnp.float32),    # output accumulator
+    ]
+    if unmasked:
+        steps = _work_steps(n_qblocks, n_kblocks, block_q, block_k, causal)
+
+        def q_block(b, t, q_blks, kv_blks):
+            return (b, q_blks[t], 0)
+
+        def kv_block(b, t, q_blks, kv_blks):
+            return (b, kv_blks[t], 0)
+
+        kernel = functools.partial(_flash_fwd_kernel_unmasked, **static)
+        operands = (*steps, q, k, v)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(BH, len(steps[0])),
+            in_specs=[pl.BlockSpec((1, block_q, Dp), q_block),
+                      pl.BlockSpec((1, block_k, Dp), kv_block),
+                      pl.BlockSpec((1, block_k, Dp), kv_block)],
+            out_specs=[pl.BlockSpec((1, block_q, Dp), q_block),
+                       pl.BlockSpec((1, block_q, 1), q_block)],
+            scratch_shapes=scratch_shapes)
+    else:
+        def kv_block(i, j):
+            # above the diagonal the kernel skips the step: name the block
+            # already resident, and the pipeline issues no copy
+            return jnp.minimum(j, _diagonal_kv_block(i, block_q, block_k)) if causal else j
+
+        kernel = functools.partial(_flash_fwd_kernel, **static)
+        operands = (q, k, v, kv_mask.astype(jnp.int32)[:, None, :])
+        grid_spec = pl.GridSpec(
+            grid=(BH, n_qblocks, n_kblocks),
+            in_specs=[
+                pl.BlockSpec((1, block_q, Dp), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, block_k, Dp), lambda b, i, j: (b, kv_block(i, j), 0)),
+                pl.BlockSpec((1, block_k, Dp), lambda b, i, j: (b, kv_block(i, j), 0)),
+                pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, kv_block(i, j))),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, Dp), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            ],
+            scratch_shapes=scratch_shapes)
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, Dp), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, Dp), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, Dp), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, Dp), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
+        grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((BH, Tq, Dp), q.dtype),
             jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running max (lane-bcast)
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running sum (lane-bcast)
-            pltpu.VMEM((block_q, Dp), jnp.float32),    # output accumulator
-        ],
         interpret=platform.pallas_interpret(),
-    )(q, k, v, kv_mask.astype(jnp.int32)[:, None, :])
+    )(*operands)
     return out, lse[:, :, 0]
 
 
-def _flash_core_fwd(q, k, v, kv_mask, causal, block_q, block_k, scale):
+def _flash_core_fwd(q, k, v, kv_mask, causal, block_q, block_k, scale, unmasked):
     out, lse = _flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k,
-                                    scale)
+                                    scale, unmasked)
     return out, (q, k, v, kv_mask, out, lse)
 
 
-def _flash_core_bwd(causal, block_q, block_k, scale, res, g):
+def _flash_core_bwd(causal, block_q, block_k, scale, unmasked, res, g):
     """Blockwise XLA backward from saved LSE — O(T·block) memory via lax.scan
     over kv blocks (dq) / q blocks (dk, dv). Matmul operands stay in the
     input dtype (bf16 on the training path) with f32 accumulation; only the
@@ -282,7 +403,12 @@ def flash_attention(q, k, v, kv_mask=None, causal: bool = False,
 
     Pads T to the block size and D to the 128-lane TPU tile (zero-padding D
     leaves dot products unchanged; padded kv positions are masked; padded q
-    rows are sliced away).
+    rows are sliced away). A call with no ``kv_mask`` whose keys need no
+    padding builds the forward kernel's ``unmasked`` variant (no mask
+    operand, no masked-row guard, only the grid steps that hold work: key 0
+    makes every row's running maximum finite before a masked entry meets it);
+    a mask, a ragged ``Tk`` or key blocks that are not whole 128-lane tiles
+    build the ``masked`` one. Same numbers either way (module docstring).
     """
     with jax.named_scope("attn.flash"):     # kernel, pads and the backward's loops alike
         return _flash_attention(q, k, v, kv_mask, causal, block_q, block_k)
@@ -297,12 +423,19 @@ def _flash_attention(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int)
         # q_offset/kv_offset)
         raise ValueError(f"causal flash_attention requires Tq == Tk, got "
                          f"Tq={Tq} Tk={Tk}")
-    if kv_mask is None:
-        kv_mask = jnp.ones((B, Tk), bool)
-
     block_q = min(block_q, _ceil_to(Tq, 8))
     block_k = min(block_k, _ceil_to(Tk, 8))
     Tq_p, Tk_p = _ceil_to(Tq, block_q), _ceil_to(Tk, block_k)
+    # no key is masked or padded, the row statistics tile along whole lanes
+    # and the step tables fit in SMEM
+    unmasked = (kv_mask is None and Tk_p == Tk and block_k % 128 == 0
+                and (Tq_p // block_q) * (Tk_p // block_k) <= _MAX_TABLE_STEPS)
+    obs.get_registry().counter(
+        "synapseml_flash_kernel_builds_total",
+        "traces of flash_attention, by the forward kernel variant they built",
+        ("variant",)).inc(variant="unmasked" if unmasked else "masked")
+    if kv_mask is None:
+        kv_mask = jnp.ones((B, Tk), bool)
     Dp = _ceil_to(D, 128)
     scale = 1.0 / np.sqrt(D)  # true head dim — padding D must not change it
 
@@ -316,6 +449,6 @@ def _flash_attention(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int)
     maskb = jnp.pad(kv_mask, ((0, 0), (0, Tk_p - Tk)))
     maskb = jnp.broadcast_to(maskb[:, None, :], (B, H, Tk_p)).reshape(B * H, Tk_p)
 
-    out = _flash_core(qb, kb, vb, maskb, causal, block_q, block_k, scale)
+    out = _flash_core(qb, kb, vb, maskb, causal, block_q, block_k, scale, unmasked)
     out = out.reshape(B, H, Tq_p, Dp)[:, :, :Tq, :D]
     return jnp.transpose(out, (0, 2, 1, 3))

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from synapseml_tpu.core import observability as obs
 from synapseml_tpu.core import platform
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
@@ -63,20 +64,27 @@ def test_flash_attention_lowers_for_tpu(compiled_pallas, B, T, H, D, causal):
         assert "tpu_custom_call" in _tpu_module(fn, qkv, qkv, qkv, mask)
 
 
-def test_flash_attention_lowers_for_tpu_at_the_long_context_cells_shape(compiled_pallas):
+@pytest.mark.parametrize("variant", ["unmasked", "masked"])
+def test_flash_attention_lowers_for_tpu_at_the_long_context_cells_shape(compiled_pallas, variant):
     """`lfm2_24b_a2b_ep8.lm_32k`: one 32,768-token row, 32 heads of 64 (padded
-    to the 128 lanes), causal, blocks of 512, forward and gradient."""
+    to the 128 lanes), causal, blocks of 512, forward and gradient. The cell
+    passes no mask and builds the unmasked kernel; a padding mask at the same
+    shape builds the masked one."""
     from synapseml_tpu.ops import flash_attention
 
     qkv = jax.ShapeDtypeStruct((1, 32768, 32, 64), jnp.bfloat16)
+    mask = [jax.ShapeDtypeStruct((1, 32768), jnp.bool_)] if variant == "masked" else []
 
-    def grad(q, k, v):
+    def grad(q, k, v, *m):
         return jax.grad(lambda q_, k_, v_: jnp.sum(flash_attention(
-            q_, k_, v_, causal=True, block_q=512, block_k=512).astype(jnp.float32)),
+            q_, k_, v_, *m, causal=True, block_q=512, block_k=512).astype(jnp.float32)),
             argnums=(0, 1, 2))(q, k, v)
 
-    text = _tpu_module(grad, qkv, qkv, qkv)
+    series = 'synapseml_flash_kernel_builds_total{variant="%s"}' % variant
+    before = obs.get_registry().snapshot().get(series, 0.0)
+    text = _tpu_module(grad, qkv, qkv, qkv, *mask)
     assert "tpu_custom_call" in text and "32768x128" in text
+    assert obs.get_registry().snapshot()[series] == before + 1
 
 
 @pytest.mark.parametrize("N,WB", [

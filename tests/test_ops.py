@@ -6,7 +6,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from synapseml_tpu.core import observability as obs
 from synapseml_tpu.ops import flash_attention, reference_attention, ring_attention_sharded
+from synapseml_tpu.ops.attention import _work_steps
 from synapseml_tpu.parallel import MeshConfig, create_mesh
 
 
@@ -87,6 +89,135 @@ def test_fully_masked_rows_zero():
     all_masked = jnp.zeros((2, 16), bool)
     out0 = flash_attention(q, k, v, kv_mask=all_masked, block_q=8, block_k=8)
     assert float(jnp.max(jnp.abs(out0))) == 0.0
+
+
+def _kernel_builds() -> dict:
+    snap = obs.get_registry().snapshot()
+    return {v: snap.get('synapseml_flash_kernel_builds_total{variant="%s"}' % v, 0.0)
+            for v in ("unmasked", "masked")}
+
+
+def _built_since(before: dict) -> dict:
+    return {v: n - before[v] for v, n in _kernel_builds().items()}
+
+
+def _within_one_ulp(a, b, dtype):
+    """|a - b| is at most one unit in the last place of ``dtype`` at the larger
+    of the two magnitudes (exact equality for float32 is asserted apart)."""
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    mag = np.maximum(np.maximum(np.abs(a), np.abs(b)), np.finfo(np.float32).tiny)
+    ulp = float(jnp.finfo(dtype).eps) * 2.0 ** np.floor(np.log2(mag))
+    return bool(np.all(np.abs(a - b) <= ulp))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("D", [64, 128])
+@pytest.mark.parametrize("block_q,block_k", [(128, 128), (128, 256), (256, 128)])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_without_a_mask_equals_flash_with_an_all_ones_mask(causal, block_q, block_k,
+                                                                   D, dtype):
+    """The unmasked variant (no mask operand, no guard, only the steps that hold
+    work, lane-replicated row statistics) against the masked one, which is the
+    kernel every call ran before: output and the three gradients."""
+    T = 512                                     # 2..4 blocks a side
+    q, k, v, _ = make_qkv(B=1, T=T, H=2, D=D, seed=D + block_q)
+    ones = jnp.ones((1, T), bool)
+
+    def run(fn, kv_mask, *xs):
+        def loss(q, k, v):
+            out = fn(q, k, v, kv_mask=kv_mask, causal=causal)
+            return jnp.sum(out.astype(jnp.float32) ** 2), out
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(*xs)
+        return (out, *grads)
+
+    flash = lambda *a, **kw: flash_attention(*a, block_q=block_q, block_k=block_k, **kw)
+    xs = tuple(x.astype(dtype) for x in (q, k, v))
+    before = _kernel_builds()
+    unmasked = run(flash, None, *xs)
+    assert _built_since(before) == {"unmasked": 1, "masked": 0}
+    masked = run(flash, ones, *xs)
+    assert _built_since(before) == {"unmasked": 1, "masked": 1}
+    ref = run(reference_attention, None, q, k, v)
+    f32 = dtype == jnp.float32
+    # XLA:CPU contracts `dot * scale - max` into one fused multiply-add where no
+    # select stands between them (not causal) and the product is inexact
+    # (1/sqrt(128) is no power of two); the chip's compiler does not, and the
+    # two variants are equal to the last bit there (PERF.md section 6, PR 34)
+    contracted_on_cpu = not causal and D == 128
+    for name, a, b, r in zip(("out", "dq", "dk", "dv"), unmasked, masked, ref):
+        a32, b32 = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if contracted_on_cpu:
+            assert np.max(np.abs(a32 - b32)) <= (4 * float(jnp.finfo(dtype).eps)
+                                                 * np.max(np.abs(b32))), name
+        elif f32:
+            np.testing.assert_array_equal(a32, b32, err_msg=name)
+        else:
+            # the CPU interpreter may fuse the two bodies differently
+            assert _within_one_ulp(a32, b32, dtype), name
+        # the file's tolerances: test_flash_matches_reference / _gradients_match
+        # (their sum of squares runs over 4x fewer rows) / _bf16_matches_f32_reference
+        if name == "out":
+            tol = dict(atol=2e-5) if f32 else dict(atol=3e-2)
+        else:
+            tol = dict(atol=2e-4) if f32 else dict(atol=0.15, rtol=0.05)
+        np.testing.assert_allclose(np.asarray(r), np.asarray(a, dtype=np.float32),
+                                   err_msg=name, **tol)
+
+
+@pytest.mark.parametrize("case", ["ragged_T", "padded_keys", "blocks_under_a_lane_tile"])
+def test_flash_builds_the_masked_variant_for_a_mask_or_a_ragged_length(case):
+    T, block = {"ragged_T": (200, 128), "padded_keys": (256, 128),
+                "blocks_under_a_lane_tile": (64, 16)}[case]
+    q, k, v, _ = make_qkv(B=2, T=T, H=2, D=64)
+    kv_mask = None
+    if case == "padded_keys":
+        kv_mask = jnp.arange(T)[None, :] < jnp.asarray([T, 100])[:, None]
+    before = _kernel_builds()
+    out = flash_attention(q, k, v, kv_mask=kv_mask, causal=True, block_q=block, block_k=block)
+    assert _built_since(before) == {"unmasked": 0, "masked": 1}
+    ref = reference_attention(q, k, v, kv_mask=kv_mask, causal=True)
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_steps_over_the_blocks_that_hold_work_at_8_by_8_blocks(causal):
+    q, k, v, _ = make_qkv(B=1, T=1024, H=1, D=64)
+    before = _kernel_builds()
+    out = flash_attention(q, k, v, causal=causal, block_q=128, block_k=128)
+    assert _built_since(before) == {"unmasked": 1, "masked": 0}
+    ref = reference_attention(q, k, v, causal=causal)
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5)
+    assert len(_work_steps(8, 8, 128, 128, causal)[0]) == (36 if causal else 64)
+
+
+@pytest.mark.parametrize("n_q,n_k,block_q,block_k", [
+    (8, 8, 128, 128), (4, 8, 256, 128), (8, 4, 128, 256), (3, 2, 256, 384), (1, 1, 512, 512)])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_step_tables_list_every_block_with_a_pair_once_in_row_order(
+        causal, n_q, n_k, block_q, block_k):
+    """Each query block's key blocks in order from 0, query blocks in order
+    (the kernel initialises at key block 0 and writes out at the row's last
+    step); under causal exactly the blocks that hold a pair key <= query."""
+    want = [(i, j) for i in range(n_q) for j in range(n_k)
+            if not causal or j * block_k <= (i + 1) * block_q - 1]
+    q_blks, kv_blks = _work_steps(n_q, n_k, block_q, block_k, causal)
+    assert q_blks.dtype == kv_blks.dtype == np.int32
+    assert list(zip(q_blks.tolist(), kv_blks.tolist())) == want
+
+
+def test_flash_kernel_builds_count_one_a_trace():
+    q, k, v, _ = make_qkv(B=1, T=128, H=1, D=64)
+    mask = jnp.ones((1, 128), bool)
+    fn = jax.jit(lambda q, k, v, m=None: flash_attention(q, k, v, kv_mask=m))
+    before = _kernel_builds()
+    fn(q, k, v)
+    fn(q, k, v)                                  # the jit cache's entry: no trace
+    assert _built_since(before) == {"unmasked": 1, "masked": 0}
+    fn(q, k, v, mask)
+    fn(q, k, v, mask)
+    assert _built_since(before) == {"unmasked": 1, "masked": 1}
+    jax.grad(lambda q: jnp.sum(fn(q[:, :64], k[:, :64], v[:, :64])))(q)   # T=64: one block of 64
+    assert _built_since(before) == {"unmasked": 1, "masked": 2}
 
 
 @pytest.mark.parametrize("causal", [False, True])
