@@ -5,10 +5,12 @@ models per-partition; here a native Flax decoder (RMSNorm + SwiGLU + RoPE +
 GQA) whose weights shard over the tensor/fsdp mesh axes — the Llama-2-7B
 sharded-inference target of BASELINE.md rides this module.
 
-Two builders give `LlamaLM` a decoder that is no Llama: `sparse_moe_lm`
-(learned sparse attention, routed experts in every layer) and
+Three builders give `LlamaLM` a decoder that is no Llama: `sparse_moe_lm`
+(learned sparse attention, routed experts in every layer),
 `hybrid_conv_moe_lm` (gated short-convolution layers among full-attention
-layers, a dense lead, sigmoid-routed experts, embedding and head tied).
+layers, a dense lead, sigmoid-routed experts, embedding and head tied) and
+`latent_moe_lm` (multi-head latent attention, a dense lead, sigmoid-routed
+experts beside a shared expert, untied head).
 """
 
 from __future__ import annotations
@@ -23,7 +25,7 @@ from .transformer import (Encoder, MlpBlock, MoEBlock, TransformerConfig,
                           rope_frequencies)
 
 __all__ = ["llama2_7b", "llama_tiny", "sparse_moe_lm", "hybrid_conv_moe_lm",
-           "next_token_labels", "LlamaLM",
+           "latent_moe_lm", "next_token_labels", "LlamaLM",
            "generate", "greedy_generate",
            "PagedLlamaLM", "paged_prefill", "paged_decode_step",
            "paged_extend", "paged_verify", "early_exit_params"]
@@ -90,6 +92,34 @@ def hybrid_conv_moe_lm(**kw) -> TransformerConfig:
         defaults["layer_types"] = tuple(
             "full_attention" if i % 4 == 2 else "conv"
             for i in range(defaults["n_layers"]))
+    return TransformerConfig(**defaults)
+
+
+def latent_moe_lm(**kw) -> TransformerConfig:
+    """A decoder with multi-head latent attention and a shared expert beside
+    the routed ones, at the published sizes of Moonlight-16B-A3B (config.json
+    of moonshotai/Moonlight-16B-A3B, `deepseek_v3`): 27 layers; 16 heads whose
+    queries and keys are 128 + 64 rotary dims wide and whose values are 128,
+    keys and values up-projected from one 512-wide normed latent a position,
+    the 64-dim rotary key shared by all heads, through the flash kernel; the
+    leading layer a dense gated MLP of width 11264, the others 64 gated
+    experts of width 1408, 6 a token, chosen by sigmoid score plus a constant
+    selection bias, their gates normalised (1e-20) and scaled by 2.446, beside
+    a shared expert of width 2 x 1408 that every token passes through; no
+    biases; head untied. One chip's share is ``moe_experts`` under
+    ``moe_total_experts`` from ``moe_first_expert``, a smaller ``vocab_size``
+    and fewer layers (``n_layers``)."""
+    defaults = dict(vocab_size=163840, hidden=2048, n_layers=27, n_heads=16,
+                    head_dim=192, rope_dim=64, v_head_dim=128, kv_latent_rank=512,
+                    mlp_dim=11264, moe_mlp_dim=1408, moe_shared_mlp_dim=2816,
+                    max_len=8192, norm="rmsnorm", norm_eps=1e-5, act="silu",
+                    gated_mlp=True, mlp_bias=False, causal=True, use_rope=True,
+                    rope_theta=50000.0, attn_bias=False, attn_impl="flash",
+                    flash_block=512, moe_dense_layers=1,
+                    moe_experts=64, moe_total_experts=64, moe_top_k=6,
+                    moe_dispatch="grouped", moe_bias=False, moe_router="sigmoid",
+                    moe_gate_scale=2.446, moe_gate_eps=1e-20)
+    defaults.update(kw)
     return TransformerConfig(**defaults)
 
 

@@ -13,6 +13,10 @@ modules built for GSPMD):
   * a stack's layers may differ (``layer_types``, ``moe_dense_layers``): a
     gated short convolution (``ShortConv``, ``ops.short_conv``) or attention
     as the token mixer, a dense MLP or routed experts as the feed-forward;
+  * attention's keys and values may come from one low-rank latent a position
+    with a rotary key that all heads share (``LatentAttention``,
+    ``kv_latent_rank > 0``), and the routed experts may stand beside a shared
+    expert that every token passes through (``moe_shared_mlp_dim > 0``);
   * optional ``nn.remat`` on blocks trades FLOPs for HBM: the backward pass
     runs each block forward again and keeps nothing of it, except what the
     indexed attention's hand-written backward pass reads (``attn_topk > 0``):
@@ -34,7 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["TransformerConfig", "Attention", "MlpBlock", "ShortConv", "Block", "Encoder",
+__all__ = ["TransformerConfig", "Attention", "LatentAttention", "MlpBlock", "ShortConv",
+           "Block", "Encoder",
            "RMSNorm", "apply_rope", "make_causal_mask"]
 
 Dtype = Any
@@ -108,9 +113,18 @@ class TransformerConfig:
     # renormalised (Mixtral; switch for k = 1). 'sigmoid' ('grouped' only):
     # scores by sigmoid, the top-k CHOSEN by score + a per-expert selection
     # bias (a constant of the model: collection 'constants', zeros unless
-    # given, no gradient, held by the Trainer's state), the gates the
-    # unbiased scores over (their sum + 1e-6); it sows no load-balance term.
+    # given, no gradient, held by the Trainer's state), the gates
+    # `moe_gate_scale` x the unbiased scores over (their sum + `moe_gate_eps`):
+    # normalised, then scaled; it sows no load-balance term. The softmax form
+    # reads neither of the two (its 1e-9 and its scale of 1 are its own).
     moe_router: str = "softmax"
+    moe_gate_scale: float = 1.0
+    moe_gate_eps: float = 1e-6
+    # width of a shared expert: one gated MLP (`MlpBlock`'s products, under
+    # the name 'shared') that EVERY token passes through, added to the routed
+    # experts' result; held whole on every chip of an expert-parallel
+    # deployment. 0 = none. 'grouped' only.
+    moe_shared_mlp_dim: int = 0
     # per-layer kinds. `layer_types`: the token mixer of each layer, 'conv'
     # (gated short convolution of 3 taps, ops.short_conv) or
     # 'full_attention'; () = attention everywhere. `moe_dense_layers`: that
@@ -124,6 +138,17 @@ class TransformerConfig:
     flash_block: int = 128  # query and key block of attn_impl='flash'
     attn_bias: bool = True  # biases on the q, k, v, o projections
     qk_norm: bool = False  # per-head RMSNorm on q and k, before RoPE
+    # latent attention (`LatentAttention`): `kv_latent_rank` > 0 makes every
+    # layer's keys and values an up-projection of ONE normed latent of that
+    # width a position. `head_dim` is then the whole query/key width a head,
+    # whose last `rope_dim` dims are rotary (the key's rotary part is one
+    # vector a position, shared by all heads; RoPE turns these dims alone) and
+    # whose first `head_dim - rope_dim` come from the latent; `v_head_dim` is
+    # the value width a head (0 -> head_dim). Scores scale by 1/sqrt(head_dim).
+    # All three are read by the latent form only.
+    kv_latent_rank: int = 0
+    rope_dim: int = 0
+    v_head_dim: int = 0
     # learned sparse attention (ops.sparse_attention): 0 = every causal key.
     # Each query attends to the exact `attn_topk` keys that an indexer of
     # `indexer_heads` heads of `indexer_head_dim` (one key head) scores highest;
@@ -151,6 +176,10 @@ class TransformerConfig:
     @property
     def expert_mlp_dim(self) -> int:
         return self.moe_mlp_dim or self.mlp_dim
+
+    @property
+    def value_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
 
 
 def _act_fn(name: str) -> Callable:
@@ -373,6 +402,56 @@ class Attention(nn.Module):
             name="o")(out)
 
 
+class LatentAttention(Attention):
+    """Multi-head latent attention, the up-projected form that training runs:
+    queries projected directly to ``n_heads x head_dim``; ONE down-projection
+    of the input to a ``kv_latent_rank``-wide latent plus a ``rope_dim``-wide
+    rotary key that all heads share; an RMSNorm on the latent; an
+    up-projection of the normed latent to each head's ``head_dim - rope_dim``
+    key dims and ``v_head_dim`` value dims. RoPE turns the rotary dims only.
+    The core is ``Attention._attend`` (keys wider than values: the flash
+    kernel pads each width on its own). Training and prefill only: a decode
+    cache would hold the latent and the rotary key, not per-head keys and
+    values, with the up-projections absorbed into the query and output
+    products. Device scope ``attn.latent``: everything outside the core."""
+
+    @nn.compact
+    def __call__(self, x, mask=None, positions=None):
+        cfg = self.cfg
+        if self.decode:
+            raise ValueError("latent attention has no decode cache: training and "
+                             "prefill only")
+        B, T, _ = x.shape
+        H, R = cfg.n_heads, cfg.rope_dim
+        N, V = cfg.head_dim - R, cfg.value_dim
+        proj = lambda name, feat, axes: nn.DenseGeneral(  # noqa: E731
+            features=feat, axis=-1, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            use_bias=False, kernel_init=nn.with_logical_partitioning(
+                nn.initializers.xavier_uniform(), axes), name=name)
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
+        with jax.named_scope("attn.latent"):
+            q = proj("q", (H, N + R), ("embed", "heads", "kv"))(x)
+            down = proj("kv_a", cfg.kv_latent_rank + R, ("embed", None))(x)
+            latent = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, axes=(None,),
+                             name="kv_norm")(down[..., :cfg.kv_latent_rank])
+            up = proj("kv_b", (H, N + V), (None, "heads", "kv"))(latent)
+            cos_np, sin_np = rope_frequencies(R, cfg.max_len, cfg.rope_theta)
+            cos, sin = jnp.asarray(cos_np), jnp.asarray(sin_np)
+            q_rot = apply_rope(q[..., N:], cos, sin, positions)
+            k_rot = apply_rope(down[:, :, None, cfg.kv_latent_rank:], cos, sin, positions)
+            q = jnp.concatenate([q[..., :N], q_rot], axis=-1)
+            k = jnp.concatenate([up[..., :N], jnp.broadcast_to(k_rot, (B, T, H, R))], axis=-1)
+        out = self._attend(q, k, up[..., N:], mask)
+        with jax.named_scope("attn.latent"):
+            return nn.DenseGeneral(
+                features=cfg.hidden, axis=(-2, -1), dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, use_bias=False,
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.xavier_uniform(), ("heads", "kv", "embed")),
+                name="o")(out)
+
+
 class MlpBlock(nn.Module):
     cfg: TransformerConfig
 
@@ -411,7 +490,9 @@ class MoEBlock(nn.Module):
     share of the experts (``moe_total_experts``, ``moe_first_expert``): the
     router scores all of them (its product at highest precision), the result
     is the held experts' part. The grouped layout also takes the sigmoid
-    router with its constant selection bias (``cfg.moe_router``).
+    router with its constant selection bias (``cfg.moe_router``) and a shared
+    expert (``cfg.moe_shared_mlp_dim``: a dense gated MLP every token passes
+    through, added to the routed result under the device scope ``moe.shared``).
 
     Sown under ``intermediates``: ``moe_aux_loss`` (softmax router: the
     load-balance term over all the router's experts; mean over layers = the
@@ -446,8 +527,9 @@ class MoEBlock(nn.Module):
         if cfg.moe_router not in ("softmax", "sigmoid"):
             raise ValueError(f"moe_router must be 'softmax' or 'sigmoid', got "
                              f"{cfg.moe_router!r}")
-        if not grouped and cfg.moe_router != "softmax":
-            raise ValueError("the sigmoid router needs moe_dispatch='grouped'")
+        if not grouped and (cfg.moe_router != "softmax" or cfg.moe_shared_mlp_dim):
+            raise ValueError("the sigmoid router and a shared expert need "
+                             "moe_dispatch='grouped'")
         with jax.named_scope("moe.route"):
             router = nn.Dense(
                 R, dtype=jnp.float32, param_dtype=cfg.param_dtype, use_bias=False,
@@ -483,7 +565,8 @@ class MoEBlock(nn.Module):
                 cfg.param_dtype)
 
         if grouped:
-            return self._grouped(x, xf, gate_vals, gate_idx, w)
+            y = self._grouped(x, xf, gate_vals, gate_idx, w)
+            return y + self._shared(x) if cfg.moe_shared_mlp_dim else y
 
         # capacity per expert, lane-friendly and >= 1
         C = max(int(np.ceil(cfg.moe_capacity_factor * S * k / E)), 1)
@@ -581,11 +664,13 @@ class MoEBlock(nn.Module):
 
     def _sigmoid_route(self, logits):
         """(gates, chosen experts) of the sigmoid router: the ``moe_top_k``
-        largest of score + selection bias, weighed by the unbiased scores over
-        their sum. The bias is a constant of the model: no gradient reaches
-        it, and the choice passes none. Sows the share of (token, choice)
-        pairs whose expert the scores alone would not have chosen."""
-        k = self.cfg.moe_top_k
+        largest of score + selection bias, weighed by ``moe_gate_scale`` x the
+        unbiased scores over (their sum + ``moe_gate_eps``). The bias is a
+        constant of the model: no gradient reaches it, and the choice passes
+        none. Sows the share of (token, choice) pairs whose expert the scores
+        alone would not have chosen."""
+        cfg = self.cfg
+        k = cfg.moe_top_k
         scores = jax.nn.sigmoid(logits)
         bias = self.variable("constants", "select_bias", jnp.zeros,
                              (logits.shape[-1],), jnp.float32).value
@@ -596,7 +681,18 @@ class MoEBlock(nn.Module):
         own = jnp.any(gate_idx[:, :, None] == unbiased[:, None, :], axis=-1)
         self.sow("intermediates", "moe_bias_steered_share",
                  1.0 - jnp.mean(own.astype(jnp.float32)))
-        return gate_vals / (jnp.sum(gate_vals, axis=-1, keepdims=True) + 1e-6), gate_idx
+        gate_vals = gate_vals / (jnp.sum(gate_vals, axis=-1, keepdims=True) + cfg.moe_gate_eps)
+        # a scale of 1 adds no operation: the programs without one stay as they were
+        return (gate_vals if cfg.moe_gate_scale == 1.0
+                else gate_vals * cfg.moe_gate_scale), gate_idx
+
+    def _shared(self, x):
+        """The shared expert's result for every token: ``MlpBlock``'s products
+        at width ``moe_shared_mlp_dim``."""
+        cfg = self.cfg
+        with jax.named_scope("moe.shared"):
+            return MlpBlock(dataclasses.replace(cfg, mlp_dim=cfg.moe_shared_mlp_dim),
+                            name="shared")(x)
 
     def _grouped(self, x, xf, gate_vals, gate_idx, w):
         """The held experts' part of the result, dropless (ops.grouped_ffn).
@@ -673,8 +769,9 @@ class Block(nn.Module):
             raise ValueError("a 'conv' layer has no decode cache: training and "
                              "prefill only")
         experts = cfg.moe_experts > 0 and self.layer >= cfg.moe_dense_layers
+        attn_cls = LatentAttention if cfg.kv_latent_rank > 0 else Attention
         return (ShortConv(cfg, name="conv") if conv
-                else Attention(cfg, decode=self.decode, name="attn"),
+                else attn_cls(cfg, decode=self.decode, name="attn"),
                 MoEBlock(cfg, name="mlp") if experts else MlpBlock(cfg, name="mlp"))
 
     @nn.compact

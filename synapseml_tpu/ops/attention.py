@@ -7,8 +7,10 @@ running-softmax (max/sum) accumulators stay in VMEM scratch, and only the
 normalized output is written back — O(T) memory instead of materializing the
 [T, T] score matrix.
 
-Layout contract: ``q, k, v: [B, T, H, D]`` (same as :mod:`models.flax_nets`),
-``kv_mask: [B, T]`` boolean (True = attend). Fully-masked query rows output
+Layout contract: ``q, k: [B, T, H, D]``, ``v: [B, T, H, Dv]`` (same as
+:mod:`models.flax_nets`; ``Dv`` may differ from ``D``: latent attention's keys
+are wider than its values), ``kv_mask: [B, T]`` boolean (True = attend).
+Fully-masked query rows output
 exactly zero (same contract as :func:`reference_attention` and ring
 attention) — padding rows carry no gradient and are sliced away downstream.
 
@@ -225,23 +227,25 @@ def _flash_core(q, k, v, kv_mask, causal, block_q, block_k, scale, unmasked):
 
 def _flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k, scale,
                          unmasked):
-    """q,k,v: [BH, T, Dp]; kv_mask: [BH, Tk] bool, which the ``unmasked``
-    variant (all True then: :func:`_flash_attention` decides) leaves out of
-    the kernel. ``scale`` is 1/sqrt of the TRUE head dim (D may be
-    lane-padded here). Returns (out, lse)."""
+    """q,k: [BH, T, Dp]; v: [BH, T, Dvp] (its own width: the accumulator and
+    the output are as wide as the values, not as the keys); kv_mask: [BH, Tk]
+    bool, which the ``unmasked`` variant (all True then:
+    :func:`_flash_attention` decides) leaves out of the kernel. ``scale`` is
+    1/sqrt of the TRUE query width (D may be lane-padded here). Returns
+    (out, lse)."""
     from jax.experimental import pallas as pl
 
     from jax.experimental.pallas import tpu as pltpu
 
     BH, Tq, Dp = q.shape
-    Tk = k.shape[1]
+    Tk, Dvp = k.shape[1], v.shape[2]
     n_qblocks, n_kblocks = Tq // block_q, Tk // block_k
     static = dict(block_q=block_q, block_k=block_k, n_kblocks=n_kblocks,
                   scale=scale, causal=causal)
     scratch_shapes = [
         pltpu.VMEM((block_q, 128), jnp.float32),   # running max (lane-bcast)
         pltpu.VMEM((block_q, 128), jnp.float32),   # running sum (lane-bcast)
-        pltpu.VMEM((block_q, Dp), jnp.float32),    # output accumulator
+        pltpu.VMEM((block_q, Dvp), jnp.float32),   # output accumulator
     ]
     if unmasked:
         steps = _work_steps(n_qblocks, n_kblocks, block_q, block_k, causal)
@@ -258,8 +262,8 @@ def _flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k, scale,
             num_scalar_prefetch=2, grid=(BH, len(steps[0])),
             in_specs=[pl.BlockSpec((1, block_q, Dp), q_block),
                       pl.BlockSpec((1, block_k, Dp), kv_block),
-                      pl.BlockSpec((1, block_k, Dp), kv_block)],
-            out_specs=[pl.BlockSpec((1, block_q, Dp), q_block),
+                      pl.BlockSpec((1, block_k, Dvp), kv_block)],
+            out_specs=[pl.BlockSpec((1, block_q, Dvp), q_block),
                        pl.BlockSpec((1, block_q, 1), q_block)],
             scratch_shapes=scratch_shapes)
     else:
@@ -275,11 +279,11 @@ def _flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k, scale,
             in_specs=[
                 pl.BlockSpec((1, block_q, Dp), lambda b, i, j: (b, i, 0)),
                 pl.BlockSpec((1, block_k, Dp), lambda b, i, j: (b, kv_block(i, j), 0)),
-                pl.BlockSpec((1, block_k, Dp), lambda b, i, j: (b, kv_block(i, j), 0)),
+                pl.BlockSpec((1, block_k, Dvp), lambda b, i, j: (b, kv_block(i, j), 0)),
                 pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, kv_block(i, j))),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, Dp), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, block_q, Dvp), lambda b, i, j: (b, i, 0)),
                 pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
             ],
             scratch_shapes=scratch_shapes)
@@ -287,7 +291,7 @@ def _flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k, scale,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Tq, Dp), q.dtype),
+            jax.ShapeDtypeStruct((BH, Tq, Dvp), q.dtype),
             jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32),
         ],
         interpret=platform.pallas_interpret(),
@@ -308,7 +312,7 @@ def _flash_core_bwd(causal, block_q, block_k, scale, unmasked, res, g):
     softmax/probability statistics are f32."""
     q, k, v, kv_mask, out, lse = res
     BH, Tq, Dp = q.shape
-    Tk = k.shape[1]
+    Tk, Dvp = k.shape[1], v.shape[2]     # dq, dk as wide as the keys; dv as the values
     qf, kf, vf, gf = q, k, v, g.astype(q.dtype)
     # delta_i = sum_d out_i * g_i  (rowwise), standard flash bwd identity
     delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
@@ -385,12 +389,12 @@ def _flash_core_bwd(causal, block_q, block_k, scale, unmasked, res, g):
         dk_blk, dv_blk = jax.lax.fori_loop(
             ki0 // block_q if causal else 0, n_qb, inner,
             (jnp.zeros((BH, block_k, Dp), jnp.float32),
-             jnp.zeros((BH, block_k, Dp), jnp.float32)))
+             jnp.zeros((BH, block_k, Dvp), jnp.float32)))
         return None, (dk_blk, dv_blk)
 
     _, (dk_blocks, dv_blocks) = jax.lax.scan(dkv_one, None, jnp.arange(n_kb))
     dk = jnp.reshape(dk_blocks.transpose(1, 0, 2, 3), (BH, Tk, Dp))
-    dv = jnp.reshape(dv_blocks.transpose(1, 0, 2, 3), (BH, Tk, Dp))
+    dv = jnp.reshape(dv_blocks.transpose(1, 0, 2, 3), (BH, Tk, Dvp))
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), None
 
 
@@ -401,14 +405,21 @@ def flash_attention(q, k, v, kv_mask=None, causal: bool = False,
                     block_q: int = 128, block_k: int = 128):
     """Fused blockwise attention. [B, T, H, D] layout, differentiable.
 
-    Pads T to the block size and D to the 128-lane TPU tile (zero-padding D
-    leaves dot products unchanged; padded kv positions are masked; padded q
-    rows are sliced away). A call with no ``kv_mask`` whose keys need no
-    padding builds the forward kernel's ``unmasked`` variant (no mask
-    operand, no masked-row guard, only the grid steps that hold work: key 0
-    makes every row's running maximum finite before a masked entry meets it);
-    a mask, a ragged ``Tk`` or key blocks that are not whole 128-lane tiles
-    build the ``masked`` one. Same numbers either way (module docstring).
+    ``v`` may have a width of its own (``v.shape[-1] != q.shape[-1]``; ``k``
+    is as wide as ``q``): the output is as wide as ``v``, the softmax scale is
+    1/sqrt of the true QUERY width, and each width is padded to its own whole
+    128-lane tiles (192 beside 128 runs 256 lanes of keys and 128 of values,
+    accumulator and output; one padded width would do a third more value work
+    and output bytes). Equal widths build the kernels they always built.
+
+    Pads T to the block size and each width to the 128-lane TPU tile
+    (zero-padding D leaves dot products unchanged; padded kv positions are
+    masked; padded q rows are sliced away). A call with no ``kv_mask`` whose
+    keys need no padding builds the forward kernel's ``unmasked`` variant (no
+    mask operand, no masked-row guard, only the grid steps that hold work: key
+    0 makes every row's running maximum finite before a masked entry meets
+    it); a mask, a ragged ``Tk`` or key blocks that are not whole 128-lane
+    tiles build the ``masked`` one. Same numbers either way (module docstring).
     """
     with jax.named_scope("attn.flash"):     # kernel, pads and the backward's loops alike
         return _flash_attention(q, k, v, kv_mask, causal, block_q, block_k)
@@ -416,7 +427,10 @@ def flash_attention(q, k, v, kv_mask=None, causal: bool = False,
 
 def _flash_attention(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int):
     B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    Tk, Dv = k.shape[1], v.shape[-1]
+    if k.shape[-1] != D:
+        raise ValueError(f"flash_attention: keys of width {k.shape[-1]} against "
+                         f"queries of width {D}")
     if causal and Tq != Tk:
         # the kernel aligns q/kv positions at 0 with no offset; a causal mask
         # with Tq != Tk would be silently misaligned (cf. reference_attention's
@@ -436,19 +450,19 @@ def _flash_attention(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int)
         ("variant",)).inc(variant="unmasked" if unmasked else "masked")
     if kv_mask is None:
         kv_mask = jnp.ones((B, Tk), bool)
-    Dp = _ceil_to(D, 128)
-    scale = 1.0 / np.sqrt(D)  # true head dim — padding D must not change it
+    Dp, Dvp = _ceil_to(D, 128), _ceil_to(Dv, 128)
+    scale = 1.0 / np.sqrt(D)  # true query width — padding D must not change it
 
-    def to_bh(x, T, Tp):
-        x = jnp.pad(x, ((0, 0), (0, Tp - T), (0, 0), (0, Dp - D)))
-        return jnp.transpose(x, (0, 2, 1, 3)).reshape(B * H, Tp, Dp)
+    def to_bh(x, Tp, Wp):
+        x = jnp.pad(x, ((0, 0), (0, Tp - x.shape[1]), (0, 0), (0, Wp - x.shape[3])))
+        return jnp.transpose(x, (0, 2, 1, 3)).reshape(B * H, Tp, Wp)
 
-    qb = to_bh(q, Tq, Tq_p)
-    kb = to_bh(k, Tk, Tk_p)
-    vb = to_bh(v, Tk, Tk_p)
+    qb = to_bh(q, Tq_p, Dp)
+    kb = to_bh(k, Tk_p, Dp)
+    vb = to_bh(v, Tk_p, Dvp)
     maskb = jnp.pad(kv_mask, ((0, 0), (0, Tk_p - Tk)))
     maskb = jnp.broadcast_to(maskb[:, None, :], (B, H, Tk_p)).reshape(B * H, Tk_p)
 
     out = _flash_core(qb, kb, vb, maskb, causal, block_q, block_k, scale, unmasked)
-    out = out.reshape(B, H, Tq_p, Dp)[:, :, :Tq, :D]
+    out = out.reshape(B, H, Tq_p, Dvp)[:, :, :Tq, :Dv]
     return jnp.transpose(out, (0, 2, 1, 3))

@@ -87,6 +87,27 @@ def test_flash_attention_lowers_for_tpu_at_the_long_context_cells_shape(compiled
     assert obs.get_registry().snapshot()[series] == before + 1
 
 
+@pytest.mark.parametrize("variant", ["unmasked", "masked"])
+def test_flash_attention_lowers_for_tpu_at_the_latent_cells_widths(compiled_pallas, variant):
+    """`moonlight_16b_a3b_ep8.lm_8k_latent`: two 8,192-token rows, 16 heads,
+    queries and keys 192 wide (256 lanes) beside values 128 wide (128 lanes),
+    causal, blocks of 512, forward and gradient: the kernel's output is as wide
+    as the values."""
+    from synapseml_tpu.ops import flash_attention
+
+    qk = jax.ShapeDtypeStruct((2, 8192, 16, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((2, 8192, 16, 128), jnp.bfloat16)
+    mask = [jax.ShapeDtypeStruct((2, 8192), jnp.bool_)] if variant == "masked" else []
+
+    def grad(q, k, v, *m):
+        return jax.grad(lambda q_, k_, v_: jnp.sum(flash_attention(
+            q_, k_, v_, *m, causal=True, block_q=512, block_k=512).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = _tpu_module(grad, qk, qk, v, *mask)
+    assert "tpu_custom_call" in text and "8192x256" in text and "8192x128" in text
+
+
 @pytest.mark.parametrize("N,WB", [
     (1_000_000, 32 * 256),      # chip_smoke Leg B's shape (Higgs-1M, depth-5 level)
     (1001, 300),                # ragged rows and bins
