@@ -18,13 +18,18 @@ modules built for GSPMD):
     ``kv_latent_rank > 0``), and the routed experts may stand beside a shared
     expert that every token passes through (``moe_shared_mlp_dim > 0``);
   * optional ``nn.remat`` on blocks trades FLOPs for HBM: the backward pass
-    runs each block forward again and keeps nothing of it, except what the
-    indexed attention's hand-written backward pass reads (``attn_topk > 0``):
-    its output, its selection's thresholds and two row statistics, 136 MB a
-    layer where running its tile loops again cost a fifth of the step. That
-    backward pass computes the indexer's head products, the index scores and
-    the attention scores again, and no statistic or search
-    (``ops.sparse_attention``).
+    runs each block forward again and keeps nothing of it, except what an
+    attention op's own backward pass reads beside its inputs, by the names
+    the op exports (``REMAT_SAVED_NAMES``). The indexed attention
+    (``attn_topk > 0``, ``ops.sparse_attention``): its output, its
+    selection's thresholds and two row statistics, 136 MB a layer where
+    running its tile loops again cost a fifth of the step; that backward pass
+    computes the indexer's head products, the index scores and the attention
+    scores again, and no statistic or search. The flash op
+    (``attn_impl == "flash"``, ``ops.attention``): the kernel's output and
+    log-sum-exp, 68 to 272 MB a layer where the second launch of the kernel
+    cost 6 to 9% of the step's device time. ``ring`` and ``ulysses`` keep
+    nothing: under ``remat`` their forward still runs twice a step.
 """
 
 from __future__ import annotations
@@ -813,14 +818,16 @@ class Encoder(nn.Module):
         cfg = self.cfg
         if not cfg.remat:
             return Block
-        policy = None
-        if cfg.attn_topk > 0:
-            # the backward pass re-runs the block without the indexed
-            # attention's tile loops: what their own backward pass reads
-            # (output, thresholds, row statistics) is kept
-            from ...ops.sparse_attention import REMAT_SAVED_NAMES
+        # the backward pass re-runs the block without the attention op the
+        # configuration can be seen to run: what that op's own backward pass
+        # reads is kept by the names the op gives it (the indexed attention's
+        # output, thresholds and row statistics, so no tile loop runs again;
+        # the flash op's output and log-sum-exp, so no kernel is launched again)
+        from ...ops import attention, sparse_attention
 
-            policy = jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED_NAMES)
+        names = (sparse_attention.REMAT_SAVED_NAMES if cfg.attn_topk > 0 else ()) \
+            + (attention.REMAT_SAVED_NAMES if cfg.attn_impl == "flash" else ())
+        policy = jax.checkpoint_policies.save_only_these_names(*names) if names else None
         return nn.remat(Block, static_argnums=(), policy=policy)
 
     @nn.compact

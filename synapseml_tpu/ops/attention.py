@@ -27,6 +27,23 @@ Backward pass: a custom VJP recomputes attention blockwise in XLA from the
 saved log-sum-exp — no [T, T] materialization, no second Pallas kernel needed.
 Under a causal mask its loops run over the blocks on and below the diagonal
 only (the others hold no pair and would add exact zeros).
+
+What a rematerialising caller keeps: beside the op's inputs the backward pass
+reads the kernel's output and log-sum-exp, which the forward rule names
+(``REMAT_SAVED_NAMES``). A caller that rematerialises the whole layer
+(``Encoder`` with ``cfg.remat``) keeps those two by name, so its re-run of the
+layer reads them and holds no kernel: one launch a step, not two. It re-runs
+what makes ``q``, ``k`` and ``v`` (norm, projections, RoPE, pads), which are
+not named. Kept, as the kernel wrote them (widths padded to 128 lanes, the
+log-sum-exp float32): ``[32, 32768, 128]`` bf16 = 268 MB + 4 MB for one layer
+of 32 heads of 64 over 32,768 positions, where a launch takes 70.0 ms on a
+v5e; ``[32, 8192, 128]`` bf16 = 67 MB + 1 MB a layer for two rows of 16 heads
+at 192 / 128 over 8,192, where it takes 6.3 ms. The step's peak memory does
+not follow these bytes: with the output a residual XLA:TPU lays the rest of
+the step out anew (13.63 -> 13.60 GB in the first case; 10.70 -> 14.82 GB in
+the second, five layers, where it puts every weight gradient's product after
+the last layer's backward pass: PERF.md section 6, PR 36). Outside such a
+caller the names are identities.
 """
 
 from __future__ import annotations
@@ -36,11 +53,17 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..core import observability as obs
 from ..core import platform
 
 _NEG_INF = -1e30
+_OUT = "attn_flash_out"
+_LSE = "attn_flash_lse"
+# what the backward pass reads beside the op's inputs, and so what a
+# rematerialised caller keeps in place of launching the kernel again
+REMAT_SAVED_NAMES = (_OUT, _LSE)
 
 
 def reference_attention(q, k, v, kv_mask=None, causal: bool = False,
@@ -302,6 +325,9 @@ def _flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k, scale,
 def _flash_core_fwd(q, k, v, kv_mask, causal, block_q, block_k, scale, unmasked):
     out, lse = _flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k,
                                     scale, unmasked)
+    # named here, so that the kept output is at once the residual and the
+    # value the layer goes on from
+    out, lse = checkpoint_name(out, _OUT), checkpoint_name(lse, _LSE)
     return out, (q, k, v, kv_mask, out, lse)
 
 

@@ -6,13 +6,16 @@ with a constant selection bias, embedding and head tied) trains through
 weights, the convolution's causality and tap order, the selection bias
 (steers the choice, never the gates; no gradient; constant through scanned
 dispatches and a checkpoint), the share test, exact routing under any
-imbalance, the tied leaf, flash against einsum, and the per-layer kinds."""
+imbalance, the tied leaf, flash against einsum, what a rematerialised block
+keeps of the flash kernel (one launch a layer and step, the same gradients),
+and the per-layer kinds."""
 
 import dataclasses
 import json
 import os
 import sys
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,14 +25,16 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from _remat_probe import assert_bit_equal, keep_nothing, pallas_eqns  # noqa: E402
 from perfbench.programs import hybrid_conv_moe_lm as adapter  # noqa: E402
 from perfbench.reference import hybrid_conv_moe_lm as ref  # noqa: E402
 from synapseml_tpu.core import observability as obs  # noqa: E402
 from synapseml_tpu.models.flax_nets.llama import (LlamaLM, hybrid_conv_moe_lm,  # noqa: E402
                                                   next_token_labels)
-from synapseml_tpu.models.flax_nets.transformer import (Encoder, MoEBlock,  # noqa: E402
+from synapseml_tpu.models.flax_nets.transformer import (Block, Encoder, MoEBlock,  # noqa: E402
                                                         TransformerConfig)
 from synapseml_tpu.models.trainer import Trainer, TrainerConfig  # noqa: E402
+from synapseml_tpu.ops import attention, sparse_attention  # noqa: E402
 from synapseml_tpu.ops.short_conv import gated_short_conv  # noqa: E402
 
 VOCAB = 64
@@ -366,6 +371,69 @@ def test_flash_equals_einsum_at_head_dim_64_gqa_4_to_1(remat):
     # the kernel's call carries the scope the device-time readers look for
     text = jax.jit(lambda v: flash.apply(v, x)).lower(variables).as_text(debug_info=True)
     assert "attn.flash" in text
+
+
+# ---- what the block's rematerialisation keeps of the flash kernel ---------------
+
+def tiny_lm_loss(remat=True):
+    """(loss of the parameters, parameters) of the tiny LM with the cell's
+    attention widths, 4 query heads of 64 over one key head, and two
+    full-attention layers, on 2 rows of 24 tokens (3 blocks of 8)."""
+    config = tiny_config(head_dim=64, num_key_value_heads=1,
+                         layer_types=["conv", "full_attention", "conv", "full_attention"])
+    trainer = Trainer(float32_module(config, remat=remat), one_chip_mesh(),
+                      TrainerConfig(**adapter.trainer_options(config)))
+    _, params, constants = seeded(config, 3)
+    batch = {k: jnp.asarray(v) for k, v in rows(3, 2, 24).items()}
+    return (lambda p: trainer.default_loss({"params": p, "constants": constants}, batch,
+                                           train=True)[0]), params
+
+
+@pytest.mark.parametrize("kept,launches", [("output_and_lse", 1), ("nothing", 2)])
+def test_a_step_launches_the_flash_kernel_once_a_full_attention_layer(
+        kept, launches, monkeypatch):
+    if kept == "nothing":
+        keep_nothing(monkeypatch)
+    loss_of, params = tiny_lm_loss(remat=True)
+    assert len(pallas_eqns(jax.make_jaxpr(jax.grad(loss_of))(params).jaxpr)) == 2 * launches
+
+
+@pytest.mark.parametrize("other", ["no_remat", "remat_that_keeps_nothing"])
+def test_gradients_do_not_depend_on_what_the_remat_keeps(other, monkeypatch):
+    loss_of, params = tiny_lm_loss(remat=True)
+    got = jax.grad(loss_of)(params)     # op by op: no compiler chooses fusions between the two
+    if other == "remat_that_keeps_nothing":
+        keep_nothing(monkeypatch)
+    assert_bit_equal(got, jax.grad(tiny_lm_loss(remat=other != "no_remat")[0])(params))
+
+
+@pytest.mark.parametrize("over,names", [
+    ({}, None),
+    ({"attn_impl": "flash"}, attention.REMAT_SAVED_NAMES),
+    ({"attn_topk": 8}, sparse_attention.REMAT_SAVED_NAMES),
+    ({"attn_topk": 8, "attn_impl": "flash"},
+     sparse_attention.REMAT_SAVED_NAMES + attention.REMAT_SAVED_NAMES)],
+    ids=["einsum", "flash", "indexed", "indexed_and_flash"])
+def test_the_remat_keeps_the_names_of_the_ops_the_configuration_runs(over, names, monkeypatch,
+                                                                      capsys):
+    cfg = TransformerConfig(hidden=32, n_layers=1, n_heads=4, mlp_dim=64, causal=True, **over)
+    assert Encoder(cfg)._block_cls() is Block                     # remat off: the class itself
+    seen = {}
+    monkeypatch.setattr(nn, "remat", lambda cls, **kw: seen.update(kw, cls=cls))
+    Encoder(dataclasses.replace(cfg, remat=True))._block_cls()
+    assert seen["cls"] is Block and seen["static_argnums"] == ()
+    if names is None:
+        assert seen["policy"] is None
+        return
+    every = sparse_attention.REMAT_SAVED_NAMES + attention.REMAT_SAVED_NAMES + ("another",)
+    kept = []
+    for name in every:
+        # a kept value is a residual beside the argument; one that is not is computed again
+        jax.ad_checkpoint.print_saved_residuals(jax.checkpoint(
+            lambda x: jnp.sin(jax.ad_checkpoint.checkpoint_name(jnp.sin(x), name)),
+            policy=seen["policy"]), jnp.ones(3))
+        kept += [name] * (len(capsys.readouterr().out.splitlines()) - 1)
+    assert kept == list(names)
 
 
 def test_layer_types_and_num_dense_layers_build_the_modules_they_name():

@@ -7,7 +7,9 @@ with scaled gates beside a shared expert; untied head) trains through
 equal (nope 8, rope 4, value 6, latent 16), so that a mixed-up width cannot
 pass; the shared rotary key, RoPE on the rotary dims alone, the latent's norm
 before the up-projection, flash against einsum at two widths, the value
-operand's own padding, the share test with the shared expert counted once,
+operand's own padding, what a rematerialised block keeps of the flash kernel
+(one launch a layer and step, the same gradients), the share test with the
+shared expert counted once,
 the gates' scale, the selection bias's constancy, exact routing under any
 imbalance."""
 
@@ -26,6 +28,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from _remat_probe import assert_bit_equal, keep_nothing, pallas_eqns  # noqa: E402
 from perfbench.programs import latent_moe_lm as adapter  # noqa: E402
 from perfbench.reference import latent_moe_lm as ref  # noqa: E402
 from synapseml_tpu.models.flax_nets.llama import (LlamaLM, hybrid_conv_moe_lm,  # noqa: E402
@@ -332,17 +335,6 @@ def test_flash_equals_einsum_with_keys_wider_than_values(block, t, variant, rema
                                rtol=1e-6, atol=1e-7)
 
 
-def _pallas_eqn(jaxpr):
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            return eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            found = _pallas_eqn(sub)
-            if found is not None:
-                return found
-    return None
-
-
 @pytest.mark.parametrize("block,t", [(512, 1024), (64, 200)], ids=["unmasked", "masked"])
 def test_the_kernels_value_operand_is_padded_to_its_own_lanes(block, t):
     """Queries and keys of 192 run 256 lanes; values of 128 stay 128, and so do
@@ -350,7 +342,7 @@ def test_the_kernels_value_operand_is_padded_to_its_own_lanes(block, t):
     q, k, v, _ = _qkv(t, d=192, dv=128, heads=2, dtype=jnp.bfloat16)
     jaxpr = jax.make_jaxpr(lambda *a: flash_attention(
         *a, causal=True, block_q=block, block_k=block))(q, k, v)
-    eqn = _pallas_eqn(jaxpr.jaxpr)
+    eqn = pallas_eqns(jaxpr.jaxpr)[0]
     tp = -(-t // block) * block
     wide = [x.aval.shape for x in eqn.invars if x.aval.shape[-2:] == (tp, 256)]
     narrow = [x.aval.shape for x in eqn.invars if x.aval.shape[-2:] == (tp, 128)]
@@ -373,7 +365,7 @@ def test_equal_widths_keep_one_padded_width_throughout(d, block, t, mask):
     m = jnp.arange(t)[None, :] < jnp.array([[t], [t - 5]]) if mask else None
     flash = lambda *a: flash_attention(  # noqa: E731
         *a, kv_mask=m, causal=True, block_q=block, block_k=block)
-    eqn = _pallas_eqn(jax.make_jaxpr(flash)(q, k, v).jaxpr)
+    eqn = pallas_eqns(jax.make_jaxpr(flash)(q, k, v).jaxpr)[0]
     dp, tp = -(-d // 128) * 128, -(-t // block) * block
     assert [x.aval.shape for x in eqn.invars if x.aval.ndim == 3 and x.aval.shape[1] == tp] \
         == [(4, tp, dp)] * 3
@@ -409,6 +401,38 @@ def test_a_latent_stack_through_flash_equals_the_same_through_einsum(remat):
     text = jax.jit(lambda v: flash.apply(v, x)).lower(variables).as_text(debug_info=True)
     assert "attn.latent" in text and "attn.flash" in text
     assert "attn.latent/attn.flash" not in text
+
+
+# ---- what the block's rematerialisation keeps of the flash kernel ---------------
+
+def tiny_lm_loss(remat=True):
+    """(loss of the parameters, parameters) of the tiny LM with the cell's
+    attention widths (keys 128 + 64, values 128) in its three layers, on 2
+    rows of 16 tokens (2 blocks of 8)."""
+    config = tiny_config(qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128)
+    trainer = Trainer(float32_module(config, remat=remat), one_chip_mesh(),
+                      TrainerConfig(**adapter.trainer_options(config)))
+    _, params, constants = seeded(config, 3)
+    batch = {k: jnp.asarray(v) for k, v in rows(3, 2, 16).items()}
+    return (lambda p: trainer.default_loss({"params": p, "constants": constants}, batch,
+                                           train=True)[0]), params
+
+
+@pytest.mark.parametrize("kept,launches", [("output_and_lse", 1), ("nothing", 2)])
+def test_a_step_launches_the_flash_kernel_once_a_layer(kept, launches, monkeypatch):
+    if kept == "nothing":
+        keep_nothing(monkeypatch)
+    loss_of, params = tiny_lm_loss(remat=True)
+    assert len(pallas_eqns(jax.make_jaxpr(jax.grad(loss_of))(params).jaxpr)) == 3 * launches
+
+
+@pytest.mark.parametrize("other", ["no_remat", "remat_that_keeps_nothing"])
+def test_gradients_do_not_depend_on_what_the_remat_keeps(other, monkeypatch):
+    loss_of, params = tiny_lm_loss(remat=True)
+    got = jax.grad(loss_of)(params)     # op by op: no compiler chooses fusions between the two
+    if other == "remat_that_keeps_nothing":
+        keep_nothing(monkeypatch)
+    assert_bit_equal(got, jax.grad(tiny_lm_loss(remat=other != "no_remat")[0])(params))
 
 
 # ---- the experts: share, shared expert, gates, bias ------------------------------------

@@ -23,6 +23,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from _remat_probe import keep_nothing  # noqa: E402
 from perfbench.programs import sparse_moe_lm as adapter  # noqa: E402
 from perfbench.reference import sparse_moe_lm as ref  # noqa: E402
 from synapseml_tpu.core import observability as obs  # noqa: E402
@@ -210,8 +211,7 @@ def test_gradients_do_not_depend_on_what_the_remat_keeps(other, monkeypatch):
         loss_of, _ = tiny_lm_loss(remat=False)
         rel = 1e-6                     # another program: float32 sums in another order
     else:
-        remat = nn.remat               # `transformer.nn` is this module
-        monkeypatch.setattr(nn, "remat", lambda cls, **_: remat(cls, static_argnums=()))
+        keep_nothing(monkeypatch)
         loss_of, _ = tiny_lm_loss(remat=True)
         # the attention's loops run again in place of reading what was kept
         found = attention_work(jax.make_jaxpr(jax.grad(loss_of))(params).jaxpr)
