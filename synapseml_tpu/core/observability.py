@@ -721,6 +721,19 @@ class Tracer:
         if span in stack:
             del stack[stack.index(span):]
 
+    def record_span(self, name: str, start_ns: int, duration_ms: float,
+                    attributes: dict | None = None,
+                    parent: SpanContext | None = None) -> Span:
+        """A finished span whose times the caller took itself: for work that
+        is known to deserve a span only once it is over (a call that turned
+        out to compile). ``start_ns`` is ``time.time_ns()`` at its start."""
+        span = self.start_span(name, attributes, parent)
+        span.start_ns = int(start_ns)
+        span.start_wall = span.start_ns / 1e9
+        span.duration_ms = float(duration_ms)
+        self.end_span(span)
+        return span
+
     @contextlib.contextmanager
     def span(self, name: str, attributes: dict | None = None,
              parent: SpanContext | None = None) -> Iterator[Span]:

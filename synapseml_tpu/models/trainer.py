@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import threading
 import time
 from typing import Any, Callable, Iterator
 
@@ -130,8 +131,18 @@ _TRAIN_METRICS = obs.HandleCache(lambda reg: {
         ("program",)),
     "compiles": reg.counter(
         "synapseml_train_step_compiles_total",
-        "dispatches across which the jitted step's cache grew (a new "
-        "signature: traced, then compiled or loaded)", ("program",)),
+        "executables the jitted step got (a new signature: traced, lowered, "
+        "then compiled or loaded): one a train.compile span", ("program",)),
+    "compile_seconds": reg.counter(
+        "synapseml_train_compile_seconds_total",
+        "jax's own seconds of building the jitted step's executables, by "
+        "phase (trace | lower | backend: compile or cache load)",
+        ("program", "phase")),
+    "program_bytes": reg.gauge(
+        "synapseml_train_program_bytes",
+        "XLA's memory analysis of the jitted step's newest executable, bytes "
+        "a device by kind (args | outputs | aliased | temp | code)",
+        ("program", "kind")),
     "loop_ms": {phase: reg.histogram(
         "synapseml_train_loop_ms",
         "host time of one boundary of the fit loop (the train.<phase> "
@@ -226,17 +237,100 @@ def _place_attrs(batch) -> dict:
     return {"bytes": _tree_nbytes(host), "ahead": not host}
 
 
+def _leading_dim(stacked) -> int:
+    """K of a pytree whose leaves lead with K."""
+    return int(np.shape(jax.tree.leaves(stacked)[0])[0])
+
+
 def _stack_steps(batches: list) -> dict:
     """K same-shape batches -> one pytree whose leaves lead with K."""
     return jax.tree.map(lambda *xs: jnp.stack(xs), *batches)
 
 
-def _jit_cache_size(fn) -> int:
-    """Entries of a jitted callable's signature cache; it grows when a call
-    traces (and compiles or loads) a new program. A private counter of
-    jax 0.9.0: where a later jax has none, no dispatch reads as compiled."""
-    size = getattr(fn, "_cache_size", None)
-    return size() if size is not None else 0
+# jax's monitoring events of a build, by the phase they time, and of the
+# persistent cache, by what it did
+_BUILD_PHASES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+                 "/jax/core/compile/backend_compile_duration": "backend"}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+# train.compile's byte attributes: (the gauge's kind, the field of XLA's
+# memory analysis)
+_PROGRAM_BYTES = {"arg_bytes": ("args", "argument_size_in_bytes"),
+                  "out_bytes": ("outputs", "output_size_in_bytes"),
+                  "alias_bytes": ("aliased", "alias_size_in_bytes"),
+                  "temp_bytes": ("temp", "temp_size_in_bytes"),
+                  "code_bytes": ("code", "generated_code_size_in_bytes")}
+
+_building = threading.local()  # .record: the thread's open _Build, or None
+
+
+class _Build:
+    """jax's own account of what one call built: the seconds of its trace,
+    lowering and backend (compile or cache load) events, the executables it
+    got (a backend event each) and whether the persistent cache served them.
+    Open from its making to ``close()``, on the thread that made it: an event
+    adds to the open record of the thread it fires on, so what another
+    thread compiles meanwhile (the chunk producer's placing) is not counted."""
+
+    __slots__ = ("under", "start_ns", "host_ms", "seconds", "executables",
+                 "cache", "taken", "_t0")
+
+    def __init__(self, under: "obs.Span"):
+        self.under = under            # the train.dispatch span it belongs to
+        self.seconds = {"trace": 0.0, "lower": 0.0, "backend": 0.0}
+        self.executables = 0
+        self.cache = "off"
+        self.taken: dict | None = None   # of(): XLA's byte counts, and take_ms
+        self.start_ns = time.time_ns()
+        self._t0 = time.perf_counter()
+        _building.record = self
+
+    def close(self) -> None:
+        _building.record = None
+        self.host_ms = (time.perf_counter() - self._t0) * 1e3
+
+    def of(self, executable) -> None:
+        """Keep XLA's memory analysis of ``executable``: bytes a device."""
+        stats = executable.memory_analysis()
+        if stats is not None:
+            self.taken = {name: int(getattr(stats, field))
+                          for name, (_, field) in _PROGRAM_BYTES.items()}
+
+    def attributes(self) -> dict:
+        return {**{f"{phase}_ms": s * 1e3 for phase, s in self.seconds.items()},
+                "cache": self.cache, **(self.taken or {})}
+
+
+def _on_build_seconds(event: str, seconds: float, **_) -> None:
+    rec = getattr(_building, "record", None)
+    phase = _BUILD_PHASES.get(event)
+    if rec is not None and phase is not None:
+        rec.seconds[phase] += seconds
+        rec.executables += phase == "backend"
+
+
+def _on_cache_event(event: str, **_) -> None:
+    rec = getattr(_building, "record", None)
+    served = _CACHE_EVENTS.get(event)
+    if rec is not None and served is not None and rec.cache != "miss":
+        rec.cache = served        # one miss among a build's programs: a miss
+
+
+_listening = threading.Lock()
+_listens = False
+
+
+def _listen_for_builds() -> None:
+    """Register the two listeners with ``jax.monitoring``, once a process.
+    jax calls them where something is traced, lowered, compiled or loaded,
+    and never between."""
+    global _listens
+    with _listening:
+        if not _listens:
+            jax.monitoring.register_event_duration_secs_listener(_on_build_seconds)
+            jax.monitoring.register_event_listener(_on_cache_event)
+            _listens = True
 
 
 class NonFiniteLossError(RuntimeError):
@@ -400,6 +494,9 @@ class Trainer:
         # inside fit: the loop's own count of the step the next dispatch
         # trains from (train.dispatch's first_step); None outside
         self._fit_step: int | None = None
+        # executables each jitted step got so far (train.compile's signature)
+        self._signatures = {"scan": 0, "step": 0}
+        _listen_for_builds()
         self._metrics: list[dict] = []
         # newest optimizer step whose loss was finite (post-step numbering,
         # comparable to checkpoint step numbers); -1 until the first loss
@@ -663,13 +760,15 @@ class Trainer:
         return step_fn
 
     @contextlib.contextmanager
-    def _dispatching(self, program: str, fn, steps: int) -> Iterator[None]:
-        """The ``train.dispatch`` span around a call of the jitted step ``fn``.
-        The call returns when the program is enqueued, and holds the trace and
-        the compile when the signature is new (``compiled``). ``first_step``
-        is the fit loop's own count of the step this dispatch trains from:
-        None outside ``fit``, since reading ``state.step`` would wait for the
-        device.
+    def _dispatching(self, program: str, steps: int) -> Iterator[_Build]:
+        """The ``train.dispatch`` span around a call of a jitted step. The
+        call returns when the program is enqueued, and holds the trace, the
+        lowering and the compile or cache load when the signature is new:
+        ``compiled`` says whether jax reported an executable built under it,
+        and the ``_Build`` this yields holds what it cost (``_built`` turns
+        it into ``train.compile`` spans). ``first_step`` is the fit loop's
+        own count of the step this dispatch trains from: None outside
+        ``fit``, since reading ``state.step`` would wait for the device.
 
         The caller makes the call in its own frame, inside this ``with``: a
         helper frame above it, or a dozen more locals in ``fit``, moved the
@@ -678,26 +777,81 @@ class Trainer:
         chip's host (PERF.md section 6, PR 27;
         ``perfbench/tools/chunk_shim.c`` counts it on any machine)."""
         first = self._fit_step
-        grown_from = _jit_cache_size(fn)
         with _LoopSpan("train.dispatch", {"program": program, "steps": steps,
                                           "first_step": first}) as ls:
-            yield
-            compiled = _jit_cache_size(fn) > grown_from
-            ls.span.set_attribute("compiled", compiled)
+            build = _Build(ls.span)
+            try:
+                yield build
+            finally:
+                build.close()
+            ls.span.set_attribute("compiled", build.executables > 0)
         if first is not None:
             self._fit_step = first + steps
+        _TRAIN_METRICS.get()["dispatches"].inc(program=program)
+
+    @contextlib.contextmanager
+    def _built(self, program: str, built: _Build) -> Iterator[None]:
+        """After a dispatch under which a jitted step got an executable: one
+        ``train.compile`` span an executable, under that dispatch's span,
+        with jax's seconds by phase, what the persistent cache did, and XLA's
+        memory analysis of the executable the NEXT dispatch runs (bytes a
+        device, and ``take_ms``, the host time of reading them). The caller
+        takes that one inside this ``with`` and hands it to ``built.of``:
+        ``fn.trace(sd, placed).lower().compile()`` on the dispatch's own
+        outputs and batch, which is the next dispatch's signature.
+
+        Where the outputs' type is the inputs' (a state that came out of the
+        step, or was placed like one), that finds what the call just built
+        and costs a lookup. Where it is new (a fresh state's counters carry no
+        mesh), it builds the second executable here, and the next dispatch,
+        which would have built it, finds it: the work moves by one dispatch
+        and none is added. The executable that ran once and was replaced
+        keeps its times and has no bytes.
+
+        The caller makes the take in its own frame, as the dispatch, binds no
+        name for it and spells it ``trace().lower()``: through ``fn.lower``,
+        or with two more locals in ``train_steps_scan``, the second
+        signature's trace fell on a slow alignment of the frame stack
+        (``_dispatching``; the counts are in PERF.md section 6, PR 37)."""
+        take = _Build(built.under)
+        try:
+            with jax.profiler.TraceAnnotation("train.compile"), self.mesh.scope():
+                yield
+        finally:
+            take.close()
+            if built.taken is not None:   # beside the bytes: what reading them took
+                built.taken["take_ms"] = take.host_ms
+            if take.executables:      # a second executable: the bytes are its
+                take.taken, built.taken = built.taken, None
+                self._compile_span(program, built)
+                self._compile_span(program, take)
+            else:                     # it found the executable the call built
+                self._compile_span(program, built)
+
+    def _compile_span(self, program: str, build: _Build) -> None:
+        self._signatures[program] += 1
+        obs.get_tracer().record_span(
+            "train.compile", build.start_ns, build.host_ms,
+            {"program": program, "signature": self._signatures[program],
+             **build.attributes()}, parent=build.under.context)
         m = _TRAIN_METRICS.get()
-        m["dispatches"].inc(program=program)
-        if compiled:
-            m["compiles"].inc(program=program)
+        m["compiles"].inc(program=program)
+        for phase, seconds in build.seconds.items():
+            m["compile_seconds"].inc(seconds, program=program, phase=phase)
+        if build.taken is not None:
+            for name, (kind, _) in _PROGRAM_BYTES.items():
+                m["program_bytes"].set(build.taken[name], program=program, kind=kind)
 
     def train_step(self, state: TrainState, batch: dict) -> tuple[TrainState, dict]:
         if self._train_step is None:
             self._train_step = jax.jit(self._step_fn(), donate_argnums=(0,))
         with _LoopSpan("train.place", _place_attrs(batch)):
             placed = self.mesh.shard_batch(batch)
-        with self._dispatching("step", self._train_step, 1), self.mesh.scope():
+        with self._dispatching("step", 1) as built, self.mesh.scope():
             sd, metrics = self._train_step(state._step_input(), placed)
+        if built.executables:
+            with self._built("step", built):
+                built.of(self._train_step.trace(sd, placed).lower().compile())
         return TrainState(params=sd["params"], opt_state=sd["opt_state"], step=sd["step"],
                           batch_stats=sd["batch_stats"], constants=sd["constants"]), metrics
 
@@ -717,9 +871,11 @@ class Trainer:
             self._scan_step = jax.jit(multi, donate_argnums=(0,))
         with _LoopSpan("train.place", _place_attrs(stacked_batches)):
             placed = self.mesh.shard_stacked_batch(stacked_batches)
-        steps = int(np.shape(jax.tree.leaves(stacked_batches)[0])[0])
-        with self._dispatching("scan", self._scan_step, steps), self.mesh.scope():
+        with self._dispatching("scan", _leading_dim(placed)) as built, self.mesh.scope():
             sd, metrics = self._scan_step(state._step_input(), placed)
+        if built.executables:
+            with self._built("scan", built):
+                built.of(self._scan_step.trace(sd, placed).lower().compile())
         return (TrainState(params=sd["params"], opt_state=sd["opt_state"], step=sd["step"],
                            batch_stats=sd["batch_stats"], constants=sd["constants"]), metrics)
 

@@ -3,9 +3,11 @@ profiler's clock, the scope names inside the step, and the gauges that read
 them (a two-layer encoder on the CPU; times here prove order and clock, never
 speed)."""
 
+import contextlib
 import glob
 import os
 import threading
+import types
 
 import jax
 import numpy as np
@@ -18,6 +20,15 @@ from synapseml_tpu.models.trainer import Trainer, TrainerConfig
 
 CHUNK, DISPATCHES, BATCH = 2, 3, 8
 COMPILES = 'synapseml_train_step_compiles_total{program="%s"}'
+COMPILE_S = 'synapseml_train_compile_seconds_total{phase="%s",program="%s"}'
+PROGRAM_BYTES = 'synapseml_train_program_bytes{kind="%s",program="%s"}'
+# train.compile's byte attribute -> (the gauge's kind, XLA's field)
+BYTES = {"arg_bytes": ("args", "argument_size_in_bytes"),
+         "out_bytes": ("outputs", "output_size_in_bytes"),
+         "alias_bytes": ("aliased", "alias_size_in_bytes"),
+         "temp_bytes": ("temp", "temp_size_in_bytes"),
+         "code_bytes": ("code", "generated_code_size_in_bytes")}
+BACKEND = "/jax/core/compile/backend_compile_duration"
 DISPATCHED = 'synapseml_train_dispatches_total{program="%s"}'
 LOOP_MS = 'synapseml_train_loop_ms{phase="%s"}'
 
@@ -44,6 +55,12 @@ def _children(spans, root):
 
 def _roots(spans):
     return [s for s in spans if s.name == "train.fit"]
+
+
+def _compiles(spans, trace_id=None):
+    """The `train.compile` spans (of one fit's trace), in the order recorded."""
+    return [s for s in spans if s.name == "train.compile"
+            and trace_id in (None, s.trace_id)]
 
 
 @pytest.fixture(scope="module")
@@ -192,21 +209,50 @@ def test_the_loop_order_within_a_cycle(chunked):
         assert placed.start_ns <= fetch_before.end_ns
 
 
-# ---- (b) which dispatch compiled -------------------------------------------
+# ---- (b) which dispatch compiled, and what it built -------------------------
 
 def test_compiled_is_the_first_dispatch(chunked):
     kids = _children(chunked["spans"], chunked["root"])["train.dispatch"]
-    assert kids[0].attributes["compiled"] is True
-    # jax 0.9.0 meets a second signature at the second dispatch: the state is
-    # then the step's own output (PERF.md, section 7), and never again
-    assert [s.attributes["compiled"] for s in kids[1:]] == [True, False]
+    # jax reported an executable under the first call alone. A fresh state's
+    # counters carry no mesh and the step's own output does, so the step has a
+    # second signature (PERF.md, section 7): it is built right after the first
+    # dispatch, and the second dispatch finds it
+    assert [s.attributes["compiled"] for s in kids] == [True, False, False]
+
+
+def test_one_compile_span_a_signature_under_its_dispatch(chunked):
+    first = _children(chunked["spans"], chunked["root"])["train.dispatch"][0]
+    built = _compiles(chunked["spans"])
+    assert [(s.attributes["program"], s.attributes["signature"]) for s in built] \
+        == [("scan", 1), ("scan", 2)]
+    for s in built:
+        assert s.parent_id == first.span_id and s.trace_id == chunked["root"].trace_id
+        assert s.start_ns >= first.start_ns and s.duration_ms > 0
+        a = s.attributes
+        assert min(a["trace_ms"], a["lower_ms"], a["backend_ms"]) > 0
+        assert a["cache"] in ("hit", "miss", "off")
+    # the first ran once, under the dispatch that built it; the second was
+    # built after that dispatch had ended, and is the one every later one runs
+    assert built[0].start_ns <= first.start_ns + 1_000_000
+    assert built[1].start_ns >= first.end_ns - 1000
+    assert not set(BYTES) & set(built[0].attributes)
+    assert all(built[1].attributes[name] >= 0 for name in BYTES)
+    assert built[1].attributes["temp_bytes"] > 0 < built[1].attributes["arg_bytes"]
+    # `take_ms`: the host time of the call that handed the executable over
+    assert "take_ms" not in built[0].attributes
+    assert built[1].attributes["take_ms"] == pytest.approx(built[1].duration_ms)
 
 
 def test_compile_counter_equals_compiled_spans(chunked):
-    kids = _children(chunked["spans"], chunked["root"])["train.dispatch"]
-    n = sum(s.attributes["compiled"] for s in kids)
-    assert chunked["snapshot"][COMPILES % "scan"] == n == 2
+    built = _compiles(chunked["spans"])
+    assert chunked["snapshot"][COMPILES % "scan"] == len(built) == 2
     assert chunked["snapshot"][DISPATCHED % "scan"] == DISPATCHES
+    for phase in ("trace", "lower", "backend"):
+        assert chunked["snapshot"][COMPILE_S % (phase, "scan")] == pytest.approx(
+            sum(s.attributes[phase + "_ms"] for s in built) / 1e3)
+    for name, (kind, _) in BYTES.items():
+        assert chunked["snapshot"][PROGRAM_BYTES % (kind, "scan")] \
+            == built[-1].attributes[name]
 
 
 def test_second_fit_on_the_returned_state_compiles_nothing(chunked):
@@ -218,6 +264,124 @@ def test_second_fit_on_the_returned_state_compiles_nothing(chunked):
     assert chunked["second_root"].trace_id != chunked["root"].trace_id
     assert chunked["snapshot_after"][COMPILES % "scan"] == 2
     assert chunked["snapshot_after"][DISPATCHED % "scan"] == 2 * DISPATCHES
+    assert chunked["snapshot_after"][COMPILE_S % ("backend", "scan")] \
+        == chunked["snapshot"][COMPILE_S % ("backend", "scan")]
+
+
+def test_gauges_equal_an_independent_compile_of_the_step(chunked):
+    """`synapseml_train_program_bytes` is XLA's memory analysis of the
+    executable the fit's dispatches run: another `jit` of the same step,
+    lowered for a state that came out of it, reads the same five counts."""
+    tr = chunked["trainer"]
+    state = tr.init_state(_batch(), jax.random.PRNGKey(0))
+    stacked = {k: np.stack([v] * CHUNK) for k, v in _batch().items()}
+    state, _ = tr.train_steps_scan(state, stacked)
+    step_fn = tr._step_fn()
+    with tr.mesh.scope():
+        stats = jax.jit(lambda sd, b: jax.lax.scan(step_fn, sd, b), donate_argnums=(0,)) \
+            .lower(state._step_input(), tr.mesh.shard_stacked_batch(stacked)) \
+            .compile().memory_analysis()
+    for kind, field in BYTES.values():
+        assert chunked["snapshot"][PROGRAM_BYTES % (kind, "scan")] == getattr(stats, field)
+
+
+def test_a_state_placed_like_the_steps_output_has_one_signature(chunked):
+    """Where the first state already has the type of the step's output, one
+    executable is built, under the first dispatch, and the bytes are its."""
+    obs.reset_tracer()
+    tr = _trainer(chunked["trainer"].mesh)
+    state = tr.init_state(_batch(), jax.random.PRNGKey(0))
+    rep = tr.mesh.replicated()
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: jax.device_put(x, rep) if getattr(x, "ndim", None) == 0 else x, tree)
+    state.opt_state, state.step = place(state.opt_state), place(state.step)
+    n = CHUNK * 2
+    tr.fit(state, iter([_batch(i) for i in range(n)]), max_steps=n, scan_chunk=CHUNK)
+    spans = obs.get_tracer().finished_spans()
+    (built,) = _compiles(spans)
+    dispatches = _children(spans, _roots(spans)[0])["train.dispatch"]
+    assert [s.attributes["compiled"] for s in dispatches] == [True, False]
+    assert built.parent_id == dispatches[0].span_id
+    assert built.attributes["signature"] == 1 and set(BYTES) <= set(built.attributes)
+    assert abs(built.duration_ms - dispatches[0].duration_ms) < 5.0
+    # a lookup after the dispatch found the executable the call had built
+    assert 0 < built.attributes["take_ms"] < built.duration_ms
+
+
+def test_a_batch_of_another_shape_mid_fit_is_the_next_signature(chunked):
+    tr = chunked["trainer"]                  # has met two signatures
+    before = obs.get_registry().snapshot().get(COMPILES % "scan", 0)
+    obs.reset_tracer()
+    state = tr.init_state(_batch(), jax.random.PRNGKey(0))
+    batches = [_batch(0), _batch(1), _batch(2, T=24), _batch(3, T=24)]
+    tr.fit(state, iter(batches), max_steps=4, scan_chunk=CHUNK)
+    spans = obs.get_tracer().finished_spans()
+    dispatches = _children(spans, _roots(spans)[0])["train.dispatch"]
+    assert [s.attributes["compiled"] for s in dispatches] == [False, True]
+    (built,) = _compiles(spans)
+    assert built.parent_id == dispatches[1].span_id
+    assert built.attributes["signature"] == 3 and set(BYTES) <= set(built.attributes)
+    assert obs.get_registry().snapshot()[COMPILES % "scan"] == before + 1
+
+
+def _backend_events(run) -> int:
+    """`backend_compile_duration` events the whole process fires while `run()`."""
+    fired = []
+
+    def listener(event, seconds, **_):
+        fired.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        run()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    return fired.count(BACKEND)
+
+
+def test_a_fit_builds_nothing_twice(mesh_dp8, monkeypatch):
+    """Taking the next dispatch's executable after the first moves a build
+    and adds none: a whole fit fires as many backend-compile events as the
+    same fit with nothing taken."""
+    def fit():
+        tr = _trainer(mesh_dp8)
+        n = CHUNK * DISPATCHES
+        state = tr.init_state(_batch(), jax.random.PRNGKey(0))
+        tr.fit(state, iter([_batch(i) for i in range(n)]), max_steps=n, scan_chunk=CHUNK)
+
+    fit()                                    # `init_state`'s and the stack's programs
+    taking = _backend_events(fit)
+
+    @contextlib.contextmanager
+    def nothing_built(self, program, steps):
+        yield types.SimpleNamespace(executables=0)
+
+    monkeypatch.setattr(Trainer, "_dispatching", nothing_built)
+    obs.reset_tracer()
+    assert _backend_events(fit) == taking == 2
+    assert not _compiles(obs.get_tracer().finished_spans())
+
+
+def test_a_compile_on_another_thread_is_not_the_dispatchs(chunked):
+    tr = chunked["trainer"]
+    obs.reset_tracer()
+    fired = []
+
+    def compile_elsewhere():
+        fired.append(_backend_events(
+            lambda: jax.jit(lambda x: x * 3 + len(fired))(np.ones(5)).block_until_ready()))
+
+    with tr._dispatching("scan", CHUNK) as built:
+        t = threading.Thread(target=compile_elsewhere)
+        t.start()
+        t.join(timeout=120)
+    assert not t.is_alive() and fired == [1]
+    assert built.executables == 0 and not any(built.seconds.values())
+    (d,) = [s for s in obs.get_tracer().finished_spans() if s.name == "train.dispatch"]
+    assert d.attributes["compiled"] is False
+    with tr._dispatching("scan", CHUNK) as built:           # ... and on this one it is
+        jax.jit(lambda x: x * 5)(np.ones(5)).block_until_ready()
+    assert built.executables == 1 and built.seconds["backend"] > 0
 
 
 @pytest.mark.parametrize("phase,count", [
@@ -269,7 +433,7 @@ def per_step(mesh_dp8, tmp_path_factory):
                scan_chunk=1, log_every=2, checkpointer=ck, checkpoint_every=2)
     spans = obs.get_tracer().finished_spans()
     return {"root": _roots(spans)[0], "kids": _children(spans, _roots(spans)[0]),
-            "snapshot": obs.get_registry().snapshot()}
+            "spans": spans, "snapshot": obs.get_registry().snapshot()}
 
 
 def test_per_step_path_records_program_step(per_step):
@@ -280,8 +444,13 @@ def test_per_step_path_records_program_step(per_step):
     assert per_step["root"].attributes == {"scan_chunk": 1, "first_step": 0,
                                            "steps_done": 4}
     assert per_step["snapshot"][DISPATCHED % "step"] == 4
-    assert per_step["snapshot"][COMPILES % "step"] \
-        == sum(s.attributes["compiled"] for s in kids) >= 1
+    # the per-step program has the scanned one's two signatures, both built
+    # at the first dispatch
+    assert [s.attributes["compiled"] for s in kids] == [True, False, False, False]
+    built = _compiles(per_step["spans"])
+    assert [(s.attributes["program"], s.attributes["signature"], s.parent_id)
+            for s in built] == [("step", 1, kids[0].span_id), ("step", 2, kids[0].span_id)]
+    assert per_step["snapshot"][COMPILES % "step"] == 2
     assert "train.chunk_wait" not in per_step["kids"]
 
 
