@@ -25,8 +25,26 @@ entry is masked at all. Both compute the same numbers wherever both apply.
 
 Backward pass: a custom VJP recomputes attention blockwise in XLA from the
 saved log-sum-exp — no [T, T] materialization, no second Pallas kernel needed.
-Under a causal mask its loops run over the blocks on and below the diagonal
-only (the others hold no pair and would add exact zeros).
+It walks the (key block, query block) pairs that hold work once (under a
+causal mask those on and below the diagonal: the others hold no pair and would
+add exact zeros): a pair forms its score, probability and gradient tiles one
+time and feeds ``dq``, ``dk`` and ``dv`` from them, five products where a loop
+nest for ``dq`` and a second one for ``dk`` and ``dv`` ran seven (PR 38). The
+key block's ``dk`` and ``dv`` ride in the inner loop's carry; ``dq`` is a
+float32 buffer of query blocks, and each pair reads its block, adds to it and
+writes it back through HBM inside the product's own fusion: ``[32, 512, 128]``
+float32 = 8.4 MB each way a pair for 32 heads of 64 over 32,768 positions
+(2,080 pairs of 512-blocks), ``[32, 512, 256]`` = 16.8 MB for two rows of 16
+heads 192 wide over 8,192 (136 pairs a layer). The buffer (537 MB; 268 MB)
+lives beside ``dk`` and ``dv`` for the length of the pass. The pair's body
+follows the call as the forward kernel's does: without a key mask
+(``unmasked``) it selects on no mask block, builds no ``[BH, block_q,
+block_k]`` mask tile and guards no masked row (``exp`` of ``-1e30`` less a
+finite log-sum-exp is an exact 0). On a v5e, inside the two cells' steps, the
+pass takes ~207 ms at the first shape where the two loop nests took ~257, and
+~122 ms over five layers against ~143 at the second (:func:`_flash_core_bwd`
+has the microseconds a pair); every sum keeps its order, so the float32
+gradients are the two loop nests' bit for bit (PERF.md section 6, PR 38).
 
 What a rematerialising caller keeps: beside the op's inputs the backward pass
 reads the kernel's output and log-sum-exp, which the forward rule names
@@ -332,93 +350,95 @@ def _flash_core_fwd(q, k, v, kv_mask, causal, block_q, block_k, scale, unmasked)
 
 
 def _flash_core_bwd(causal, block_q, block_k, scale, unmasked, res, g):
-    """Blockwise XLA backward from saved LSE — O(T·block) memory via lax.scan
-    over kv blocks (dq) / q blocks (dk, dv). Matmul operands stay in the
-    input dtype (bf16 on the training path) with f32 accumulation; only the
-    softmax/probability statistics are f32."""
+    """Blockwise XLA backward from the saved log-sum-exp, one pass over the
+    (key block, query block) pairs that hold work: a scan over the key blocks,
+    inside it a loop over the query blocks that see the key block (all of
+    them, or under ``causal`` those at and below the diagonal). A pair forms
+    ``s = q k^T``, ``p = exp(s - lse)``, ``dp = g v^T`` and ``ds = p (dp -
+    delta)`` once and feeds the three gradients from them: ``dv += p^T g``,
+    ``dk += ds^T q``, ``dq[query block] += ds k``: five products. Matmul
+    operands stay in the input dtype (bf16 on the training path) with f32
+    accumulation; only the softmax/probability statistics are f32. Every sum
+    runs from zeros in ascending order of the other side's blocks, as the two
+    loop nests this replaced summed (``tests/test_ops.py`` keeps them: equal
+    bit for bit in float32).
+
+    ``dk`` and ``dv`` of the key block ride in the inner loop's carry. ``dq``
+    is one float32 ``[n_qb, BH, block_q, Dp]`` buffer carried through both
+    loops, updated in place: a pair reads its block (a slice fusion of its
+    own), adds the product and writes it back in the product's fusion, ``BH x
+    block_q x Dp`` float32 through HBM each way (8.4 MB for 32 heads of 64 on
+    128 lanes at blocks of 512; 16.8 MB on 256 lanes). On a v5e at the first
+    shape a pair takes 81 us of products and 12 us of that read, 2,080 pairs
+    and ~207 ms a pass (the two loop nests: 117 us a pair and the mask tile,
+    ~257 ms); ``dq.at[qi].add`` compiles to one read-add-write fusion and
+    takes the same time.
+
+    ``unmasked`` (decided by :func:`_flash_attention`: the key mask is all
+    ones) leaves the mask work out of the pair: no block of the mask is
+    sliced or selected on, so no ``[BH, block_q, block_k]`` mask tile is
+    built, and no masked-row guard runs (a masked entry is ``-1e30`` against a
+    finite log-sum-exp and ``exp`` gives an exact 0; with the guard XLA:TPU
+    packs its predicate for all heads into a tile of its own: 22 ms of 225 a
+    pass at the first shape)."""
     q, k, v, kv_mask, out, lse = res
     BH, Tq, Dp = q.shape
     Tk, Dvp = k.shape[1], v.shape[2]     # dq, dk as wide as the keys; dv as the values
-    qf, kf, vf, gf = q, k, v, g.astype(q.dtype)
+    gf = g.astype(q.dtype)
     # delta_i = sum_d out_i * g_i  (rowwise), standard flash bwd identity
     delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
-
-    q_pos = jnp.arange(Tq)
-    kv_pos = jnp.arange(Tk)
-
-    def p_block(q_blk, lse_blk, kb_idx, k_all, qi0):
-        """probs for one (q block, kv block): [BH, bq, bk]."""
-        kb = jax.lax.dynamic_slice_in_dim(k_all, kb_idx * block_k, block_k, axis=1)
-        s = jnp.einsum("bqd,bkd->bqk", q_blk, kb,
-                       preferred_element_type=jnp.float32) * scale
-        mb = jax.lax.dynamic_slice_in_dim(kv_mask, kb_idx * block_k, block_k, axis=1)
-        s = jnp.where(mb[:, None, :], s, _NEG_INF)
-        if causal:
-            qp = qi0 + q_pos[:block_q][None, :, None]
-            kp = kb_idx * block_k + kv_pos[:block_k][None, None, :]
-            s = jnp.where(kp <= qp, s, _NEG_INF)
-        p = jnp.where(s <= _NEG_INF * 0.5, 0.0, jnp.exp(s - lse_blk[:, :, None]))
-        return p, kb
-
     n_qb, n_kb = Tq // block_q, Tk // block_k
+    q_pos = jnp.arange(block_q)[None, :, None]
+    kv_pos = jnp.arange(block_k)[None, None, :]
 
-    def dq_one(_, qi):
-        qi0 = qi * block_q
-        q_blk = jax.lax.dynamic_slice_in_dim(qf, qi0, block_q, axis=1)
-        lse_blk = jax.lax.dynamic_slice_in_dim(lse, qi0, block_q, axis=1)
-        g_blk = jax.lax.dynamic_slice_in_dim(gf, qi0, block_q, axis=1)
-        d_blk = jax.lax.dynamic_slice_in_dim(delta, qi0, block_q, axis=1)
-
-        def inner(ki, dq_acc):
-            p, kb = p_block(q_blk, lse_blk, ki, kf, qi0)
-            vb = jax.lax.dynamic_slice_in_dim(vf, ki * block_k, block_k, axis=1)
-            dp = jnp.einsum("bqd,bkd->bqk", g_blk, vb,
-                            preferred_element_type=jnp.float32)
-            ds = p * (dp - d_blk[:, :, None])
-            return dq_acc + jnp.einsum("bqk,bkd->bqd", ds.astype(kb.dtype), kb,
-                                       preferred_element_type=jnp.float32) * scale
-
-        # causal: key blocks past the query block's last row hold no pair
-        last_kb = jnp.minimum(n_kb, (qi0 + block_q - 1) // block_k + 1) if causal else n_kb
-        dq_blk = jax.lax.fori_loop(0, last_kb, inner,
-                                   jnp.zeros((BH, block_q, Dp), jnp.float32))
-        return None, dq_blk
-
-    _, dq_blocks = jax.lax.scan(dq_one, None, jnp.arange(n_qb))
-    dq = jnp.reshape(dq_blocks.transpose(1, 0, 2, 3), (BH, Tq, Dp))
-
-    def dkv_one(_, ki):
+    def key_block(dq, ki):
         ki0 = ki * block_k
-        kb = jax.lax.dynamic_slice_in_dim(kf, ki0, block_k, axis=1)
-        vb = jax.lax.dynamic_slice_in_dim(vf, ki0, block_k, axis=1)
+        kb = jax.lax.dynamic_slice_in_dim(k, ki0, block_k, axis=1)
+        vb = jax.lax.dynamic_slice_in_dim(v, ki0, block_k, axis=1)
+        if not unmasked:
+            mb = jax.lax.dynamic_slice_in_dim(kv_mask, ki0, block_k, axis=1)
 
-        def inner(qi, carry):
-            dk_acc, dv_acc = carry
+        def pair(qi, carry):
+            dq, dk_acc, dv_acc = carry
             qi0 = qi * block_q
-            q_blk = jax.lax.dynamic_slice_in_dim(qf, qi0, block_q, axis=1)
+            q_blk = jax.lax.dynamic_slice_in_dim(q, qi0, block_q, axis=1)
             lse_blk = jax.lax.dynamic_slice_in_dim(lse, qi0, block_q, axis=1)
             g_blk = jax.lax.dynamic_slice_in_dim(gf, qi0, block_q, axis=1)
             d_blk = jax.lax.dynamic_slice_in_dim(delta, qi0, block_q, axis=1)
-            p, _ = p_block(q_blk, lse_blk, ki, kf, qi0)
-            dv_acc = dv_acc + jnp.einsum("bqk,bqd->bkd", p.astype(g_blk.dtype),
-                                         g_blk,
+            s = jnp.einsum("bqd,bkd->bqk", q_blk, kb,
+                           preferred_element_type=jnp.float32) * scale
+            if not unmasked:
+                s = jnp.where(mb[:, None, :], s, _NEG_INF)
+            if causal:
+                s = jnp.where(ki0 + kv_pos <= qi0 + q_pos, s, _NEG_INF)
+            p = jnp.exp(s - lse_blk[:, :, None])
+            if not unmasked:
+                # a row with no key left has lse = -1e30 too: exp(0) would count
+                # its masked entries (the forward kernel's guard)
+                p = jnp.where(s <= _NEG_INF * 0.5, 0.0, p)
+            dv_acc = dv_acc + jnp.einsum("bqk,bqd->bkd", p.astype(g_blk.dtype), g_blk,
                                          preferred_element_type=jnp.float32)
             dp = jnp.einsum("bqd,bkd->bqk", g_blk, vb,
                             preferred_element_type=jnp.float32)
-            ds = p * (dp - d_blk[:, :, None])
-            dk_acc = dk_acc + jnp.einsum("bqk,bqd->bkd", ds.astype(q_blk.dtype),
-                                         q_blk,
+            ds = (p * (dp - d_blk[:, :, None])).astype(q.dtype)
+            dk_acc = dk_acc + jnp.einsum("bqk,bqd->bkd", ds, q_blk,
                                          preferred_element_type=jnp.float32) * scale
-            return dk_acc, dv_acc
+            dq_blk = jax.lax.dynamic_index_in_dim(dq, qi, keepdims=False)
+            dq = jax.lax.dynamic_update_index_in_dim(
+                dq, dq_blk + jnp.einsum("bqk,bkd->bqd", ds, kb,
+                                        preferred_element_type=jnp.float32) * scale, qi, 0)
+            return dq, dk_acc, dv_acc
 
         # causal: query blocks that end before the key block's first row hold no pair
-        dk_blk, dv_blk = jax.lax.fori_loop(
-            ki0 // block_q if causal else 0, n_qb, inner,
-            (jnp.zeros((BH, block_k, Dp), jnp.float32),
+        dq, dk_blk, dv_blk = jax.lax.fori_loop(
+            ki0 // block_q if causal else 0, n_qb, pair,
+            (dq, jnp.zeros((BH, block_k, Dp), jnp.float32),
              jnp.zeros((BH, block_k, Dvp), jnp.float32)))
-        return None, (dk_blk, dv_blk)
+        return dq, (dk_blk, dv_blk)
 
-    _, (dk_blocks, dv_blocks) = jax.lax.scan(dkv_one, None, jnp.arange(n_kb))
+    dq_blocks, (dk_blocks, dv_blocks) = jax.lax.scan(
+        key_block, jnp.zeros((n_qb, BH, block_q, Dp), jnp.float32), jnp.arange(n_kb))
+    dq = jnp.reshape(dq_blocks.transpose(1, 0, 2, 3), (BH, Tq, Dp))
     dk = jnp.reshape(dk_blocks.transpose(1, 0, 2, 3), (BH, Tk, Dp))
     dv = jnp.reshape(dv_blocks.transpose(1, 0, 2, 3), (BH, Tk, Dvp))
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), None

@@ -43,6 +43,25 @@ def test_lowering_tests_cover_every_pallas_call():
         "cross-lowering tests below and to chip_smoke.py Leg B")
 
 
+def _cell_flash_grad(q, k, v, *mask):
+    """dq, dk, dv of a flash cell's call: causal, blocks of 512."""
+    from synapseml_tpu.ops import flash_attention
+
+    return jax.grad(lambda q_, k_, v_: jnp.sum(flash_attention(
+        q_, k_, v_, *mask, causal=True, block_q=512, block_k=512).astype(jnp.float32)),
+        argnums=(0, 1, 2))(q, k, v)
+
+
+def _assert_one_pass_backward(text: str, dq_blocks: str, masked: bool):
+    """The kernel is opaque in the module, so every ``dot_general`` is the
+    backward's: five a tile pair in one loop nest, ``dq`` accumulated in a
+    float32 buffer of query blocks, and a block of the key mask sliced only
+    where the call passed one."""
+    assert text.count("stablehlo.dot_general") == 5
+    assert dq_blocks in text
+    assert ("tensor<32x512xi1>" in text) == masked
+
+
 @pytest.mark.parametrize("B,T,H,D,causal", [
     (8, 512, 12, 64, False),    # chip_smoke Leg B's shape
     (2, 100, 4, 64, True),      # ragged T, causal
@@ -70,21 +89,14 @@ def test_flash_attention_lowers_for_tpu_at_the_long_context_cells_shape(compiled
     to the 128 lanes), causal, blocks of 512, forward and gradient. The cell
     passes no mask and builds the unmasked kernel; a padding mask at the same
     shape builds the masked one."""
-    from synapseml_tpu.ops import flash_attention
-
     qkv = jax.ShapeDtypeStruct((1, 32768, 32, 64), jnp.bfloat16)
     mask = [jax.ShapeDtypeStruct((1, 32768), jnp.bool_)] if variant == "masked" else []
-
-    def grad(q, k, v, *m):
-        return jax.grad(lambda q_, k_, v_: jnp.sum(flash_attention(
-            q_, k_, v_, *m, causal=True, block_q=512, block_k=512).astype(jnp.float32)),
-            argnums=(0, 1, 2))(q, k, v)
-
     series = 'synapseml_flash_kernel_builds_total{variant="%s"}' % variant
     before = obs.get_registry().snapshot().get(series, 0.0)
-    text = _tpu_module(grad, qkv, qkv, qkv, *mask)
+    text = _tpu_module(_cell_flash_grad, qkv, qkv, qkv, *mask)
     assert "tpu_custom_call" in text and "32768x128" in text
     assert obs.get_registry().snapshot()[series] == before + 1
+    _assert_one_pass_backward(text, "64x32x512x128xf32", masked=variant == "masked")
 
 
 @pytest.mark.parametrize("variant", ["unmasked", "masked"])
@@ -93,19 +105,54 @@ def test_flash_attention_lowers_for_tpu_at_the_latent_cells_widths(compiled_pall
     queries and keys 192 wide (256 lanes) beside values 128 wide (128 lanes),
     causal, blocks of 512, forward and gradient: the kernel's output is as wide
     as the values."""
-    from synapseml_tpu.ops import flash_attention
-
     qk = jax.ShapeDtypeStruct((2, 8192, 16, 192), jnp.bfloat16)
     v = jax.ShapeDtypeStruct((2, 8192, 16, 128), jnp.bfloat16)
     mask = [jax.ShapeDtypeStruct((2, 8192), jnp.bool_)] if variant == "masked" else []
-
-    def grad(q, k, v, *m):
-        return jax.grad(lambda q_, k_, v_: jnp.sum(flash_attention(
-            q_, k_, v_, *m, causal=True, block_q=512, block_k=512).astype(jnp.float32)),
-            argnums=(0, 1, 2))(q, k, v)
-
-    text = _tpu_module(grad, qk, qk, v, *mask)
+    text = _tpu_module(_cell_flash_grad, qk, qk, v, *mask)
     assert "tpu_custom_call" in text and "8192x256" in text and "8192x128" in text
+    _assert_one_pass_backward(text, "16x32x512x256xf32", masked=variant == "masked")
+
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    """One chip of a v5e that is described, not attached: the TPU's own
+    compiler runs here (Mosaic for the kernel, XLA:TPU for the backward's
+    loops) and says what the program asks of the chip's memory."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("qk_shape,v_shape,temp_gb", [
+    pytest.param((1, 32768, 32, 64), (1, 32768, 32, 64), 2.3, id="lfm2_24b_a2b_ep8.lm_32k"),
+    pytest.param((2, 8192, 16, 192), (2, 8192, 16, 128), 0.75,
+                 id="moonlight_16b_a3b_ep8.lm_8k_latent"),
+])
+def test_flash_gradient_compiles_for_a_v5e_at_the_cells_shapes(
+        compiled_pallas, one_v5e, qk_shape, v_shape, temp_gb):
+    """The op's gradient as the two flash cells run it (no mask, causal, blocks
+    of 512) through the chip's compiler. Its temporaries hold the backward's
+    float32 ``dq`` buffer (537 MB and 268 MB) beside ``dk`` and ``dv``: 2.15
+    and 0.67 GB as this was written."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    qk = jax.ShapeDtypeStruct(qk_shape, jnp.bfloat16, sharding=one_v5e)
+    v = jax.ShapeDtypeStruct(v_shape, jnp.bfloat16, sharding=one_v5e)
+    # an executable for a described chip cannot be read back from the cache
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(_cell_flash_grad).lower(qk, qk, v).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
 
 
 @pytest.mark.parametrize("N,WB", [

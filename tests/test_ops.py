@@ -1,13 +1,15 @@
 """ops module: flash attention (Pallas, interpret on CPU) + ring attention
 (shard_map over the 8-device seq mesh) vs the XLA reference oracle."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from synapseml_tpu.core import observability as obs
-from synapseml_tpu.ops import flash_attention, reference_attention, ring_attention_sharded
+from synapseml_tpu.ops import attention, flash_attention, reference_attention, ring_attention_sharded
 from synapseml_tpu.ops.attention import _work_steps
 from synapseml_tpu.parallel import MeshConfig, create_mesh
 
@@ -218,6 +220,197 @@ def test_flash_kernel_builds_count_one_a_trace():
     assert _built_since(before) == {"unmasked": 1, "masked": 1}
     jax.grad(lambda q: jnp.sum(fn(q[:, :64], k[:, :64], v[:, :64])))(q)   # T=64: one block of 64
     assert _built_since(before) == {"unmasked": 1, "masked": 2}
+
+
+def _two_loop_flash_bwd(causal, block_q, block_k, scale, unmasked, res, g):
+    """The backward pass as it was until PR 38, kept as the plain reference of
+    the single pass: one loop nest over the pairs for dq, a second one over
+    the same pairs for dk and dv, each forming its own ``s``, ``p``, ``dp`` and
+    ``ds`` (seven products a pair), the key mask selected on in every call."""
+    q, k, v, kv_mask, out, lse = res
+    BH, Tq, Dp = q.shape
+    Tk, Dvp = k.shape[1], v.shape[2]
+    gf = g.astype(q.dtype)
+    delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
+    q_pos, kv_pos = jnp.arange(Tq), jnp.arange(Tk)
+    neg = attention._NEG_INF
+
+    def p_block(q_blk, lse_blk, kb_idx, qi0):
+        kb = jax.lax.dynamic_slice_in_dim(k, kb_idx * block_k, block_k, axis=1)
+        s = jnp.einsum("bqd,bkd->bqk", q_blk, kb, preferred_element_type=jnp.float32) * scale
+        mb = jax.lax.dynamic_slice_in_dim(kv_mask, kb_idx * block_k, block_k, axis=1)
+        s = jnp.where(mb[:, None, :], s, neg)
+        if causal:
+            qp = qi0 + q_pos[:block_q][None, :, None]
+            kp = kb_idx * block_k + kv_pos[:block_k][None, None, :]
+            s = jnp.where(kp <= qp, s, neg)
+        return jnp.where(s <= neg * 0.5, 0.0, jnp.exp(s - lse_blk[:, :, None])), kb
+
+    n_qb, n_kb = Tq // block_q, Tk // block_k
+
+    def q_side(qi0):
+        return tuple(jax.lax.dynamic_slice_in_dim(x, qi0, block_q, axis=1)
+                     for x in (q, lse, gf, delta))
+
+    def dq_one(_, qi):
+        qi0 = qi * block_q
+        q_blk, lse_blk, g_blk, d_blk = q_side(qi0)
+
+        def inner(ki, dq_acc):
+            p, kb = p_block(q_blk, lse_blk, ki, qi0)
+            vb = jax.lax.dynamic_slice_in_dim(v, ki * block_k, block_k, axis=1)
+            dp = jnp.einsum("bqd,bkd->bqk", g_blk, vb, preferred_element_type=jnp.float32)
+            ds = p * (dp - d_blk[:, :, None])
+            return dq_acc + jnp.einsum("bqk,bkd->bqd", ds.astype(kb.dtype), kb,
+                                       preferred_element_type=jnp.float32) * scale
+
+        last_kb = jnp.minimum(n_kb, (qi0 + block_q - 1) // block_k + 1) if causal else n_kb
+        return None, jax.lax.fori_loop(0, last_kb, inner,
+                                       jnp.zeros((BH, block_q, Dp), jnp.float32))
+
+    _, dq_blocks = jax.lax.scan(dq_one, None, jnp.arange(n_qb))
+
+    def dkv_one(_, ki):
+        ki0 = ki * block_k
+        vb = jax.lax.dynamic_slice_in_dim(v, ki0, block_k, axis=1)
+
+        def inner(qi, carry):
+            dk_acc, dv_acc = carry
+            qi0 = qi * block_q
+            q_blk, lse_blk, g_blk, d_blk = q_side(qi0)
+            p, _ = p_block(q_blk, lse_blk, ki, qi0)
+            dv_acc = dv_acc + jnp.einsum("bqk,bqd->bkd", p.astype(g_blk.dtype), g_blk,
+                                         preferred_element_type=jnp.float32)
+            dp = jnp.einsum("bqd,bkd->bqk", g_blk, vb, preferred_element_type=jnp.float32)
+            ds = p * (dp - d_blk[:, :, None])
+            dk_acc = dk_acc + jnp.einsum("bqk,bqd->bkd", ds.astype(q_blk.dtype), q_blk,
+                                         preferred_element_type=jnp.float32) * scale
+            return dk_acc, dv_acc
+
+        return None, jax.lax.fori_loop(
+            ki0 // block_q if causal else 0, n_qb, inner,
+            (jnp.zeros((BH, block_k, Dp), jnp.float32),
+             jnp.zeros((BH, block_k, Dvp), jnp.float32)))
+
+    _, (dk_blocks, dv_blocks) = jax.lax.scan(dkv_one, None, jnp.arange(n_kb))
+
+    def whole(blocks, T, dtype):
+        return jnp.reshape(blocks.transpose(1, 0, 2, 3), (BH, T, blocks.shape[-1])).astype(dtype)
+
+    return (whole(dq_blocks, Tq, q.dtype), whole(dk_blocks, Tk, k.dtype),
+            whole(dv_blocks, Tk, v.dtype), None)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _two_loop_flash_core(q, k, v, kv_mask, causal, block_q, block_k, scale, unmasked):
+    return attention._flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k,
+                                          scale, unmasked)[0]
+
+
+_two_loop_flash_core.defvjp(attention._flash_core_fwd, _two_loop_flash_bwd)
+
+
+def _grads_of_sum_of_squares(fn, q, k, v, kv_mask, causal):
+    return jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v, kv_mask=kv_mask, causal=causal) ** 2),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("D,Dv", [(64, 64), (192, 128)], ids=["64_64", "192_128"])
+@pytest.mark.parametrize("block_q,block_k", [(128, 128), (128, 256), (256, 128)])
+@pytest.mark.parametrize("keys", ["causal_no_mask", "causal_key_mask", "causal_ragged_T",
+                                  "all_pairs_Tq_256_Tk_512"])
+def test_flash_backward_in_one_pass_equals_the_two_loop_form(monkeypatch, keys, block_q,
+                                                             block_k, D, Dv):
+    """dq, dk and dv of the single pass over the tile pairs against the two
+    loop nests it replaced, bit for bit in float32 (the same products, every
+    sum in the same order), and against ``jax.grad(reference_attention)``."""
+    causal = keys != "all_pairs_Tq_256_Tk_512"
+    Tq, Tk = {"causal_ragged_T": (300, 300), "all_pairs_Tq_256_Tk_512": (256, 512)}.get(
+        keys, (512, 512))
+    rs = np.random.default_rng(Tq + block_q + D)
+    q = jnp.asarray(rs.normal(size=(2, Tq, 2, D)), jnp.float32)
+    k = jnp.asarray(rs.normal(size=(2, Tk, 2, D)), jnp.float32)
+    v = jnp.asarray(rs.normal(size=(2, Tk, 2, Dv)), jnp.float32)
+    kv_mask = jnp.asarray(rs.random((2, Tk)) > 0.2) if keys == "causal_key_mask" else None
+    flash = functools.partial(flash_attention, block_q=block_q, block_k=block_k)
+    before = _kernel_builds()
+    one_pass = _grads_of_sum_of_squares(flash, q, k, v, kv_mask, causal)
+    unmasked = keys in ("causal_no_mask", "all_pairs_Tq_256_Tk_512")
+    assert _built_since(before) == {"unmasked": int(unmasked), "masked": int(not unmasked)}
+    monkeypatch.setattr(attention, "_flash_core", _two_loop_flash_core)
+    two_loops = _grads_of_sum_of_squares(flash, q, k, v, kv_mask, causal)
+    ref = _grads_of_sum_of_squares(reference_attention, q, k, v, kv_mask, causal)
+    # XLA:CPU contracts `dot * scale - lse` into one fused multiply-add where no
+    # select stands between them (no mask, not causal) and the product is
+    # inexact (1/sqrt(192) is no power of two); the chip's compiler does not
+    contracted_on_cpu = not causal and D == 192
+    for name, a, b, r in zip(("dq", "dk", "dv"), one_pass, two_loops, ref):
+        assert a.shape == r.shape
+        if contracted_on_cpu:
+            assert np.max(np.abs(a - b)) <= 4 * np.finfo(np.float32).eps * np.max(np.abs(b)), name
+        else:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+        # test_flash_gradients_match's tolerance
+        np.testing.assert_allclose(np.asarray(r), np.asarray(a), atol=5e-5, err_msg=name)
+
+
+def _sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for x in value if isinstance(value, (tuple, list)) else (value,):
+            x = getattr(x, "jaxpr", x)
+            if hasattr(x, "eqns"):
+                yield x
+
+
+def _eqns_inside_loops(jaxpr, inside=False):
+    """Every equation under a ``scan`` or ``while`` of ``jaxpr``, at any depth;
+    the forward kernel's own body (``pallas_call``) is not entered."""
+    for eqn in jaxpr.eqns:
+        if inside:
+            yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in _sub_jaxprs(eqn):
+                yield from _eqns_inside_loops(
+                    sub, inside or eqn.primitive.name in ("scan", "while"))
+
+
+@pytest.mark.parametrize("D,Dv", [(64, 64), (192, 128)], ids=["64_64", "192_128"])
+def test_flash_backward_forms_five_products_a_pair_and_no_mask_tile_without_a_mask(
+        monkeypatch, D, Dv):
+    """What the CPU can count of the backward pass: the loops of an unmasked
+    causal call's gradient hold five ``dot_general`` (the two loop nests held
+    seven), one ``exp`` and one select a tile (the causal one) and slice no
+    boolean; a call with a key mask slices the mask's block and selects on a
+    tile twice more (the mask, and the guard of rows with no key left)."""
+    T, block = 512, 128
+    qk = jnp.zeros((2, T, 2, D), jnp.float32)
+    v = jnp.zeros((2, T, 2, Dv), jnp.float32)
+
+    flash = functools.partial(flash_attention, block_q=block, block_k=block)
+
+    def loop_eqns(core, kv_mask):
+        with monkeypatch.context() as m:
+            m.setattr(attention, "_flash_core", core)
+            jaxpr = jax.make_jaxpr(lambda q, k, v: _grads_of_sum_of_squares(
+                flash, q, k, v, kv_mask, True))(qk, qk, v)
+        return list(_eqns_inside_loops(jaxpr.jaxpr))
+
+    def count(eqns, primitive):
+        return sum(e.primitive.name == primitive for e in eqns)
+
+    def mask_blocks(eqns):
+        return [e for e in eqns if e.primitive.name == "dynamic_slice"
+                and e.invars[0].aval.dtype == jnp.bool_]
+
+    unmasked = loop_eqns(attention._flash_core, None)
+    masked = loop_eqns(attention._flash_core, jnp.ones((2, T), bool))
+    assert count(unmasked, "dot_general") == count(masked, "dot_general") == 5
+    assert count(loop_eqns(_two_loop_flash_core, None), "dot_general") == 7
+    assert mask_blocks(unmasked) == []
+    assert [e.outvars[0].aval.shape for e in mask_blocks(masked)] == [(4, block)]
+    for eqns, selects in ((unmasked, 1), (masked, 3)):
+        tiles = [e.primitive.name for e in eqns if e.outvars[0].aval.shape == (4, block, block)]
+        assert tiles.count("select_n") == selects and tiles.count("exp") == 1
 
 
 @pytest.mark.parametrize("causal", [False, True])
