@@ -17,6 +17,7 @@ import numpy as np
 
 from ..core import DataFrame, Estimator, Model
 from ..core import batching as cb
+from ..core import observability as obs
 from ..core.params import ComplexParam, Param, TypeConverters
 from ..parallel.mesh import MeshConfig, MeshContext, create_mesh
 from .flax_nets.bert import BertClassifier, bert_base, bert_tiny
@@ -27,6 +28,15 @@ from .trainer import (Trainer, TrainerConfig, TrainState,
 __all__ = ["DeepTextClassifier", "DeepTextModel"]
 
 _ARCHS = {"bert-base": bert_base, "bert-tiny": bert_tiny}
+
+_SCORE_METRICS = obs.HandleCache(lambda reg: {
+    "batches": reg.counter(
+        "synapseml_text_score_batches_total",
+        "DeepTextModel.transform batches by how their host preparation ran: "
+        "overlapped (an earlier batch of the partition was on the device) "
+        "or exposed (nothing in flight: a partition's first batch)",
+        ("prep",)),
+})
 
 
 def _resolve_arch(name: str):
@@ -299,6 +309,15 @@ class DeepTextModel(Model, _TextParams):
         dp = self._mesh.data_parallel_size() if self._mesh is not None else 1
         bucketer = cb.default_bucketer()
 
+        def dispatch(texts, bucket):
+            # each batch pads to its own longest row (the tokenizer's rule),
+            # so batch k+1 is tokenized without waiting for the partition
+            enc = self._tok(texts, max_len=self.get("max_token_len"))
+            ids = np.asarray(enc["input_ids"])
+            mask = np.asarray(enc["attention_mask"])
+            return run_for(bucket, ids.shape[1])(
+                cb.pad_rows(ids, bucket), cb.pad_rows(mask, bucket))
+
         def per_part(part):
             texts = list(part[self.get("text_col")])
             if not texts:
@@ -307,14 +326,17 @@ class DeepTextModel(Model, _TextParams):
                 out[self.get("scores_col")] = np.zeros((0, self.get("num_classes")), np.float32)
                 out[self.get("prediction_col")] = np.zeros(0, np.int32)
                 return out
-            enc = self._tok(texts, max_len=self.get("max_token_len"))
-            ids = np.asarray(enc["input_ids"])
-            mask = np.asarray(enc["attention_mask"])
-            probs_chunks = []
+            batches = _SCORE_METRICS.get()["batches"]
+            probs_chunks, in_flight = [], None
             for s, e, bucket in bucketer.slices(len(texts), bs, multiple_of=dp):
-                p = run_for(bucket, ids.shape[1])(
-                    cb.pad_rows(ids[s:e], bucket), cb.pad_rows(mask[s:e], bucket))
-                probs_chunks.append(cb.unpad_rows(p, e - s))
+                # one batch of lookahead: batch k+1 is prepared and queued
+                # behind batch k before batch k is fetched
+                p = dispatch(texts[s:e], bucket)
+                batches.inc(prep="exposed" if in_flight is None else "overlapped")
+                if in_flight is not None:
+                    probs_chunks.append(cb.unpad_rows(*in_flight))
+                in_flight = (p, e - s)
+            probs_chunks.append(cb.unpad_rows(*in_flight))
             probs = np.concatenate(probs_chunks, axis=0)
             out = dict(part)
             out[self.get("scores_col")] = probs

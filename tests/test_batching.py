@@ -423,6 +423,161 @@ def test_deep_text_bucketed_matches_unpadded(text_model, fresh_cache):
     _padded_vs_exact(transform, compare)
 
 
+class _CountingTokenizer:
+    """Wraps a model's tokenizer as a caller timing it would: each call's
+    texts and padded length, in order."""
+
+    def __init__(self, inner, events=None):
+        self.inner, self.calls, self.events = inner, [], events
+
+    def __call__(self, texts, **kw):
+        enc = self.inner(texts, **kw)
+        self.calls.append((list(texts), enc["input_ids"].shape[1]))
+        if self.events is not None:
+            self.events.append(("tok", len(self.calls) - 1))
+        return enc
+
+
+def _streamed_texts():
+    """19 rows in batches of 8: batches whose longest rows pad to 8, 16 and
+    24 tokens, the last a partial slice of 3 rows."""
+    words = lambda i, n: " ".join(f"w{i}x{j}" for j in range(n))
+    return ([words(i, 3 + i % 4) for i in range(8)]
+            + [words(i, 10 + i % 3) for i in range(8, 16)]
+            + [words(i, 18 + i % 2) for i in range(16, 19)])
+
+
+def _scores(out):
+    return (np.asarray(list(out.collect_column("scores"))),
+            np.asarray(out.collect_column("prediction")))
+
+
+def test_deep_text_streamed_batches_match_one_slice_partitions(text_model,
+                                                              fresh_cache):
+    """A partition of several batches pads each batch to its own longest row
+    and scores it as that batch alone, as its own partition, would score."""
+    model = text_model.copy({"max_token_len": 32, "batch_size": 8})
+    model._get_apply()
+    model._tok = tok = _CountingTokenizer(model._tok)
+    texts = _streamed_texts()
+    got = _scores(model.transform(
+        DataFrame.from_dict({"text": np.asarray(texts, dtype=object)})))
+    bounds = [(0, 8), (8, 16), (16, 19)]
+    want = [(texts[s:e], L) for (s, e), L in zip(bounds, (8, 16, 24))]
+    assert tok.calls == want
+    for s, e in bounds:
+        alone = _scores(model.transform(DataFrame.from_dict(
+            {"text": np.asarray(texts[s:e], dtype=object)})))
+        np.testing.assert_array_equal(got[0][s:e], alone[0])
+        np.testing.assert_array_equal(got[1][s:e], alone[1])
+    assert tok.calls[3:] == want     # each alone: one call at the same length
+
+
+class _StubResult:
+    """A device result that records when the host turns it into numpy."""
+
+    def __init__(self, k, rows, events):
+        self.k, self.rows, self.events = k, rows, events
+
+    def __array__(self, dtype=None, copy=None):
+        self.events.append(("fetch", self.k))
+        return np.full((self.rows, 2), 0.5, np.float32)
+
+
+def _stub_model(text_model, events, shapes):
+    model = text_model.copy({"max_token_len": 32, "batch_size": 8})
+    model._get_apply()
+    model._tok = _CountingTokenizer(model._tok, events)
+
+    def run_for(bucket, seq_len):
+        def run(ids, mask):
+            assert ids.shape == mask.shape == (bucket, seq_len)
+            k = len(shapes)
+            shapes.append((bucket, seq_len))
+            events.append(("dispatch", k))
+            return _StubResult(k, bucket, events)
+        return run
+
+    model._apply_fn = run_for
+    return model
+
+
+def _score_batches():
+    from synapseml_tpu.models.text import _SCORE_METRICS
+
+    c = _SCORE_METRICS.get()["batches"]
+    return {p: c.labels(prep=p).value for p in ("overlapped", "exposed")}
+
+
+def test_deep_text_stream_order_and_counter(text_model, fresh_cache):
+    """Batch k+1 is tokenized and dispatched before batch k is fetched; the
+    counter reads one exposed batch a partition and the rest overlapped."""
+    events, shapes = [], []
+    model = _stub_model(text_model, events, shapes)
+    texts = _streamed_texts()
+    before = _score_batches()
+    model.transform(DataFrame.from_dict(
+        {"text": np.asarray(texts + texts[:8], dtype=object)}, num_partitions=2))
+    after = _score_batches()
+    # partitions of 14 and 13 rows: two batches each
+    want = []
+    for first in (0, 2):
+        want += [("tok", first), ("dispatch", first),
+                 ("tok", first + 1), ("dispatch", first + 1),
+                 ("fetch", first), ("fetch", first + 1)]
+    assert events == want
+    assert after["exposed"] - before["exposed"] == 2
+    assert after["overlapped"] - before["overlapped"] == 2
+
+    events.clear(), shapes.clear(), model._tok.calls.clear()
+    before = _score_batches()
+    model.transform(DataFrame.from_dict({"text": np.asarray(texts, dtype=object)}))
+    after = _score_batches()
+    assert events == [("tok", 0), ("dispatch", 0), ("tok", 1), ("dispatch", 1),
+                      ("fetch", 0), ("tok", 2), ("dispatch", 2), ("fetch", 1),
+                      ("fetch", 2)]
+    assert shapes == [(8, 8), (8, 16), (8, 24)]
+    assert after["exposed"] - before["exposed"] == 1
+    assert after["overlapped"] - before["overlapped"] == 2
+
+
+def test_deep_text_one_slice_partition_tokenizes_once(text_model, fresh_cache):
+    """A partition that fits one slice: one tokenizer call over all its rows
+    at the partition's padded length, one executable."""
+    events, shapes = [], []
+    model = _stub_model(text_model, events, shapes)
+    texts = _streamed_texts()[5:11]
+    before = _score_batches()
+    model.transform(DataFrame.from_dict({"text": np.asarray(texts, dtype=object)}))
+    after = _score_batches()
+    length = model._tok.inner(texts, max_len=32)["input_ids"].shape[1]
+    assert model._tok.calls == [(texts, length)] and length == 16
+    assert shapes == [(8, length)]
+    assert events == [("tok", 0), ("dispatch", 0), ("fetch", 0)]
+    assert after["exposed"] - before["exposed"] == 1
+    assert after["overlapped"] - before["overlapped"] == 0
+
+
+def test_deep_text_stream_compile_bound(text_model, fresh_cache):
+    """A streamed partition builds one executable per distinct (bucket,
+    length) of its batches, and a second transform builds none."""
+    model = text_model.copy({"max_token_len": 32, "batch_size": 8})
+    texts = _streamed_texts()
+    texts = texts + texts[:8] + texts[8:11]  # a last slice of 6 rows pads to 16 again
+    df = DataFrame.from_dict({"text": np.asarray(texts, dtype=object)})
+    model._get_apply()
+    slices = list(cb.default_bucketer().slices(len(texts), 8))
+    pairs = {(bucket, model._tok(texts[s:e], max_len=32)["input_ids"].shape[1])
+             for s, e, bucket in slices}
+    assert len(slices) == 4 and pairs == {(8, 8), (8, 16), (8, 24)}
+    misses0 = fresh_cache.miss_count("deep_text_model")
+    first = _scores(model.transform(df))
+    assert fresh_cache.miss_count("deep_text_model") - misses0 == len(pairs)
+    second = _scores(model.transform(df))
+    assert fresh_cache.miss_count("deep_text_model") - misses0 == len(pairs)
+    np.testing.assert_array_equal(first[0], second[0])
+
+
 def test_deep_vision_bucketed_matches_unpadded(fresh_cache):
     from synapseml_tpu.models import DeepVisionClassifier
 
