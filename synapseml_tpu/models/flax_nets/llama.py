@@ -12,7 +12,10 @@ layers, a dense lead, sigmoid-routed experts, embedding and head tied) and
 `latent_moe_lm` (multi-head latent attention, a dense lead, sigmoid-routed
 experts beside a shared expert, untied head). A fourth, `looped_lm`, runs one
 stack of sandwich-normed layers several times with shared weights, with an
-exit gate and the head at every loop step.
+exit gate and the head at every loop step. A fifth, `window_moe_lm`, mixes
+sliding-window and full attention layers of different head counts and
+rotary, with a sigmoid gate a head, a dense lead and softmax-routed experts
+beside a shared expert.
 """
 
 from __future__ import annotations
@@ -22,12 +25,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .transformer import (Encoder, MlpBlock, MoEBlock, TransformerConfig,
-                          _norm, apply_rope, make_causal_mask,
+from .transformer import (AttnKind, Encoder, MlpBlock, MoEBlock, TransformerConfig,
+                          Yarn, _norm, apply_rope, make_causal_mask,
                           rope_frequencies)
 
 __all__ = ["llama2_7b", "llama_tiny", "sparse_moe_lm", "hybrid_conv_moe_lm",
-           "latent_moe_lm", "looped_lm", "next_token_labels", "LlamaLM",
+           "latent_moe_lm", "looped_lm", "window_moe_lm", "next_token_labels", "LlamaLM",
            "generate", "greedy_generate",
            "PagedLlamaLM", "paged_prefill", "paged_decode_step",
            "paged_extend", "paged_verify", "early_exit_params"]
@@ -146,6 +149,45 @@ def looped_lm(**kw) -> TransformerConfig:
                     use_rope=True, rope_theta=1e6, attn_bias=False,
                     attn_impl="flash", flash_block=512, loop_steps=4)
     defaults.update(kw)
+    return TransformerConfig(**defaults)
+
+
+def window_moe_lm(**kw) -> TransformerConfig:
+    """A decoder of sliding-window and full attention layers, at the published
+    sizes of Laguna-XS.2 (config.json of poolside/Laguna-XS.2, `laguna`): 40
+    layers, full every fourth from layer 0 and sliding (a window of 512 keys)
+    between; 8 key/value heads of 128 throughout, 48 query heads on the full
+    layers and 64 on the sliding ones, each head's output scaled by a sigmoid
+    gate of the layer's input; the full layers turn the leading 64 of 128 dims
+    at theta 500000 under YaRN (factor 64 over 4096 positions, beta 64 / 1,
+    cos and sin x 1.4158883), the sliding ones all 128 at theta 10000; a
+    dense gated MLP of width 8192 in layer 0, then 256 gated experts of width
+    512, 8 a token by softmax, the top 8 renormalised and scaled by 2.5,
+    beside an ungated shared expert of width 512; RMSNorm 1e-6; no biases;
+    untied head over 100352 rows. One chip's share is ``moe_experts`` under
+    ``moe_total_experts`` from ``moe_first_expert``, a smaller ``vocab_size``
+    and fewer layers (``n_layers`` and ``layer_types`` together)."""
+    defaults = dict(vocab_size=100352, hidden=2048, n_layers=40, n_heads=48,
+                    n_kv_heads=8, head_dim=128, mlp_dim=8192, moe_mlp_dim=512,
+                    moe_shared_mlp_dim=512, max_len=262144, norm="rmsnorm",
+                    norm_eps=1e-6, act="silu", gated_mlp=True, mlp_bias=False,
+                    causal=True, use_rope=True, rope_theta=500000.0, attn_bias=False,
+                    attn_impl="flash", flash_block=512, attn_gate=True,
+                    attn_kinds=(("full_attention", AttnKind(
+                                    rotary_fraction=0.5,
+                                    yarn=Yarn(factor=64.0, original_max_len=4096,
+                                              beta_fast=64.0, beta_slow=1.0,
+                                              attention_factor=1.4158883083359672))),
+                                ("sliding_attention", AttnKind(
+                                    heads=64, window=512, rope_theta=10000.0))),
+                    moe_dense_layers=1, moe_experts=256, moe_total_experts=256,
+                    moe_top_k=8, moe_dispatch="grouped", moe_bias=False,
+                    moe_router="softmax", moe_gate_scale=2.5)
+    defaults.update(kw)
+    if "layer_types" not in defaults:   # the published pattern, as far as n_layers goes
+        defaults["layer_types"] = tuple(
+            "full_attention" if i % 4 == 0 else "sliding_attention"
+            for i in range(defaults["n_layers"]))
     return TransformerConfig(**defaults)
 
 

@@ -13,6 +13,9 @@ modules built for GSPMD):
   * a stack's layers may differ (``layer_types``, ``moe_dense_layers``): a
     gated short convolution (``ShortConv``, ``ops.short_conv``) or attention
     as the token mixer, a dense MLP or routed experts as the feed-forward;
+    attention layers of two kinds, full and sliding-window, may differ in
+    their heads and rotary too (``attn_kinds``, ``AttnKind``), and a sigmoid
+    gate a head may scale each head's output (``attn_gate``);
   * attention's keys and values may come from one low-rank latent a position
     with a rotary key that all heads share (``LatentAttention``,
     ``kv_latent_rank > 0``), and the routed experts may stand beside a shared
@@ -43,11 +46,44 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["TransformerConfig", "Attention", "LatentAttention", "MlpBlock", "ShortConv",
-           "Block", "Encoder",
+__all__ = ["TransformerConfig", "AttnKind", "Yarn", "Attention", "LatentAttention",
+           "MlpBlock", "ShortConv", "Block", "Encoder",
            "RMSNorm", "apply_rope", "make_causal_mask"]
 
 Dtype = Any
+
+LAYER_KINDS = ("conv", "full_attention", "sliding_attention")
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN's scaling of rotary frequencies (arXiv:2309.00071, as
+    ``transformers``' ``_compute_yarn_parameters`` has it): dims that turn
+    fewer than ``beta_slow`` times over ``original_max_len`` positions are
+    interpolated (frequency / ``factor``), those that turn more than
+    ``beta_fast`` times keep theirs, a linear ramp between; cos and sin are
+    multiplied by ``attention_factor``."""
+    factor: float
+    original_max_len: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float
+
+
+@dataclasses.dataclass(frozen=True)
+class AttnKind:
+    """What the attention layers of one kind (``layer_types``) take beside the
+    stack's own settings: their query heads (0 -> ``n_heads``; the key/value
+    heads are the stack's), the keys a query sees (``window`` > 0: itself and
+    the ``window - 1`` before it; 0: every causal key), the rotary base (0 ->
+    ``rope_theta``), the share of a head's dims that turn (the leading ones,
+    half-split among themselves; the rest pass unturned) and YaRN's scaling
+    of their frequencies (None: none)."""
+    heads: int = 0
+    window: int = 0
+    rope_theta: float = 0.0
+    rotary_fraction: float = 1.0
+    yarn: Yarn | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,7 +120,7 @@ class TransformerConfig:
     # blockwise kernel, ops.flash_attention), 'ring' / 'ulysses'
     # (sequence-parallel over `seq_axis` — an error without a mesh in scope
     # whose seq axis size > 1). Which is fastest on the chip is not
-    # measured (ROADMAP A3); 'flash' avoids the O(T^2) score buffer.
+    # measured (ROADMAP A6, C3); 'flash' avoids the O(T^2) score buffer.
     attn_impl: str = "einsum"
     seq_axis: str = "seq"
     # mixture-of-experts MLP (switch-transformer routing): 0 = dense MLP.
@@ -123,7 +159,8 @@ class TransformerConfig:
     # given, no gradient, held by the Trainer's state), the gates
     # `moe_gate_scale` x the unbiased scores over (their sum + `moe_gate_eps`):
     # normalised, then scaled; it sows no load-balance term. The softmax form
-    # reads neither of the two (its 1e-9 and its scale of 1 are its own).
+    # reads `moe_gate_scale` too (its top-k renormalised, then scaled), and
+    # not `moe_gate_eps` (its 1e-9 is its own).
     moe_router: str = "softmax"
     moe_gate_scale: float = 1.0
     moe_gate_eps: float = 1e-6
@@ -133,11 +170,19 @@ class TransformerConfig:
     # deployment. 0 = none. 'grouped' only.
     moe_shared_mlp_dim: int = 0
     # per-layer kinds. `layer_types`: the token mixer of each layer, 'conv'
-    # (gated short convolution of 3 taps, ops.short_conv) or
-    # 'full_attention'; () = attention everywhere. `moe_dense_layers`: that
+    # (gated short convolution of 3 taps, ops.short_conv), 'full_attention'
+    # or 'sliding_attention'; () = full attention everywhere. `attn_kinds`:
+    # (kind, AttnKind) pairs, what an attention kind's layers take beside
+    # the stack's settings; a kind not named takes
+    # AttnKind()'s, and 'sliding_attention' has to be named with a window.
+    # `attn_gate`: every attention layer scales head h's output by
+    # sigmoid(x . w_h), x the layer's (normed) input, before the output
+    # projection. `moe_dense_layers`: that
     # many leading layers keep the dense MLP of width `mlp_dim` when
     # `moe_experts > 0`; the experts' width is `moe_mlp_dim` (0 -> mlp_dim).
     layer_types: tuple = ()
+    attn_kinds: tuple = ()
+    attn_gate: bool = False
     moe_dense_layers: int = 0
     moe_mlp_dim: int = 0
     mlp_bias: bool = True  # biases on the dense MLP's projections
@@ -176,9 +221,16 @@ class TransformerConfig:
         if self.layer_types and len(self.layer_types) != self.n_layers:
             raise ValueError(f"layer_types names {len(self.layer_types)} layers, "
                              f"n_layers is {self.n_layers}")
-        unknown = set(self.layer_types) - {"conv", "full_attention"}
+        unknown = set(self.layer_types) - set(LAYER_KINDS)
         if unknown:
-            raise ValueError(f"layer_types holds 'conv' or 'full_attention', got {unknown}")
+            raise ValueError(f"layer_types holds {LAYER_KINDS}, got {unknown}")
+        unknown = {k for k, _ in self.attn_kinds} - set(LAYER_KINDS[1:])
+        if unknown:
+            raise ValueError(f"attn_kinds names attention kinds {LAYER_KINDS[1:]}, got {unknown}")
+        if "sliding_attention" in self.layer_types and not (
+                self.causal and self.attn_kind("sliding_attention").window > 0):
+            raise ValueError("a 'sliding_attention' layer needs causal=True and a window "
+                             "(attn_kinds)")
         if self.norm_position not in ("pre", "post", "sandwich"):
             raise ValueError(f"norm_position is 'pre', 'post' or 'sandwich', got "
                              f"{self.norm_position!r}")
@@ -190,6 +242,10 @@ class TransformerConfig:
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    def attn_kind(self, kind: str) -> AttnKind:
+        """What the attention layers of ``kind`` take (``attn_kinds``)."""
+        return dict(self.attn_kinds).get(kind, AttnKind())
 
     @property
     def expert_mlp_dim(self) -> int:
@@ -230,11 +286,26 @@ def _norm(cfg: TransformerConfig, **kw):
                         **kw)
 
 
-def rope_frequencies(head_dim: int, max_len: int, theta: float) -> tuple[np.ndarray, np.ndarray]:
+def rope_frequencies(head_dim: int, max_len: int, theta: float,
+                     yarn: Yarn | None = None) -> tuple[np.ndarray, np.ndarray]:
+    """cos, sin [max_len, head_dim / 2] of the rotary angles; with ``yarn``
+    its frequencies and its factor on both (``Yarn``)."""
     inv = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim))
+    scale = 1.0
+    if yarn is not None:
+        def turns_dim(turns):       # the dim whose angle turns `turns` times
+            return head_dim * np.log(yarn.original_max_len / (turns * 2 * np.pi)) \
+                / (2 * np.log(theta))
+
+        low = max(np.floor(turns_dim(yarn.beta_fast)), 0)
+        high = min(np.ceil(turns_dim(yarn.beta_slow)), head_dim - 1)
+        ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                       / max(high - low, 0.001), 0, 1)
+        inv = (inv / yarn.factor * ramp + inv * (1 - ramp)).astype(np.float32)
+        scale = yarn.attention_factor
     t = np.arange(max_len, dtype=np.float32)
     freqs = np.outer(t, inv)
-    return np.cos(freqs), np.sin(freqs)
+    return (np.cos(freqs) * scale).astype(np.float32), (np.sin(freqs) * scale).astype(np.float32)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array, positions: jax.Array) -> jax.Array:
@@ -245,10 +316,15 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array, positions: jax.Arra
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
 
 
-def make_causal_mask(q_len: int, kv_len: int, offset: int = 0) -> jax.Array:
+def make_causal_mask(q_len: int, kv_len: int, offset: int = 0, window: int = 0) -> jax.Array:
+    """[1, 1, Q, KV]: keys at or before the query; with ``window`` > 0 the
+    ``window`` last of them alone."""
     q_pos = jnp.arange(q_len)[:, None] + offset
     kv_pos = jnp.arange(kv_len)[None, :]
-    return (kv_pos <= q_pos)[None, None, :, :]  # [1,1,Q,KV]
+    mask = kv_pos <= q_pos
+    if window:
+        mask = mask & (kv_pos > q_pos - window)
+    return mask[None, None, :, :]
 
 
 class Attention(nn.Module):
@@ -259,12 +335,17 @@ class Attention(nn.Module):
     'einsum' (XLA), 'flash' (Pallas blockwise kernel), 'ring' (K/V rotation
     over ``cfg.seq_axis``), or 'ulysses' (all-to-all head/token swap over
     ``cfg.seq_axis``) — the long-context paths the reference lacks,
-    SURVEY.md §5."""
+    SURVEY.md §5. ``kind`` names the layer's attention kind
+    (``cfg.attn_kind``): its heads, window and rotary; a window runs the
+    flash op's band (scope ``attn.window``) or the einsum core under the
+    band's mask, and no other core. With ``cfg.attn_gate`` each head's output
+    is scaled by a sigmoid gate of the layer's input before ``o``."""
 
     cfg: TransformerConfig
     decode: bool = False
+    kind: str = "full_attention"
 
-    def _attend(self, q, k, v, mask):
+    def _attend(self, q, k, v, mask, window: int = 0):
         cfg = self.cfg
         D = cfg.head_dim
         # flash/ring support padding (kv-position) masks; arbitrary [.., Q, K]
@@ -282,6 +363,8 @@ class Attention(nn.Module):
         # for fused kernels); residual/MLP dropout is unaffected. Falling back
         # to einsum here would silently reintroduce the O(T^2) score matrix.
         eligible = not self.decode and (mask is None or mask_is_kv_shaped)
+        if window and impl in ("ring", "ulysses"):
+            raise ValueError(f"attn_impl={impl!r} has no sliding window: 'flash' or 'einsum'")
 
         if impl in ("ring", "ulysses") and eligible:
             from ...parallel.mesh import current_mesh
@@ -311,12 +394,13 @@ class Attention(nn.Module):
         if impl == "flash" and eligible:
             from ...ops import flash_attention
 
-            # the op names its scope, `attn.flash`
+            # the op names its scope, `attn.flash` (`attn.window` under a window)
             return flash_attention(q, k, v, kv_mask=kv_mask, causal=cfg.causal,
-                                   block_q=cfg.flash_block, block_k=cfg.flash_block)
+                                   block_q=cfg.flash_block, block_k=cfg.flash_block,
+                                   window=window)
 
         if cfg.causal and not self.decode:
-            causal = make_causal_mask(q.shape[1], k.shape[1])
+            causal = make_causal_mask(q.shape[1], k.shape[1], window=window)
             mask = causal if mask is None else jnp.logical_and(mask, causal)
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(D).astype(cfg.dtype)
         if mask is not None:
@@ -366,7 +450,8 @@ class Attention(nn.Module):
     def __call__(self, x, mask=None, positions=None):
         cfg = self.cfg
         B, T, _ = x.shape
-        H, KV, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+        kind = cfg.attn_kind(self.kind)
+        H, KV, D = kind.heads or cfg.n_heads, cfg.kv_heads, cfg.head_dim
         dense = lambda name, heads: nn.DenseGeneral(  # noqa: E731
             features=(heads, D), axis=-1, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             use_bias=cfg.attn_bias,
@@ -384,10 +469,18 @@ class Attention(nn.Module):
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
         if cfg.use_rope:
-            cos_np, sin_np = rope_frequencies(D, cfg.max_len, cfg.rope_theta)
+            rot = int(D * kind.rotary_fraction)
+            cos_np, sin_np = rope_frequencies(rot, cfg.max_len, kind.rope_theta or cfg.rope_theta,
+                                              kind.yarn)
             cos, sin = jnp.asarray(cos_np), jnp.asarray(sin_np)
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
+            if rot == D:
+                q = apply_rope(q, cos, sin, positions)
+                k = apply_rope(k, cos, sin, positions)
+            else:       # the leading `rot` dims turn, the rest pass
+                q = jnp.concatenate([apply_rope(q[..., :rot], cos, sin, positions),
+                                     q[..., rot:]], axis=-1)
+                k = jnp.concatenate([apply_rope(k[..., :rot], cos, sin, positions),
+                                     k[..., rot:]], axis=-1)
 
         if self.decode:
             # linen cache: append at cache_index; the update is skipped on the
@@ -406,12 +499,20 @@ class Attention(nn.Module):
             causal = make_causal_mask(T, kv_len, offset=start)
             mask = causal if mask is None else jnp.logical_and(mask, causal)
         if cfg.attn_topk > 0:
+            if kind.window:
+                raise ValueError("the indexed path has no sliding window")
             out = self._attend_indexed(x, q, k, v, mask, positions)
         else:
             if KV != H:
                 k = jnp.repeat(k, H // KV, axis=2)
                 v = jnp.repeat(v, H // KV, axis=2)
-            out = self._attend(q, k, v, mask)
+            out = self._attend(q, k, v, mask, kind.window)
+        if cfg.attn_gate:
+            gate = nn.DenseGeneral(
+                features=H, axis=-1, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                use_bias=False, kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.xavier_uniform(), ("embed", "heads")), name="gate")(x)
+            out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)[..., None]
         return nn.DenseGeneral(
             features=cfg.hidden, axis=(-2, -1), dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             use_bias=cfg.attn_bias,
@@ -569,6 +670,8 @@ class MoEBlock(nn.Module):
                     # the router gradient); Mixtral never ships k=1 configs.
                     gate_vals = gate_vals / jnp.maximum(
                         jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+                if cfg.moe_gate_scale != 1.0:   # a scale of 1 adds no operation
+                    gate_vals = gate_vals * cfg.moe_gate_scale
                 # load-balance aux loss: R * sum_e f_e * P_e over ALL the router's
                 # experts, with f_e the token fraction averaged over ALL k routing
                 # choices (the Mixtral/switch formulation — top-1-only would let
@@ -783,14 +886,14 @@ class Block(nn.Module):
     def _parts(self):
         """(token mixer, feed-forward) of this layer, by its kinds."""
         cfg = self.cfg
-        conv = bool(cfg.layer_types) and cfg.layer_types[self.layer] == "conv"
-        if conv and self.decode:
-            raise ValueError("a 'conv' layer has no decode cache: training and "
+        kind = cfg.layer_types[self.layer] if cfg.layer_types else "full_attention"
+        if kind != "full_attention" and self.decode:
+            raise ValueError(f"a {kind!r} layer has no decode cache: training and "
                              "prefill only")
         experts = cfg.moe_experts > 0 and self.layer >= cfg.moe_dense_layers
         attn_cls = LatentAttention if cfg.kv_latent_rank > 0 else Attention
-        return (ShortConv(cfg, name="conv") if conv
-                else attn_cls(cfg, decode=self.decode, name="attn"),
+        return (ShortConv(cfg, name="conv") if kind == "conv"
+                else attn_cls(cfg, decode=self.decode, kind=kind, name="attn"),
                 MoEBlock(cfg, name="mlp") if experts else MlpBlock(cfg, name="mlp"))
 
     @nn.compact

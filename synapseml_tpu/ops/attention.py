@@ -23,6 +23,20 @@ row's first block holds key 0, so the running maximum is finite before any
 ``-1e30`` entry meets it and ``exp`` gives an exact 0; without ``causal`` no
 entry is masked at all. Both compute the same numbers wherever both apply.
 
+A sliding window (``window`` > 0, causal only: query ``i`` sees keys ``i -
+window < j <= i``, itself and the ``window - 1`` before it) runs the same two
+kernels under a scope of its own, ``attn.window`` (not nested in
+``attn.flash``), counted as ``variant="window"``, and visits only the (query
+block, key block) pairs that meet the band, forward and backward; the band's
+edge tiles are masked inside the kernel. The unmasked variant's premise does
+not hold there (a row's band may miss a whole key block), so its step tables
+list each query block's key blocks from the diagonal BACKWARDS: a row's own
+key is in the first tile it meets, its running maximum is finite from the
+first step, and no masked-row guard is needed. The masked variant keeps its
+guard and its order. While tracing, a windowed call sets the gauge
+``synapseml_attn_window_pairs_ratio``: the pairs its tiles compute (tiles x
+block_q x block_k) over the band's pairs.
+
 Backward pass: a custom VJP recomputes attention blockwise in XLA from the
 saved log-sum-exp — no [T, T] materialization, no second Pallas kernel needed.
 It walks the (key block, query block) pairs that hold work once (under a
@@ -85,11 +99,12 @@ REMAT_SAVED_NAMES = (_OUT, _LSE)
 
 
 def reference_attention(q, k, v, kv_mask=None, causal: bool = False,
-                        q_offset=0, kv_offset=0):
+                        q_offset=0, kv_offset=0, window: int = 0):
     """Plain XLA attention (the correctness oracle). [B,T,H,D] layout.
 
     ``q_offset``/``kv_offset`` are global position offsets so sequence-parallel
     shards can build the right causal mask (used by ring attention).
+    ``window`` > 0 (with ``causal``) keeps the keys ``q - window < kv <= q``.
     """
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
@@ -99,6 +114,8 @@ def reference_attention(q, k, v, kv_mask=None, causal: bool = False,
         q_pos = q_offset + jnp.arange(Tq)[:, None]
         kv_pos = kv_offset + jnp.arange(Tk)[None, :]
         scores = jnp.where((kv_pos <= q_pos)[None, None], scores, _NEG_INF)
+        if window:
+            scores = jnp.where((kv_pos > q_pos - window)[None, None], scores, _NEG_INF)
     if kv_mask is not None:
         scores = jnp.where(kv_mask[:, None, None, :], scores, _NEG_INF)
     any_valid = jnp.any(scores > _NEG_INF * 0.5, axis=-1)        # [B,H,Tq]
@@ -116,11 +133,13 @@ def reference_attention(q, k, v, kv_mask=None, causal: bool = False,
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                       m_scr, l_scr, acc_scr, *,
                       block_k: int, n_kblocks: int, scale: float, causal: bool,
-                      block_q: int):
+                      block_q: int, window: int):
     """One (batch*head, q-block, kv-block) program. Only ONE block_k-sized K/V
     tile is VMEM-resident at a time (streamed by the grid's innermost
     dimension); the running max/sum/accumulator live in VMEM scratch that
-    persists across the kv dimension and is written out on the last step."""
+    persists across the kv dimension and is written out on the last step.
+    Under a ``window`` the steps before the band's first key block compute
+    nothing, as those above the diagonal."""
     from jax.experimental import pallas as pl
 
     q_blk = pl.program_id(1)
@@ -148,6 +167,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
             q_pos = q_blk * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             kv_pos = kv_blk * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(kv_pos <= q_pos, s, _NEG_INF)
+            if window:
+                s = jnp.where(kv_pos > q_pos - window, s, _NEG_INF)
         m = m_scr[:, 0]
         new_m = jnp.maximum(m, jnp.max(s, axis=1))
         alpha = jnp.exp(m - new_m)
@@ -161,7 +182,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32)
         m_scr[...] = jnp.broadcast_to(new_m[:, None], m_scr.shape)
 
-    if causal:
+    if window:
+        # skip kv blocks fully above the diagonal or before the band
+        pl.when((kv_blk <= _diagonal_kv_block(q_blk, block_q, block_k))
+                & (kv_blk >= _band_first_kv_block(q_blk, block_q, block_k, window)))(_compute)
+    elif causal:
         # skip kv blocks fully above the diagonal
         pl.when(kv_blk * block_k <= (q_blk + 1) * block_q - 1)(_compute)
     else:
@@ -182,10 +207,17 @@ def _diagonal_kv_block(q_blk, block_q: int, block_k: int):
     return ((q_blk + 1) * block_q - 1) // block_k
 
 
+def _band_first_kv_block(q_blk, block_q: int, block_k: int, window: int):
+    """The key block that holds the first key query block ``q_blk``'s first
+    row sees under a window: the first one it needs (negative where the band
+    reaches before key 0; an int, an array of them or a traced scalar)."""
+    return (q_blk * block_q - window + 1) // block_k
+
+
 def _flash_fwd_kernel_unmasked(q_blk_ref, kv_blk_ref, q_ref, k_ref, v_ref, o_ref,
                                lse_ref, m_scr, l_scr, acc_scr, *,
                                block_q: int, block_k: int, n_kblocks: int,
-                               scale: float, causal: bool):
+                               scale: float, causal: bool, window: int):
     """One (batch*head, step) program of a call without a key mask. The step's
     query and key block come from the two scalar-prefetched tables, which list
     the pairs that hold work in row order (under ``causal`` the lower triangle:
@@ -196,13 +228,19 @@ def _flash_fwd_kernel_unmasked(q_blk_ref, kv_blk_ref, q_ref, k_ref, v_ref, o_ref
     along them, where a ``[block_q, 1]`` column broadcast over the lanes cost
     40 of 113 ms a launch on a v5e (PERF.md section 6, PR 34). The causal
     select stays on every block: its passes run in the products' shadow, and
-    a second body for the blocks below the diagonal measured 1.6 ms slower."""
+    a second body for the blocks below the diagonal measured 1.6 ms slower.
+    Under a ``window`` a row starts at its diagonal key block and ends at the
+    band's first (:func:`_work_steps`), and the band's lower edge is selected
+    on beside the causal one."""
     from jax.experimental import pallas as pl
 
     step = pl.program_id(1)
     q_blk, kv_blk = q_blk_ref[step], kv_blk_ref[step]
+    first = 0
+    if window:
+        first = jnp.minimum(_diagonal_kv_block(q_blk, block_q, block_k), n_kblocks - 1)
 
-    @pl.when(kv_blk == 0)
+    @pl.when(kv_blk == first)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -214,6 +252,8 @@ def _flash_fwd_kernel_unmasked(q_blk_ref, kv_blk_ref, q_ref, k_ref, v_ref, o_ref
         q_pos = q_blk * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         kv_pos = kv_blk * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(kv_pos <= q_pos, s, _NEG_INF)
+        if window:
+            s = jnp.where(kv_pos > q_pos - window, s, _NEG_INF)
     m = m_scr[...]                                      # [block_q, 128], lanes equal
     new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m - new_m)
@@ -227,7 +267,9 @@ def _flash_fwd_kernel_unmasked(q_blk_ref, kv_blk_ref, q_ref, k_ref, v_ref, o_ref
     m_scr[...] = new_m
 
     last = n_kblocks - 1
-    if causal:
+    if window:
+        last = jnp.maximum(_band_first_kv_block(q_blk, block_q, block_k, window), 0)
+    elif causal:
         last = jnp.minimum(_diagonal_kv_block(q_blk, block_q, block_k), last)
 
     @pl.when(kv_blk == last)
@@ -243,9 +285,18 @@ _MAX_TABLE_STEPS = 1 << 16
 
 
 def _work_steps(n_qblocks: int, n_kblocks: int, block_q: int, block_k: int,
-                causal: bool) -> tuple[np.ndarray, np.ndarray]:
+                causal: bool, window: int = 0) -> tuple[np.ndarray, np.ndarray]:
     """(query block, key block) of every grid step of the unmasked variant:
-    each query block's needed key blocks in order, query blocks in order."""
+    each query block's needed key blocks in order, query blocks in order.
+    Under a ``window`` (causal): each query block's key blocks that meet the
+    band, from its diagonal block down to the band's first."""
+    if window:
+        rows = np.arange(n_qblocks)
+        hi = np.minimum(_diagonal_kv_block(rows, block_q, block_k), n_kblocks - 1)
+        counts = hi - np.maximum(_band_first_kv_block(rows, block_q, block_k, window), 0) + 1
+        back = np.arange(counts.sum()) - np.repeat(np.cumsum(counts) - counts, counts)
+        return (np.repeat(rows, counts).astype(np.int32),
+                (np.repeat(hi, counts) - back).astype(np.int32))
     counts = np.full(n_qblocks, n_kblocks)
     if causal:
         counts = np.minimum(_diagonal_kv_block(np.arange(n_qblocks), block_q, block_k) + 1,
@@ -259,15 +310,15 @@ def _ceil_to(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash_core(q, k, v, kv_mask, causal, block_q, block_k, scale, unmasked):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash_core(q, k, v, kv_mask, causal, block_q, block_k, scale, unmasked, window):
     out, _ = _flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k,
-                                  scale, unmasked)
+                                  scale, unmasked, window)
     return out
 
 
 def _flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k, scale,
-                         unmasked):
+                         unmasked, window):
     """q,k: [BH, T, Dp]; v: [BH, T, Dvp] (its own width: the accumulator and
     the output are as wide as the values, not as the keys); kv_mask: [BH, Tk]
     bool, which the ``unmasked`` variant (all True then:
@@ -282,14 +333,14 @@ def _flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k, scale,
     Tk, Dvp = k.shape[1], v.shape[2]
     n_qblocks, n_kblocks = Tq // block_q, Tk // block_k
     static = dict(block_q=block_q, block_k=block_k, n_kblocks=n_kblocks,
-                  scale=scale, causal=causal)
+                  scale=scale, causal=causal, window=window)
     scratch_shapes = [
         pltpu.VMEM((block_q, 128), jnp.float32),   # running max (lane-bcast)
         pltpu.VMEM((block_q, 128), jnp.float32),   # running sum (lane-bcast)
         pltpu.VMEM((block_q, Dvp), jnp.float32),   # output accumulator
     ]
     if unmasked:
-        steps = _work_steps(n_qblocks, n_kblocks, block_q, block_k, causal)
+        steps = _work_steps(n_qblocks, n_kblocks, block_q, block_k, causal, window)
 
         def q_block(b, t, q_blks, kv_blks):
             return (b, q_blks[t], 0)
@@ -311,6 +362,8 @@ def _flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k, scale,
         def kv_block(i, j):
             # above the diagonal the kernel skips the step: name the block
             # already resident, and the pipeline issues no copy
+            if window:      # before the band too: name its first block
+                j = jnp.maximum(j, _band_first_kv_block(i, block_q, block_k, window))
             return jnp.minimum(j, _diagonal_kv_block(i, block_q, block_k)) if causal else j
 
         kernel = functools.partial(_flash_fwd_kernel, **static)
@@ -340,16 +393,17 @@ def _flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k, scale,
     return out, lse[:, :, 0]
 
 
-def _flash_core_fwd(q, k, v, kv_mask, causal, block_q, block_k, scale, unmasked):
+def _flash_core_fwd(q, k, v, kv_mask, causal, block_q, block_k, scale, unmasked,
+                    window):
     out, lse = _flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k,
-                                    scale, unmasked)
+                                    scale, unmasked, window)
     # named here, so that the kept output is at once the residual and the
     # value the layer goes on from
     out, lse = checkpoint_name(out, _OUT), checkpoint_name(lse, _LSE)
     return out, (q, k, v, kv_mask, out, lse)
 
 
-def _flash_core_bwd(causal, block_q, block_k, scale, unmasked, res, g):
+def _flash_core_bwd(causal, block_q, block_k, scale, unmasked, window, res, g):
     """Blockwise XLA backward from the saved log-sum-exp, one pass over the
     (key block, query block) pairs that hold work: a scan over the key blocks,
     inside it a loop over the query blocks that see the key block (all of
@@ -380,7 +434,13 @@ def _flash_core_bwd(causal, block_q, block_k, scale, unmasked, res, g):
     built, and no masked-row guard runs (a masked entry is ``-1e30`` against a
     finite log-sum-exp and ``exp`` gives an exact 0; with the guard XLA:TPU
     packs its predicate for all heads into a tile of its own: 22 ms of 225 a
-    pass at the first shape)."""
+    pass at the first shape).
+
+    Under a ``window`` the inner loop also stops at the last query block that
+    sees the key block (a key ``j`` is seen by the queries ``j <= i < j +
+    window``), and the pair selects on the band's lower edge beside the
+    causal one; every row's log-sum-exp is finite (its own key), so the
+    unmasked pair still needs no guard."""
     q, k, v, kv_mask, out, lse = res
     BH, Tq, Dp = q.shape
     Tk, Dvp = k.shape[1], v.shape[2]     # dq, dk as wide as the keys; dv as the values
@@ -411,6 +471,8 @@ def _flash_core_bwd(causal, block_q, block_k, scale, unmasked, res, g):
                 s = jnp.where(mb[:, None, :], s, _NEG_INF)
             if causal:
                 s = jnp.where(ki0 + kv_pos <= qi0 + q_pos, s, _NEG_INF)
+            if window:
+                s = jnp.where(ki0 + kv_pos > qi0 + q_pos - window, s, _NEG_INF)
             p = jnp.exp(s - lse_blk[:, :, None])
             if not unmasked:
                 # a row with no key left has lse = -1e30 too: exp(0) would count
@@ -429,9 +491,13 @@ def _flash_core_bwd(causal, block_q, block_k, scale, unmasked, res, g):
                                         preferred_element_type=jnp.float32) * scale, qi, 0)
             return dq, dk_acc, dv_acc
 
-        # causal: query blocks that end before the key block's first row hold no pair
+        # causal: query blocks that end before the key block's first row hold no
+        # pair; a window: nor those that start after its last key's last query
+        stop = n_qb
+        if window:
+            stop = jnp.minimum((ki0 + block_k + window - 2) // block_q + 1, n_qb)
         dq, dk_blk, dv_blk = jax.lax.fori_loop(
-            ki0 // block_q if causal else 0, n_qb, pair,
+            ki0 // block_q if causal else 0, stop, pair,
             (dq, jnp.zeros((BH, block_k, Dp), jnp.float32),
              jnp.zeros((BH, block_k, Dvp), jnp.float32)))
         return dq, (dk_blk, dv_blk)
@@ -448,7 +514,7 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
 def flash_attention(q, k, v, kv_mask=None, causal: bool = False,
-                    block_q: int = 128, block_k: int = 128):
+                    block_q: int = 128, block_k: int = 128, window: int = 0):
     """Fused blockwise attention. [B, T, H, D] layout, differentiable.
 
     ``v`` may have a width of its own (``v.shape[-1] != q.shape[-1]``; ``k``
@@ -466,12 +532,28 @@ def flash_attention(q, k, v, kv_mask=None, causal: bool = False,
     0 makes every row's running maximum finite before a masked entry meets
     it); a mask, a ragged ``Tk`` or key blocks that are not whole 128-lane
     tiles build the ``masked`` one. Same numbers either way (module docstring).
+
+    ``window`` > 0 (``causal`` only): query ``i`` sees keys ``i - window < j
+    <= i``, and only the tiles that meet that band are computed, under the
+    scope ``attn.window`` (the unmasked variant also needs ``block_k`` a
+    multiple of ``block_q``, so that a row's own key is in the first tile it
+    meets).
     """
-    with jax.named_scope("attn.flash"):     # kernel, pads and the backward's loops alike
-        return _flash_attention(q, k, v, kv_mask, causal, block_q, block_k)
+    if window and not causal:
+        raise ValueError("flash_attention: a window is a causal band (causal=True)")
+    # kernel, pads and the backward's loops alike
+    with jax.named_scope("attn.window" if window else "attn.flash"):
+        return _flash_attention(q, k, v, kv_mask, causal, block_q, block_k, window)
 
 
-def _flash_attention(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int):
+def _band_pairs(t: int, window: int) -> int:
+    """(query, key) pairs of a causal band of ``window`` keys over ``t`` positions."""
+    w = min(window, t)
+    return w * (w + 1) // 2 + (t - w) * w
+
+
+def _flash_attention(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int,
+                     window: int):
     B, Tq, H, D = q.shape
     Tk, Dv = k.shape[1], v.shape[-1]
     if k.shape[-1] != D:
@@ -489,11 +571,20 @@ def _flash_attention(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int)
     # no key is masked or padded, the row statistics tile along whole lanes
     # and the step tables fit in SMEM
     unmasked = (kv_mask is None and Tk_p == Tk and block_k % 128 == 0
-                and (Tq_p // block_q) * (Tk_p // block_k) <= _MAX_TABLE_STEPS)
-    obs.get_registry().counter(
+                and (Tq_p // block_q) * (Tk_p // block_k) <= _MAX_TABLE_STEPS
+                and (not window or block_k % block_q == 0))
+    reg = obs.get_registry()
+    reg.counter(
         "synapseml_flash_kernel_builds_total",
         "traces of flash_attention, by the forward kernel variant they built",
-        ("variant",)).inc(variant="unmasked" if unmasked else "masked")
+        ("variant",)).inc(variant="window" if window else "unmasked" if unmasked else "masked")
+    if window:      # either variant computes the tiles of the step tables
+        tiles = len(_work_steps(Tq_p // block_q, Tk_p // block_k, block_q, block_k, True,
+                                window)[0])
+        reg.gauge("synapseml_attn_window_pairs_ratio",
+                  "(query, key) pairs a windowed flash call's tiles compute over the "
+                  "band's, at its latest trace").set(
+            tiles * block_q * block_k / _band_pairs(Tq, window))
     if kv_mask is None:
         kv_mask = jnp.ones((B, Tk), bool)
     Dp, Dvp = _ceil_to(D, 128), _ceil_to(Dv, 128)
@@ -509,6 +600,6 @@ def _flash_attention(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int)
     maskb = jnp.pad(kv_mask, ((0, 0), (0, Tk_p - Tk)))
     maskb = jnp.broadcast_to(maskb[:, None, :], (B, H, Tk_p)).reshape(B * H, Tk_p)
 
-    out = _flash_core(qb, kb, vb, maskb, causal, block_q, block_k, scale, unmasked)
+    out = _flash_core(qb, kb, vb, maskb, causal, block_q, block_k, scale, unmasked, window)
     out = out.reshape(B, H, Tq_p, Dvp)[:, :, :Tq, :Dv]
     return jnp.transpose(out, (0, 2, 1, 3))

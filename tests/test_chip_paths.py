@@ -155,6 +155,39 @@ def test_flash_gradient_compiles_for_a_v5e_at_the_cells_shapes(
     assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
 
 
+def test_windowed_flash_gradient_compiles_for_a_v5e_at_the_window_cells_shape(
+        compiled_pallas, one_v5e):
+    """The op's gradient as the window cell's sliding layers run it (one row of
+    8,192 positions, 64 heads of 128, a window of 512, blocks of 512: the
+    unmasked kernel over the band's tiles, the backward's loops stopped at the
+    band) through the chip's compiler, with the kernel under `attn.window`."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from synapseml_tpu.ops import flash_attention
+
+    qkv = jax.ShapeDtypeStruct((1, 8192, 64, 128), jnp.bfloat16, sharding=one_v5e)
+
+    def grad(q, k, v):
+        return jax.grad(lambda q_, k_, v_: jnp.sum(flash_attention(
+            q_, k_, v_, causal=True, block_q=512, block_k=512, window=512).astype(
+                jnp.float32)), argnums=(0, 1, 2))(q, k, v)
+
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        lowered = jax.jit(grad).lower(qkv, qkv, qkv)
+        compiled = lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+    assert "tpu_custom_call" in compiled.as_text()
+    text = lowered.as_text(debug_info=True)
+    assert "attn.window" in text and "attn.flash" not in text
+    # the float32 dq buffer of the backward (268 MB) beside dk and dv, as for the causal op
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
 @pytest.mark.parametrize("N,WB", [
     (1_000_000, 32 * 256),      # chip_smoke Leg B's shape (Higgs-1M, depth-5 level)
     (1001, 300),                # ragged rows and bins

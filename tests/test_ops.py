@@ -222,7 +222,7 @@ def test_flash_kernel_builds_count_one_a_trace():
     assert _built_since(before) == {"unmasked": 1, "masked": 2}
 
 
-def _two_loop_flash_bwd(causal, block_q, block_k, scale, unmasked, res, g):
+def _two_loop_flash_bwd(causal, block_q, block_k, scale, unmasked, window, res, g):
     """The backward pass as it was until PR 38, kept as the plain reference of
     the single pass: one loop nest over the pairs for dq, a second one over
     the same pairs for dk and dv, each forming its own ``s``, ``p``, ``dp`` and
@@ -301,10 +301,10 @@ def _two_loop_flash_bwd(causal, block_q, block_k, scale, unmasked, res, g):
             whole(dv_blocks, Tk, v.dtype), None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _two_loop_flash_core(q, k, v, kv_mask, causal, block_q, block_k, scale, unmasked):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _two_loop_flash_core(q, k, v, kv_mask, causal, block_q, block_k, scale, unmasked, window):
     return attention._flash_core_fwd_impl(q, k, v, kv_mask, causal, block_q, block_k,
-                                          scale, unmasked)[0]
+                                          scale, unmasked, window)[0]
 
 
 _two_loop_flash_core.defvjp(attention._flash_core_fwd, _two_loop_flash_bwd)
