@@ -120,6 +120,16 @@ def pallas_interpret() -> bool:
         f"(attn_impl='einsum', histogram_impl='segment') on this backend")
 
 
+def pallas_compiled() -> bool:
+    """Whether a Pallas TPU kernel runs compiled here (``tpu``). The choice
+    of a kernel for speed asks this: the ``cpu`` interpreter
+    (:func:`pallas_interpret`) is a test device, never a fast path, and other
+    backends run no Pallas TPU kernel."""
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
 def _visible_tpu_chips() -> int:
     """TPU chips on this host's PCI bus, counted WITHOUT initialising a JAX
     backend (which would claim them). An upper bound: a machine may hand a

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["TransformerConfig", "AttnKind", "Yarn", "Attention", "LatentAttention",
+           "resolve_attn_impl", "FLASH_MIN_KEYS",
            "MlpBlock", "ShortConv", "Block", "Encoder",
            "RMSNorm", "apply_rope", "make_causal_mask"]
 
@@ -116,12 +117,20 @@ class TransformerConfig:
     act: str = "gelu"
     remat: bool = False
     norm_eps: float = 1e-6
-    # attention backend: 'einsum' (XLA, always available), 'flash' (Pallas
-    # blockwise kernel, ops.flash_attention), 'ring' / 'ulysses'
+    # attention backend: 'auto' (the call picks: `resolve_attn_impl`),
+    # 'einsum' (XLA, always available), 'flash' (Pallas blockwise kernel,
+    # ops.flash_attention: no O(T^2) score buffer), 'ring' / 'ulysses'
     # (sequence-parallel over `seq_axis` — an error without a mesh in scope
-    # whose seq axis size > 1). Which is fastest on the chip is not
-    # measured (ROADMAP A6, C3); 'flash' avoids the O(T^2) score buffer.
-    attn_impl: str = "einsum"
+    # whose seq axis size > 1). 'auto' is 'flash' where the flash op can run
+    # the call (not decode; no mask or a key mask), Pallas runs compiled (the
+    # TPU), no mesh of more than one device is in scope (the compiler does
+    # not partition a Pallas kernel) and the keys number FLASH_MIN_KEYS or
+    # more, else 'einsum'; and its 'flash' runs the kernel only where the
+    # call is not differentiated (`_forward_only`). On a v5e the flash
+    # forward wins from there, its XLA backward does not (PERF.md section
+    # 6); the key-padded kernel runs the unmasked one's body
+    # (ops/attention.py).
+    attn_impl: str = "auto"
     seq_axis: str = "seq"
     # mixture-of-experts MLP (switch-transformer routing): 0 = dense MLP.
     # Expert weights carry the 'expert' logical axis, so on a mesh with an
@@ -187,7 +196,11 @@ class TransformerConfig:
     moe_mlp_dim: int = 0
     mlp_bias: bool = True  # biases on the dense MLP's projections
     tie_embeddings: bool = False  # the LM head is the embedding matrix (LlamaLM)
-    flash_block: int = 128  # query and key block of attn_impl='flash'
+    # query and key block of the flash op: at 512 a row of up to 512 keys is
+    # one tile, and it measured fastest at every cell's shape (BERT-base's
+    # layer forward at 512 rows of 512: 13.31 ms, 20.76 at 256, 34.17 at 128;
+    # PERF.md section 6)
+    flash_block: int = 512
     attn_bias: bool = True  # biases on the q, k, v, o projections
     qk_norm: bool = False  # per-head RMSNorm on q and k, before RoPE
     # latent attention (`LatentAttention`): `kv_latent_rank` > 0 makes every
@@ -327,11 +340,71 @@ def make_causal_mask(q_len: int, kv_len: int, offset: int = 0, window: int = 0) 
     return mask[None, None, :, :]
 
 
+# keys from which attn_impl='auto' runs the flash op's forward. BERT-base's
+# attention layer with its projections on a v5e (ms, einsum / flash; PERF.md
+# section 6): forward at 512 rows 21.15 / 13.29 at 512 keys, 14.30 / 10.44 at
+# 384, 6.93 / 7.01 at 256, 2.90 / 3.40 at 128. Forward and gradient at 32
+# rows 4.14 / 4.38 at 512 keys and 2.55 / 2.77 at 384: the op's XLA backward
+# loses, so a differentiated call runs the einsum core (`_forward_only`)
+FLASH_MIN_KEYS = 384
+
+
+def _forward_only(fast: Callable, differentiable: Callable) -> Callable:
+    """``fast(q, k, v)`` where the call is only run forward; where it is
+    differentiated, ``differentiable``'s forward and its VJP. Both compute
+    the same attention (to rounding). On a v5e the flash op's forward beats
+    the einsum core's from ``FLASH_MIN_KEYS`` keys, and its XLA backward,
+    whose float32 probabilities cross HBM, does not beat the einsum core's
+    backward: BERT-base fine-tuning at 512 tokens read 4.7% fewer samples a
+    second through it (PERF.md section 6)."""
+    @jax.custom_vjp
+    def core(q, k, v):
+        return fast(q, k, v)
+
+    def fwd(q, k, v):
+        return jax.vjp(differentiable, q, k, v)
+
+    core.defvjp(fwd, lambda vjp, g: vjp(g))
+    return core
+
+
+def _einsum_probs(q, k, mask, head_dim: int, dtype):
+    """The einsum core's softmax probabilities ``[B, H, Q, K]``, in ``dtype``."""
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(head_dim).astype(dtype)
+    if mask is not None:
+        scores = jnp.where(mask, scores, jnp.finfo(dtype).min)
+    return jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dtype)
+
+
+def resolve_attn_impl(impl: str, kv_len: int, eligible: bool) -> str:
+    """The core an ``attn_impl`` runs a call of ``kv_len`` keys on;
+    ``eligible``: the flash op can run the call (not decode; no mask, or a
+    key mask) and loses nothing the einsum core would do (no dropout of the
+    probabilities). An explicit value is kept. ``'auto'`` is ``'flash'`` for an
+    eligible call of ``FLASH_MIN_KEYS`` keys or more where Pallas runs
+    compiled and no mesh of more than one device is in scope, else
+    ``'einsum'``: on the CPU the kernel's interpreter is a test device, never
+    a fast path, and the compiler does not partition a Pallas kernel over a
+    mesh (a data-parallel step at 512 keys would stop at lowering). Where
+    ``'auto'`` gives ``'flash'``, :class:`Attention` runs the kernel only in a
+    call that is not differentiated (:func:`_forward_only`)."""
+    if impl != "auto":
+        return impl
+    from ...core import platform
+    from ...parallel.mesh import current_mesh
+
+    mesh = current_mesh()
+    flash = (eligible and kv_len >= FLASH_MIN_KEYS and platform.pallas_compiled()
+             and (mesh is None or mesh.n_devices == 1))
+    return "flash" if flash else "einsum"
+
+
 class Attention(nn.Module):
     """Multi-head / grouped-query attention with optional rotary embeddings and
     a linen cache collection for autoregressive decode.
 
-    The score/softmax/value core dispatches on ``cfg.attn_impl``:
+    The score/softmax/value core dispatches on ``cfg.attn_impl``
+    (``'auto'`` resolved by :func:`resolve_attn_impl`):
     'einsum' (XLA), 'flash' (Pallas blockwise kernel), 'ring' (K/V rotation
     over ``cfg.seq_axis``), or 'ulysses' (all-to-all head/token swap over
     ``cfg.seq_axis``) — the long-context paths the reference lacks,
@@ -355,14 +428,17 @@ class Attention(nn.Module):
                              and mask.shape[1] == 1 and mask.shape[2] == 1)
         if mask_is_kv_shaped:
             kv_mask = mask[:, 0, 0, :]
-        # init only makes params and the core has none: its example inputs
-        # (a (1, 8) batch in shard_inference_params) need not divide a mesh
-        impl = "einsum" if self.is_initializing() else cfg.attn_impl
         # NOTE: flash/ring never materialize attention probabilities, so
         # attention-probability dropout does not apply on those paths (standard
         # for fused kernels); residual/MLP dropout is unaffected. Falling back
         # to einsum here would silently reintroduce the O(T^2) score matrix.
         eligible = not self.decode and (mask is None or mask_is_kv_shaped)
+        # init only makes params and the core has none: its example inputs
+        # (a (1, 8) batch in shard_inference_params) need not divide a mesh.
+        # 'auto' keeps the probabilities' dropout where it applies
+        impl = "einsum" if self.is_initializing() else resolve_attn_impl(
+            cfg.attn_impl, k.shape[1],
+            eligible and not (cfg.dropout > 0 and self.has_rng("dropout")))
         if window and impl in ("ring", "ulysses"):
             raise ValueError(f"attn_impl={impl!r} has no sliding window: 'flash' or 'einsum'")
 
@@ -394,21 +470,31 @@ class Attention(nn.Module):
         if impl == "flash" and eligible:
             from ...ops import flash_attention
 
-            # the op names its scope, `attn.flash` (`attn.window` under a window)
-            return flash_attention(q, k, v, kv_mask=kv_mask, causal=cfg.causal,
-                                   block_q=cfg.flash_block, block_k=cfg.flash_block,
-                                   window=window)
+            def flash(q, k, v):
+                # the op names its scope, `attn.flash` (`attn.window` under a window)
+                return flash_attention(q, k, v, kv_mask=kv_mask, causal=cfg.causal,
+                                       block_q=cfg.flash_block, block_k=cfg.flash_block,
+                                       window=window)
 
-        if cfg.causal and not self.decode:
-            causal = make_causal_mask(q.shape[1], k.shape[1], window=window)
-            mask = causal if mask is None else jnp.logical_and(mask, causal)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(D).astype(cfg.dtype)
-        if mask is not None:
-            scores = jnp.where(mask, scores, jnp.finfo(cfg.dtype).min)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(cfg.dtype)
+            if cfg.attn_impl == "flash":
+                return flash(q, k, v)
+            # 'auto': the kernel where the call runs forward only
+            mask = self._einsum_mask(mask, q.shape[1], k.shape[1], window)
+            return _forward_only(flash, lambda q, k, v: jnp.einsum(
+                "bhqk,bkhd->bqhd", _einsum_probs(q, k, mask, D, cfg.dtype), v))(q, k, v)
+
+        mask = self._einsum_mask(mask, q.shape[1], k.shape[1], window)
+        probs = _einsum_probs(q, k, mask, D, cfg.dtype)
         if cfg.dropout > 0:
             probs = nn.Dropout(cfg.dropout, deterministic=not self.has_rng("dropout"))(probs)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+    def _einsum_mask(self, mask, q_len: int, kv_len: int, window: int):
+        """The einsum core's mask: ``mask`` and, outside decode, the causal band."""
+        if self.cfg.causal and not self.decode:
+            causal = make_causal_mask(q_len, kv_len, window=window)
+            mask = causal if mask is None else jnp.logical_and(mask, causal)
+        return mask
 
     def _attend_indexed(self, x, q, k, v, mask, positions):
         """Learned sparse attention: the indexer reads the layer's input (no
@@ -958,6 +1044,7 @@ class Encoder(nn.Module):
         # the flash op's output and log-sum-exp, so no kernel is launched again)
         from ...ops import attention, sparse_attention
 
+        # ('auto' runs the flash op only where the call is not differentiated)
         names = (sparse_attention.REMAT_SAVED_NAMES if cfg.attn_topk > 0 else ()) \
             + (attention.REMAT_SAVED_NAMES if cfg.attn_impl == "flash" else ())
         policy = jax.checkpoint_policies.save_only_these_names(*names) if names else None

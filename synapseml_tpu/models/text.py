@@ -96,11 +96,12 @@ class DeepTextClassifier(Estimator, _TextParams):
     checkpoint_keep = Param("checkpoint_keep", "retain the most recent K "
                             "checkpoints", default=3,
                             converter=TypeConverters.to_int)
-    attn_impl = Param("attn_impl", "attention backend: einsum | flash | ring "
-                      "| ulysses (None = architecture default; ring/ulysses "
+    attn_impl = Param("attn_impl", "attention backend: auto | einsum | flash "
+                      "| ring | ulysses (None = architecture default; auto "
+                      "picks einsum or flash from the call; ring/ulysses "
                       "need a mesh with a seq axis > 1; ulysses also needs "
                       "n_heads divisible by the seq-axis size)", default=None,
-                      validator=lambda v: v in (None, "einsum", "flash",
+                      validator=lambda v: v in (None, "auto", "einsum", "flash",
                                                 "ring", "ulysses"))
     tokenizer = ComplexParam("tokenizer", "tokenizer object/config/name", default=None)
     mesh_config = ComplexParam("mesh_config", "MeshConfig override", default=None)
@@ -208,17 +209,19 @@ class DeepTextModel(Model, _TextParams):
     tokenizer_config = ComplexParam("tokenizer_config", "tokenizer config dict")
     train_metrics = ComplexParam("train_metrics", "loss/throughput trace", default=None)
     attn_impl = Param("attn_impl", "serve-time attention backend override: "
-                      "einsum | flash (None = the trained arch's choice); "
-                      "pure kernel selection — the param tree is unchanged",
-                      default=None,
-                      validator=lambda v: v in (None, "einsum", "flash"))
+                      "auto | einsum | flash (None = the trained arch's "
+                      "choice); pure kernel selection — the param tree is "
+                      "unchanged", default=None,
+                      validator=lambda v: v in (None, "auto", "einsum", "flash"))
 
     # publish-time backend search (registry/autotune.py): the single-chip
     # attention impls the attn_backends decision bench compares — the
     # fastest per platform is pinned into the artifact manifest at publish
     # and re-applied at /admin/load. Declared on the MODEL (the class
     # artifacts actually serve), not the estimator: ring/ulysses need a
-    # mesh topology and stay out of the serve-path search.
+    # mesh topology and stay out of the serve-path search. A manifest's pin
+    # owns the choice where one was published; elsewhere the arch's default
+    # 'auto' picks from the call (transformer.resolve_attn_impl).
     _AUTOTUNE_PARAMS = {"attn_impl": ("einsum", "flash")}
 
     def __init__(self, **kw):

@@ -114,17 +114,38 @@ def test_flash_attention_lowers_for_tpu_at_the_latent_cells_widths(compiled_pall
 
 
 @pytest.fixture(scope="module")
-def one_v5e():
-    """One chip of a v5e that is described, not attached: the TPU's own
-    compiler runs here (Mosaic for the kernel, XLA:TPU for the backward's
-    loops) and says what the program asks of the chip's memory."""
+def v5e_2x2():
+    """The four chips of a v5e host that is described, not attached: the
+    TPU's own compiler runs here (Mosaic for the kernel, XLA:TPU for the
+    backward's loops) and says what the program asks of the chip's memory."""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def one_v5e(v5e_2x2):
+    """One chip of the described v5e host."""
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_2x2[0])
+
+
+def _compiled_without_the_cache(fn, *specs):
+    """``fn`` compiled for the described chips: an executable for a described
+    chip cannot be read back from the compile cache, so it is kept out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(fn).lower(*specs).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
 
 
 @pytest.mark.parametrize("qk_shape,v_shape,temp_gb", [
@@ -138,19 +159,9 @@ def test_flash_gradient_compiles_for_a_v5e_at_the_cells_shapes(
     of 512) through the chip's compiler. Its temporaries hold the backward's
     float32 ``dq`` buffer (537 MB and 268 MB) beside ``dk`` and ``dv``: 2.15
     and 0.67 GB as this was written."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     qk = jax.ShapeDtypeStruct(qk_shape, jnp.bfloat16, sharding=one_v5e)
     v = jax.ShapeDtypeStruct(v_shape, jnp.bfloat16, sharding=one_v5e)
-    # an executable for a described chip cannot be read back from the cache
-    cache_was_on = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        compiled = jax.jit(_cell_flash_grad).lower(qk, qk, v).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was_on)
-        compilation_cache.reset_cache()
+    compiled = _compiled_without_the_cache(_cell_flash_grad, qk, qk, v)
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
 
@@ -186,6 +197,75 @@ def test_windowed_flash_gradient_compiles_for_a_v5e_at_the_window_cells_shape(
     assert "attn.window" in text and "attn.flash" not in text
     # the float32 dq buffer of the backward (268 MB) beside dk and dv, as for the causal op
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+@pytest.mark.parametrize("rows,grad,temp_gb", [
+    pytest.param(512, False, 2.0, id="bert_base.score"),
+    pytest.param(32, True, 0.7, id="finetune_t512_shape_gradient")])
+def test_key_padded_flash_compiles_for_a_v5e_at_the_bert_cells_shapes(
+        compiled_pallas, one_v5e, rows, grad, temp_gb):
+    """The op as BERT-base runs it at 512 tokens under its key-padding mask
+    (12 heads of 64 along the lanes, not causal, blocks of 512: one tile a
+    pair of heads and row, the masked kernel): the score cell's forward, and
+    the gradient at the fine-tuning cell's shape (that cell's ``'auto'``
+    differentiates the einsum core; ``attn_impl='flash'`` runs this), through
+    the chip's compiler. The
+    forward's temporaries, 1.21 GB as this was written, are the operands laid
+    out ``[512, 512, 768]`` from the arguments' layout; the heads moved next
+    to the batch and padded to 128 lanes took 4.83. The gradient's, 0.61 GB,
+    hold the backward's float32 ``p`` tile ``[32, 12, 512, 512]`` (0.40)."""
+    from synapseml_tpu.ops import flash_attention
+
+    qkv = jax.ShapeDtypeStruct((rows, 512, 12, 64), jnp.bfloat16, sharding=one_v5e)
+    mask = jax.ShapeDtypeStruct((rows, 512), jnp.bool_, sharding=one_v5e)
+
+    def fwd(q, k, v, m):
+        return flash_attention(q, k, v, kv_mask=m, block_q=512, block_k=512)
+
+    def fwd_grad(q, k, v, m):
+        return jax.grad(lambda q_, k_, v_: jnp.sum(fwd(q_, k_, v_, m).astype(jnp.float32)),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    compiled = _compiled_without_the_cache(fwd_grad if grad else fwd, qkv, qkv, qkv, mask)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["scoring", "fine_tuning"])
+def test_bert_at_512_keys_compiles_for_a_data_mesh_of_four_v5e(compiled_pallas, monkeypatch,
+                                                                 v5e_2x2, grad):
+    """BERT-base at 512 tokens under its key-padding mask on a four-chip
+    host's data mesh: ``DeepTextModel``'s scoring with a ``mesh_config``, and
+    ``DeepTextClassifier._fit``'s gradient (its ``MeshConfig()`` takes every
+    chip for ``data``). ``attn_impl='auto'`` stays einsum under the mesh,
+    since the compiler cannot partition a Pallas kernel, and both compile."""
+    from synapseml_tpu.models.flax_nets.bert import BertClassifier, bert_base
+    from synapseml_tpu.parallel import MeshConfig, create_mesh
+
+    monkeypatch.setattr(platform, "pallas_compiled", lambda: True)
+    mesh = create_mesh(MeshConfig(), devices=v5e_2x2, allow_fewer=False)
+    assert mesh.axis_sizes["data"] == 4
+    cfg = bert_base()
+    assert cfg.attn_impl == "auto"
+    module = BertClassifier(cfg, num_classes=2)
+    ids, mask = (jax.ShapeDtypeStruct((8, 512), jnp.int32, sharding=mesh.batch_sharding())
+                 for _ in range(2))
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=mesh.replicated()),
+        jax.eval_shape(module.init, jax.random.PRNGKey(0), ids, mask)["params"])
+
+    def score(params, ids, mask):
+        return jax.nn.softmax(module.apply({"params": params}, ids, mask), axis=-1)
+
+    def fine_tune(params, ids, mask):
+        return jax.grad(lambda p: jnp.sum(module.apply({"params": p}, ids, mask)))(params)
+
+    series = 'synapseml_flash_kernel_builds_total{variant="masked"}'
+    before = obs.get_registry().snapshot().get(series, 0.0)
+    with mesh.scope():
+        compiled = _compiled_without_the_cache(fine_tune if grad else score, params, ids, mask)
+    assert obs.get_registry().snapshot().get(series, 0.0) == before
+    assert "tpu_custom_call" not in compiled.as_text()
 
 
 @pytest.mark.parametrize("N,WB", [

@@ -408,12 +408,13 @@ def test_gradients_do_not_depend_on_what_the_remat_keeps(other, monkeypatch):
 
 
 @pytest.mark.parametrize("over,names", [
-    ({}, None),
+    ({"attn_impl": "einsum"}, None),
     ({"attn_impl": "flash"}, attention.REMAT_SAVED_NAMES),
-    ({"attn_topk": 8}, sparse_attention.REMAT_SAVED_NAMES),
+    ({}, None),                 # 'auto': a differentiated call runs the einsum core
+    ({"attn_topk": 8, "attn_impl": "einsum"}, sparse_attention.REMAT_SAVED_NAMES),
     ({"attn_topk": 8, "attn_impl": "flash"},
      sparse_attention.REMAT_SAVED_NAMES + attention.REMAT_SAVED_NAMES)],
-    ids=["einsum", "flash", "indexed", "indexed_and_flash"])
+    ids=["einsum", "flash", "auto", "indexed", "indexed_and_flash"])
 def test_the_remat_keeps_the_names_of_the_ops_the_configuration_runs(over, names, monkeypatch,
                                                                       capsys):
     cfg = TransformerConfig(hidden=32, n_layers=1, n_heads=4, mlp_dim=64, causal=True, **over)

@@ -174,6 +174,18 @@ def test_deep_vision_classifier_runs():
     assert acc > 0.8, f"train accuracy {acc} too low"
 
 
+@pytest.mark.parametrize("stage", ["DeepTextClassifier", "DeepTextModel"])
+def test_deep_text_attn_impl_returns_to_the_default(stage):
+    """``'auto'``, the architectures' default, is a value either text stage
+    takes: a pinned model can return to it."""
+    from synapseml_tpu.models import text
+
+    cls = getattr(text, stage)
+    assert cls(attn_impl="flash").set(attn_impl="auto").get("attn_impl") == "auto"
+    with pytest.raises(ValueError, match="attn_impl"):
+        cls(attn_impl="fastest")
+
+
 def test_deep_text_attn_impl_ring_on_seq_mesh():
     """attn_impl='ring' wired through DeepTextClassifier: fit + transform on a
     mesh with a seq axis (the long-context path the reference lacks)."""

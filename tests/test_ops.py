@@ -1,6 +1,8 @@
 """ops module: flash attention (Pallas, interpret on CPU) + ring attention
 (shard_map over the 8-device seq mesh) vs the XLA reference oracle."""
 
+import contextlib
+import dataclasses
 import functools
 
 import jax
@@ -93,6 +95,50 @@ def test_fully_masked_rows_zero():
     assert float(jnp.max(jnp.abs(out0))) == 0.0
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("Dv", [64, 128])
+@pytest.mark.parametrize("T,block", [(128, 128), (512, 128)], ids=["one_tile", "four_tiles"])
+def test_flash_key_padded_matches_reference(T, block, Dv, dtype):
+    """The masked variant under a key-padding mask, not causal (the BERT
+    cells' call: heads of one width that tile the lanes run along them),
+    against the float32 ``reference_attention``: output and the three
+    gradients. The four batch entries' keys: all of them; right padding
+    (keys from 100 on masked); left padding (over four tiles the whole first
+    key block masked: a row's running max stays at -1e30 for a block and what
+    it summed there is wiped at its first real key); none (exact zeros, and
+    no gradient reaches that entry's q, k or v)."""
+    B, H, D = 4, 2, 64
+    rs = np.random.default_rng(T + Dv)
+    q, k = (jnp.asarray(rs.normal(size=(B, T, H, D)), jnp.float32) for _ in range(2))
+    v = jnp.asarray(rs.normal(size=(B, T, H, Dv)), jnp.float32)
+    w = jnp.asarray(rs.normal(size=(B, T, H, Dv)), jnp.float32)
+    pos = np.arange(T)
+    left = block + 20 if T > block else 32
+    kv_mask = jnp.asarray(np.stack([pos >= 0, pos < 100, pos >= left, pos < 0]))
+
+    def run(fn, *xs):
+        def loss(q, k, v):
+            out = fn(q, k, v, kv_mask=kv_mask)
+            return jnp.sum(out.astype(jnp.float32) * w), out
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(*xs)
+        return (out, *grads)
+
+    before = _kernel_builds()
+    got = run(functools.partial(flash_attention, block_q=block, block_k=block),
+              *(x.astype(dtype) for x in (q, k, v)))
+    assert _built_since(before) == {"unmasked": 0, "masked": 1}
+    want = run(reference_attention, q, k, v)
+    for name, a, r in zip(("out", "dq", "dk", "dv"), got, want):
+        # the file's tolerances: test_flash_matches_reference / _gradients_match /
+        # _bf16_matches_f32_reference
+        if dtype == jnp.float32:
+            tol = dict(atol=2e-5 if name == "out" else 5e-5)
+        else:
+            tol = dict(atol=3e-2) if name == "out" else dict(atol=0.15, rtol=0.05)
+        np.testing.assert_allclose(np.asarray(r), np.asarray(a, np.float32), err_msg=name, **tol)
+        assert np.all(np.asarray(a, np.float32)[3] == 0.0), name
+
+
 def _kernel_builds() -> dict:
     snap = obs.get_registry().snapshot()
     return {v: snap.get('synapseml_flash_kernel_builds_total{variant="%s"}' % v, 0.0)
@@ -116,11 +162,14 @@ def _within_one_ulp(a, b, dtype):
 @pytest.mark.parametrize("D", [64, 128])
 @pytest.mark.parametrize("block_q,block_k", [(128, 128), (128, 256), (256, 128)])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_without_a_mask_equals_flash_with_an_all_ones_mask(causal, block_q, block_k,
-                                                                   D, dtype):
-    """The unmasked variant (no mask operand, no guard, only the steps that hold
-    work, lane-replicated row statistics) against the masked one, which is the
-    kernel every call ran before: output and the three gradients."""
+def test_flash_without_a_mask_equals_flash_with_an_all_ones_mask(monkeypatch, causal, block_q,
+                                                                   block_k, D, dtype):
+    """The unmasked variant (no mask operand, only the steps that hold work)
+    against the masked one in the same ``[B * H, T, 128]`` layout, which is the
+    kernel every call ran before: output and the three gradients. (A masked
+    call without ``causal`` whose heads tile the lanes runs them along the
+    lanes: test_flash_key_padded_matches_reference.)"""
+    monkeypatch.setattr(attention, "_heads_tile_the_lanes", lambda H, D, Dv: False)
     T = 512                                     # 2..4 blocks a side
     q, k, v, _ = make_qkv(B=1, T=T, H=2, D=D, seed=D + block_q)
     ones = jnp.ones((1, T), bool)
@@ -381,7 +430,8 @@ def test_flash_backward_forms_five_products_a_pair_and_no_mask_tile_without_a_ma
     causal call's gradient hold five ``dot_general`` (the two loop nests held
     seven), one ``exp`` and one select a tile (the causal one) and slice no
     boolean; a call with a key mask slices the mask's block and selects on a
-    tile twice more (the mask, and the guard of rows with no key left)."""
+    tile once more (the mask: a row with no key left needs no guard, its
+    log-sum-exp is +1e30)."""
     T, block = 512, 128
     qk = jnp.zeros((2, T, 2, D), jnp.float32)
     v = jnp.zeros((2, T, 2, Dv), jnp.float32)
@@ -408,7 +458,7 @@ def test_flash_backward_forms_five_products_a_pair_and_no_mask_tile_without_a_ma
     assert count(loop_eqns(_two_loop_flash_core, None), "dot_general") == 7
     assert mask_blocks(unmasked) == []
     assert [e.outvars[0].aval.shape for e in mask_blocks(masked)] == [(4, block)]
-    for eqns, selects in ((unmasked, 1), (masked, 3)):
+    for eqns, selects in ((unmasked, 1), (masked, 2)):
         tiles = [e.primitive.name for e in eqns if e.outvars[0].aval.shape == (4, block, block)]
         assert tiles.count("select_n") == selects and tiles.count("exp") == 1
 
@@ -492,7 +542,7 @@ def test_encoder_attn_impls_agree():
     mask_1d[:, -5:] = False
     mask = jnp.asarray(mask_1d)[:, None, None, :]
 
-    enc = Encoder(base)
+    enc = Encoder(dataclasses.replace(base, attn_impl="einsum"))
     variables = enc.init(jax.random.PRNGKey(0), x, mask)
 
     out_einsum = enc.apply(variables, x, mask)
@@ -511,6 +561,89 @@ def test_encoder_attn_impls_agree():
         out_uly = Encoder(dataclasses.replace(base, attn_impl="ulysses")).apply(variables, x, mask)
     np.testing.assert_allclose(np.asarray(out_einsum)[valid],
                                np.asarray(out_uly)[valid], atol=2e-4)
+
+
+@pytest.mark.parametrize("impl,kv_len,eligible,compiled,devices,want", [
+    ("auto", 0, True, True, 0, "flash"),         # at the threshold (offsets from it)
+    ("auto", 3584, True, True, 0, "flash"),
+    ("auto", -1, True, True, 0, "einsum"),       # under it
+    ("auto", 0, True, False, 0, "einsum"),       # the CPU: the interpreter is no fast path
+    ("auto", 0, False, True, 0, "einsum"),       # decode, a [.., Q, K] mask, dropout
+    ("auto", 0, True, True, 1, "flash"),         # a mesh of one device in scope
+    ("auto", 0, True, True, 4, "einsum"),        # of four: no Pallas kernel is partitioned
+    ("einsum", 3584, True, True, 0, "einsum"),
+    ("flash", -500, False, False, 0, "flash"),
+    ("flash", 0, True, True, 4, "flash"),
+    ("ring", 0, True, True, 0, "ring"),
+    ("ulysses", -500, True, False, 0, "ulysses")])
+def test_auto_attention_resolves_from_the_call(monkeypatch, impl, kv_len, eligible, compiled,
+                                               devices, want):
+    from synapseml_tpu.core import platform
+    from synapseml_tpu.models.flax_nets.transformer import FLASH_MIN_KEYS, resolve_attn_impl
+
+    monkeypatch.setattr(platform, "pallas_compiled", lambda: compiled)
+    scope = (create_mesh(MeshConfig(data=devices), devices=jax.devices()[:devices]).scope()
+             if devices else contextlib.nullcontext())
+    with scope:
+        assert resolve_attn_impl(impl, FLASH_MIN_KEYS + kv_len, eligible) == want
+
+
+def _encoder_builds(cfg, x, mask, decode=False) -> dict:
+    """Flash kernels an ``Encoder`` of ``cfg`` builds, traced (not run) over
+    ``x`` with ``mask``, as on a TPU."""
+    from synapseml_tpu.models.flax_nets.transformer import Encoder
+
+    enc = Encoder(cfg, decode=decode)
+    variables = jax.eval_shape(enc.init, jax.random.PRNGKey(0), x, mask)
+    before = _kernel_builds()
+    jax.eval_shape(functools.partial(enc.apply, mutable=["cache"] if decode else False),
+                   variables, x, mask)
+    return _built_since(before)
+
+
+@pytest.mark.parametrize("call", ["key_mask", "no_mask", "query_key_mask", "decode",
+                                  "under_the_threshold"])
+def test_auto_attention_runs_flash_only_where_the_call_is_eligible(monkeypatch, call):
+    """Through ``Attention._attend``: a key mask or none, at the threshold,
+    runs the flash op (masked or unmasked kernel); a ``[.., Q, K]`` mask, a
+    decode step over its cache, or fewer keys than the threshold runs einsum."""
+    from synapseml_tpu.core import platform
+    from synapseml_tpu.models.flax_nets.transformer import FLASH_MIN_KEYS, TransformerConfig
+
+    monkeypatch.setattr(platform, "pallas_compiled", lambda: True)
+    T = FLASH_MIN_KEYS - (call == "under_the_threshold")
+    cfg = TransformerConfig(hidden=32, n_layers=2, n_heads=2, mlp_dim=64, max_len=T,
+                            causal=call == "decode")
+    assert cfg.attn_impl == "auto"
+    x = jax.ShapeDtypeStruct((2, 1 if call == "decode" else T, 32), jnp.float32)
+    mask = {"key_mask": jax.ShapeDtypeStruct((2, 1, 1, T), jnp.bool_),
+            "query_key_mask": jax.ShapeDtypeStruct((2, 1, T, T), jnp.bool_),
+            "under_the_threshold": jax.ShapeDtypeStruct((2, 1, 1, T), jnp.bool_)}.get(call)
+    want = {"key_mask": {"unmasked": 0, "masked": 2},
+            "no_mask": {"unmasked": 2, "masked": 0}}.get(call, {"unmasked": 0, "masked": 0})
+    assert _encoder_builds(cfg, x, mask, decode=call == "decode") == want
+
+
+@pytest.mark.parametrize("preset,T,builds", [
+    ("bert_base", 128, 0),          # bert_base.finetune, bert_base.finetune_dp4
+    ("vit_b16", 197, 0),            # vit_b16.finetune (no mask)
+    ("bert_base", 512, 12)],        # bert_base.score (forward only)
+    ids=["bert_base_T128", "vit_b16_T197", "bert_base_T512"])
+def test_auto_attention_keeps_einsum_for_the_short_encoder_cells(monkeypatch, preset, T, builds):
+    """The presets' own rule, as on a TPU: BERT-base at 128 keys and
+    ViT-B/16 at 197 resolve ``'auto'`` to einsum (their programs are the
+    ones they always were); BERT-base's forward at 512 under its key-padding
+    mask runs the masked flash kernel in each of its 12 layers."""
+    from synapseml_tpu.core import platform
+    from synapseml_tpu.models.flax_nets.bert import bert_base
+    from synapseml_tpu.models.flax_nets.vit import vit_b16
+
+    monkeypatch.setattr(platform, "pallas_compiled", lambda: True)
+    cfg = {"bert_base": bert_base, "vit_b16": vit_b16}[preset]()
+    assert cfg.attn_impl == "auto" and cfg.flash_block == 512
+    x = jax.ShapeDtypeStruct((2, T, cfg.hidden), cfg.dtype)
+    mask = jax.ShapeDtypeStruct((2, 1, 1, T), jnp.bool_) if preset == "bert_base" else None
+    assert _encoder_builds(cfg, x, mask) == {"unmasked": 0, "masked": builds}
 
 
 def test_ring_attention_grad_matches_reference_with_mask():

@@ -237,13 +237,20 @@ class BareRematEncoder(nn.Module):
         return x if self.cfg.norm_position == "post" else transformer._norm(self.cfg)(x)
 
 
+_BERT_SHAPED = TransformerConfig(hidden=32, n_layers=2, n_heads=4, mlp_dim=64, max_len=16,
+                                 norm_position="post", remat=True, attn_impl="einsum")
+_LLAMA_SHAPED = TransformerConfig(hidden=32, n_layers=2, n_heads=4, n_kv_heads=2, mlp_dim=64,
+                                  max_len=16, causal=True, use_rope=True, norm="rmsnorm",
+                                  gated_mlp=True, act="silu", remat=True, attn_impl="einsum")
+
+
 @pytest.mark.parametrize("cfg", [
-    TransformerConfig(hidden=32, n_layers=2, n_heads=4, mlp_dim=64, max_len=16,
-                      norm_position="post", remat=True),
-    TransformerConfig(hidden=32, n_layers=2, n_heads=4, n_kv_heads=2, mlp_dim=64, max_len=16,
-                      causal=True, use_rope=True, norm="rmsnorm", gated_mlp=True, act="silu",
-                      remat=True)], ids=["bert_shaped", "llama_shaped"])
+    _BERT_SHAPED, _LLAMA_SHAPED, dataclasses.replace(_BERT_SHAPED, attn_impl="auto"),
+    dataclasses.replace(_LLAMA_SHAPED, attn_impl="auto")],
+    ids=["bert_shaped", "llama_shaped", "bert_shaped_auto", "llama_shaped_auto"])
 def test_a_module_without_indexed_attention_keeps_its_rematerialised_program(cfg):
+    """``'auto'`` keeps nothing of the flash op: a differentiated call runs
+    the einsum core."""
     x = jnp.ones((2, 8, cfg.hidden), jnp.float32)
     mask = jnp.ones((2, 1, 1, 8), bool)
     texts = []
