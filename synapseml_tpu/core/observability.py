@@ -637,6 +637,13 @@ class Span:
     def set_attribute(self, key: str, value) -> None:
         self.attributes[key] = value
 
+    def restart(self) -> None:
+        """Read the start's clocks again: for a caller that made the span
+        before the work it times began."""
+        self.start_ns = time.time_ns()
+        self.start_wall = self.start_ns / 1e9
+        self._start_mono = time.perf_counter()
+
     def end(self, error: BaseException | None = None) -> None:
         if self.duration_ms is None:
             self.duration_ms = (time.perf_counter() - self._start_mono) * 1e3

@@ -142,10 +142,10 @@ class PipelineTrainer(Trainer):
                 f"axis of size {pipe} (one stage per coordinate)")
         params = self._rule_place_params(params)
         self._tx = _make_optimizer(self.cfg, params)
-        opt_state = self._rule_place_opt_state(params,
-                                               self._tx.init(params))
-        return TrainState(params=params, opt_state=opt_state,
-                          step=jnp.zeros((), jnp.int32), batch_stats=None)
+        opt_state, step = self._place_counters(
+            self._rule_place_opt_state(params, self._tx.init(params)), 0)
+        return TrainState(params=params, opt_state=opt_state, step=step,
+                          batch_stats=None)
 
     # ---- the pipelined loss (consumed by Trainer._step_fn) ----------------
     def _pipeline_loss(self, variables, batch: dict) -> jax.Array:

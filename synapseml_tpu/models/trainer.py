@@ -200,8 +200,11 @@ class _LoopSpan:
     ``core.observability`` span (always recorded; ``obs.Span`` says where its
     times lie in a profiler trace) and as a ``jax.profiler.TraceAnnotation``
     of the same name, which an operator's own profile shows when its host
-    tracer is on and which costs a flag check when it is not. The duration of
-    a ``train.<phase>`` span also feeds ``synapseml_train_loop_ms{phase}``.
+    tracer is on and which costs a flag check when it is not. The span's
+    clocks are read right beside the annotation's, with the tracer's
+    bookkeeping (new ids, the lock that the chunk producer's spans share)
+    outside both, so the two agree to microseconds. The duration of a
+    ``train.<phase>`` span also feeds ``synapseml_train_loop_ms{phase}``.
     ``keep = False`` inside the block leaves no span behind."""
 
     __slots__ = ("span", "keep", "_start", "_ann", "_tracer")
@@ -212,18 +215,20 @@ class _LoopSpan:
         self.keep = True
 
     def __enter__(self) -> "_LoopSpan":
-        self._ann = jax.profiler.TraceAnnotation(self._start[0])
-        self._ann.__enter__()
         self._tracer = obs.get_tracer()
         self.span = self._tracer.start_span(*self._start)
+        self._ann = jax.profiler.TraceAnnotation(self._start[0])
+        self._ann.__enter__()
+        self.span.restart()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        self.span.end(exc)
+        self._ann.__exit__(exc_type, exc, tb)
         if self.keep:
             self._tracer.end_span(self.span, error=exc)
         else:
             self._tracer.discard_span(self.span)
-        self._ann.__exit__(exc_type, exc, tb)
         phase = self.span.name.removeprefix("train.")
         if phase in _LOOP_PHASES and self.keep and exc is None:
             _TRAIN_METRICS.get()["loop_ms"][phase].observe(self.span.duration_ms)
@@ -583,6 +588,24 @@ class Trainer:
         pp.emit_shard_metrics(params, placed, self.mesh)
         return placed
 
+    def _place_counters(self, opt_state, step: int) -> tuple[Any, jax.Array]:
+        """``opt_state`` with its 0-d leaves (Adam's ``count``, a schedule's
+        or ``MultiSteps``' counters), and ``step``, replicated on the mesh, as
+        the jitted step returns them; a leaf the rule table already placed
+        stays as it is. A counter made off the mesh has another type than
+        the step's output, so the step would meet a second signature at its
+        second dispatch and be traced and compiled again."""
+        from ..parallel.partition import place_leaf
+
+        rep = self.mesh.replicated()
+
+        def place(x):
+            if np.ndim(x) or getattr(getattr(x, "sharding", None), "mesh", None) == rep.mesh:
+                return x
+            return place_leaf(x, rep)
+
+        return jax.tree.map(place, opt_state), place_leaf(np.int32(step), rep)
+
     def checkpoint_sharding_fn(self):
         """Path-aware ``sharding_fn`` for ``restore_checkpoint``: leaves
         restore DIRECTLY onto their rule-table placement (each device
@@ -646,10 +669,10 @@ class Trainer:
             _, treedef = jax.tree.flatten(fresh)
             opt_state = jax.tree.unflatten(
                 treedef, list(_align_restored(fresh, opt_state, "opt_state")))
-        opt_state = self._rule_place_opt_state(params, opt_state)
-        return TrainState(params=params, opt_state=opt_state,
-                          step=jnp.asarray(step, jnp.int32), batch_stats=batch_stats,
-                          constants=constants)
+        opt_state, step = self._place_counters(
+            self._rule_place_opt_state(params, opt_state), step)
+        return TrainState(params=params, opt_state=opt_state, step=step,
+                          batch_stats=batch_stats, constants=constants)
 
     def init_state(self, example_batch: dict, rng: jax.Array | None = None,
                    init_params=None, init_batch_stats=None) -> TrainState:
@@ -677,10 +700,10 @@ class Trainer:
             constants = self._unbox_with_sharding(variables["constants"])
         tx = _make_optimizer(self.cfg, params)
         self._tx = tx
-        opt_state = self._rule_place_opt_state(params, tx.init(params))
-        return TrainState(params=params, opt_state=opt_state,
-                          step=jnp.zeros((), jnp.int32), batch_stats=batch_stats,
-                          constants=constants)
+        opt_state, step = self._place_counters(
+            self._rule_place_opt_state(params, tx.init(params)), 0)
+        return TrainState(params=params, opt_state=opt_state, step=step,
+                          batch_stats=batch_stats, constants=constants)
 
     def _model_inputs(self, batch: dict) -> dict:
         drop = {"labels", "label", "mask", "_valid"}
@@ -768,6 +791,14 @@ class Trainer:
         # ".../transpose(jvp(forward))/..." (the backward pass),
         # ".../optimizer/...", ".../step_metrics/...". Metadata only.
         def step_fn(state: dict, batch: dict) -> tuple[dict, dict]:
+            if param_sh is not None:
+                # read on the declared placement too: the scanned step's carry
+                # keeps it, where XLA would lay the parameters out as the
+                # ZeRO update computes them and the state would come back
+                # with another type than it went in
+                state = dict(state, params=jax.lax.with_sharding_constraint(
+                    state["params"], param_sh))
+
             def loss_of(params):
                 variables = {"params": params}
                 if state.get("batch_stats") is not None:
@@ -848,13 +879,15 @@ class Trainer:
         ``fn.trace(sd, placed).lower().compile()`` on the dispatch's own
         outputs and batch, which is the next dispatch's signature.
 
-        Where the outputs' type is the inputs' (a state that came out of the
-        step, or was placed like one), that finds what the call just built
-        and costs a lookup. Where it is new (a fresh state's counters carry no
-        mesh), it builds the second executable here, and the next dispatch,
-        which would have built it, finds it: the work moves by one dispatch
-        and none is added. The executable that ran once and was replaced
-        keeps its times and has no bytes.
+        Where the outputs' type is the inputs' (a state from ``init_state``
+        or ``resume_state``, which place the counters as the step returns
+        them, or one that came out of the step), that finds what the call
+        just built and costs a lookup: the step has one signature. Where it is
+        new (a ``TrainState`` built by hand, its counters off the mesh), it
+        builds the second executable here, and the next dispatch, which would
+        have built it, finds it: the work moves by one dispatch and none is
+        added. The executable that ran once and was replaced keeps its times
+        and has no bytes.
 
         The caller makes the take in its own frame, as the dispatch, binds no
         name for it and spells it ``trace().lower()``: through ``fn.lower``,
@@ -906,7 +939,8 @@ class Trainer:
     # ---- scanned multi-step: K optimizer steps in ONE dispatch ----
     # Host dispatch overhead disappears: the train loop itself lives
     # on-device as a lax.scan, the TPU-idiomatic replacement for horovod's
-    # per-step host-driven loop.
+    # per-step host-driven loop. A state from init_state / resume_state has
+    # the types the step returns, so the step has one signature (_built).
     def train_steps_scan(self, state: TrainState, stacked_batches: dict
                          ) -> tuple[TrainState, dict]:
         """stacked_batches: pytree whose leaves have leading dim K (num steps)."""

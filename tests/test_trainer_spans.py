@@ -10,13 +10,14 @@ import threading
 import types
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from synapseml_tpu.core import instrumentation
 from synapseml_tpu.core import observability as obs
 from synapseml_tpu.models.flax_nets.bert import BertClassifier, bert_tiny
-from synapseml_tpu.models.trainer import Trainer, TrainerConfig
+from synapseml_tpu.models.trainer import Trainer, TrainerConfig, TrainState
 
 CHUNK, DISPATCHES, BATCH = 2, 3, 8
 COMPILES = 'synapseml_train_step_compiles_total{program="%s"}'
@@ -84,8 +85,8 @@ def chunked(mesh_dp8):
             "second": _children(spans, second_root), "second_root": second_root,
             "snapshot_after": obs.get_registry().snapshot(),
             "main_tid": threading.get_ident(),
-            # both signatures of its scanned step are compiled: later tests
-            # that need no fresh compile train on it from a fresh state
+            # its scanned step is compiled: later tests that need no fresh
+            # compile train on it from a fresh state
             "trainer": tr}
 
 
@@ -213,39 +214,32 @@ def test_the_loop_order_within_a_cycle(chunked):
 
 def test_compiled_is_the_first_dispatch(chunked):
     kids = _children(chunked["spans"], chunked["root"])["train.dispatch"]
-    # jax reported an executable under the first call alone. A fresh state's
-    # counters carry no mesh and the step's own output does, so the step has a
-    # second signature (PERF.md, section 7): it is built right after the first
-    # dispatch, and the second dispatch finds it
+    # jax reported an executable under the first call alone: `init_state` made
+    # the counters with the type the step returns them with, so the step has
+    # one signature and nothing is built after the first dispatch
     assert [s.attributes["compiled"] for s in kids] == [True, False, False]
 
 
 def test_one_compile_span_a_signature_under_its_dispatch(chunked):
     first = _children(chunked["spans"], chunked["root"])["train.dispatch"][0]
-    built = _compiles(chunked["spans"])
-    assert [(s.attributes["program"], s.attributes["signature"]) for s in built] \
-        == [("scan", 1), ("scan", 2)]
-    for s in built:
-        assert s.parent_id == first.span_id and s.trace_id == chunked["root"].trace_id
-        assert s.start_ns >= first.start_ns and s.duration_ms > 0
-        a = s.attributes
-        assert min(a["trace_ms"], a["lower_ms"], a["backend_ms"]) > 0
-        assert a["cache"] in ("hit", "miss", "off")
-    # the first ran once, under the dispatch that built it; the second was
-    # built after that dispatch had ended, and is the one every later one runs
-    assert built[0].start_ns <= first.start_ns + 1_000_000
-    assert built[1].start_ns >= first.end_ns - 1000
-    assert not set(BYTES) & set(built[0].attributes)
-    assert all(built[1].attributes[name] >= 0 for name in BYTES)
-    assert built[1].attributes["temp_bytes"] > 0 < built[1].attributes["arg_bytes"]
-    # `take_ms`: the host time of the call that handed the executable over
-    assert "take_ms" not in built[0].attributes
-    assert built[1].attributes["take_ms"] == pytest.approx(built[1].duration_ms)
+    (built,) = _compiles(chunked["spans"])
+    assert (built.attributes["program"], built.attributes["signature"]) == ("scan", 1)
+    assert built.parent_id == first.span_id and built.trace_id == chunked["root"].trace_id
+    assert built.start_ns >= first.start_ns and built.duration_ms > 0
+    a = built.attributes
+    assert min(a["trace_ms"], a["lower_ms"], a["backend_ms"]) > 0
+    assert a["cache"] in ("hit", "miss", "off")
+    # the one every later dispatch runs: its bytes, read by a lookup after the
+    # dispatch that built it
+    assert built.start_ns <= first.start_ns + 1_000_000
+    assert all(a[name] >= 0 for name in BYTES)
+    assert a["temp_bytes"] > 0 < a["arg_bytes"]
+    assert 0 < a["take_ms"] < built.duration_ms
 
 
 def test_compile_counter_equals_compiled_spans(chunked):
     built = _compiles(chunked["spans"])
-    assert chunked["snapshot"][COMPILES % "scan"] == len(built) == 2
+    assert chunked["snapshot"][COMPILES % "scan"] == len(built) == 1
     assert chunked["snapshot"][DISPATCHED % "scan"] == DISPATCHES
     for phase in ("trace", "lower", "backend"):
         assert chunked["snapshot"][COMPILE_S % (phase, "scan")] == pytest.approx(
@@ -262,7 +256,7 @@ def test_second_fit_on_the_returned_state_compiles_nothing(chunked):
     assert [s.attributes["first_step"] for s in kids] \
         == [CHUNK * DISPATCHES + i * CHUNK for i in range(DISPATCHES)]
     assert chunked["second_root"].trace_id != chunked["root"].trace_id
-    assert chunked["snapshot_after"][COMPILES % "scan"] == 2
+    assert chunked["snapshot_after"][COMPILES % "scan"] == 1
     assert chunked["snapshot_after"][DISPATCHED % "scan"] == 2 * DISPATCHES
     assert chunked["snapshot_after"][COMPILE_S % ("backend", "scan")] \
         == chunked["snapshot"][COMPILE_S % ("backend", "scan")]
@@ -285,16 +279,29 @@ def test_gauges_equal_an_independent_compile_of_the_step(chunked):
         assert chunked["snapshot"][PROGRAM_BYTES % (kind, "scan")] == getattr(stats, field)
 
 
-def test_a_state_placed_like_the_steps_output_has_one_signature(chunked):
-    """Where the first state already has the type of the step's output, one
+def _hand_built(tr, state, placed: bool) -> TrainState:
+    """A `TrainState` built by hand from `state`'s parameters, as a caller may:
+    a fresh optimizer state and step made off the mesh, or placed replicated
+    on it, as the step returns them."""
+    opt_state, step = tr._tx.init(state.params), jnp.zeros((), jnp.int32)
+    if placed:
+        rep = tr.mesh.replicated()
+        place = lambda tree: jax.tree.map(  # noqa: E731
+            lambda x: jax.device_put(x, rep) if np.ndim(x) == 0 else x, tree)
+        opt_state, step = place(opt_state), place(step)
+    return TrainState(params=state.params, opt_state=opt_state, step=step)
+
+
+@pytest.mark.parametrize("made", ["init_state", "placed_by_hand"])
+def test_a_state_placed_like_the_steps_output_has_one_signature(chunked, made):
+    """Where the first state already has the type of the step's output (as
+    `init_state` makes it, or as a caller places a state built by hand), one
     executable is built, under the first dispatch, and the bytes are its."""
     obs.reset_tracer()
     tr = _trainer(chunked["trainer"].mesh)
     state = tr.init_state(_batch(), jax.random.PRNGKey(0))
-    rep = tr.mesh.replicated()
-    place = lambda tree: jax.tree.map(  # noqa: E731
-        lambda x: jax.device_put(x, rep) if getattr(x, "ndim", None) == 0 else x, tree)
-    state.opt_state, state.step = place(state.opt_state), place(state.step)
+    if made == "placed_by_hand":
+        state = _hand_built(tr, state, placed=True)
     n = CHUNK * 2
     tr.fit(state, iter([_batch(i) for i in range(n)]), max_steps=n, scan_chunk=CHUNK)
     spans = obs.get_tracer().finished_spans()
@@ -308,8 +315,40 @@ def test_a_state_placed_like_the_steps_output_has_one_signature(chunked):
     assert 0 < built.attributes["take_ms"] < built.duration_ms
 
 
+@pytest.mark.parametrize("program,chunk", [("scan", CHUNK), ("step", 1)])
+def test_a_hand_built_state_off_the_mesh_has_a_second_signature(chunked, program, chunk):
+    """A `TrainState` built by hand with its counters off the mesh has another
+    type than the step's output: the second signature is built right after
+    the first dispatch, and every later dispatch runs it."""
+    obs.reset_tracer()
+    tr = _trainer(chunked["trainer"].mesh)
+    state = _hand_built(tr, tr.init_state(_batch(), jax.random.PRNGKey(0)), placed=False)
+    n = chunk * DISPATCHES
+    tr.fit(state, iter([_batch(i) for i in range(n)]), max_steps=n, scan_chunk=chunk)
+    spans = obs.get_tracer().finished_spans()
+    dispatches = _children(spans, _roots(spans)[0])["train.dispatch"]
+    assert [s.attributes["compiled"] for s in dispatches] == [True, False, False]
+    first = dispatches[0]
+    built = _compiles(spans)
+    assert [(s.attributes["program"], s.attributes["signature"]) for s in built] \
+        == [(program, 1), (program, 2)]
+    for s in built:
+        assert s.parent_id == first.span_id and s.duration_ms > 0
+        assert min(s.attributes[p + "_ms"] for p in ("trace", "lower", "backend")) > 0
+    # the first ran once, under the dispatch that built it; the second was
+    # built after that dispatch had ended, and is the one every later one runs
+    assert built[0].start_ns <= first.start_ns + 1_000_000
+    assert built[1].start_ns >= first.end_ns - 1000
+    assert not set(BYTES) & set(built[0].attributes)
+    assert all(built[1].attributes[name] >= 0 for name in BYTES)
+    assert built[1].attributes["temp_bytes"] > 0 < built[1].attributes["arg_bytes"]
+    # `take_ms`: the host time of the call that handed the executable over
+    assert "take_ms" not in built[0].attributes
+    assert built[1].attributes["take_ms"] == pytest.approx(built[1].duration_ms)
+
+
 def test_a_batch_of_another_shape_mid_fit_is_the_next_signature(chunked):
-    tr = chunked["trainer"]                  # has met two signatures
+    tr = chunked["trainer"]                  # has met one signature
     before = obs.get_registry().snapshot().get(COMPILES % "scan", 0)
     obs.reset_tracer()
     state = tr.init_state(_batch(), jax.random.PRNGKey(0))
@@ -320,7 +359,7 @@ def test_a_batch_of_another_shape_mid_fit_is_the_next_signature(chunked):
     assert [s.attributes["compiled"] for s in dispatches] == [False, True]
     (built,) = _compiles(spans)
     assert built.parent_id == dispatches[1].span_id
-    assert built.attributes["signature"] == 3 and set(BYTES) <= set(built.attributes)
+    assert built.attributes["signature"] == 2 and set(BYTES) <= set(built.attributes)
     assert obs.get_registry().snapshot()[COMPILES % "scan"] == before + 1
 
 
@@ -341,12 +380,13 @@ def _backend_events(run) -> int:
 
 def test_a_fit_builds_nothing_twice(mesh_dp8, monkeypatch):
     """Taking the next dispatch's executable after the first moves a build
-    and adds none: a whole fit fires as many backend-compile events as the
-    same fit with nothing taken."""
+    and adds none: a whole fit from a state built by hand off the mesh (two
+    signatures) fires as many backend-compile events as the same fit with
+    nothing taken."""
     def fit():
         tr = _trainer(mesh_dp8)
         n = CHUNK * DISPATCHES
-        state = tr.init_state(_batch(), jax.random.PRNGKey(0))
+        state = _hand_built(tr, tr.init_state(_batch(), jax.random.PRNGKey(0)), placed=False)
         tr.fit(state, iter([_batch(i) for i in range(n)]), max_steps=n, scan_chunk=CHUNK)
 
     fit()                                    # `init_state`'s and the stack's programs
@@ -360,6 +400,22 @@ def test_a_fit_builds_nothing_twice(mesh_dp8, monkeypatch):
     obs.reset_tracer()
     assert _backend_events(fit) == taking == 2
     assert not _compiles(obs.get_tracer().finished_spans())
+
+
+@pytest.mark.parametrize("chunk", [CHUNK, 1], ids=["scan", "step"])
+def test_a_fit_from_a_fresh_state_fires_one_backend_compile(mesh_dp8, chunk):
+    """The whole fit from `init_state`'s state builds the step once: one
+    backend-compile event, under the first dispatch."""
+    def fit():
+        tr = _trainer(mesh_dp8)
+        n = chunk * DISPATCHES
+        tr.fit(tr.init_state(_batch(), jax.random.PRNGKey(0)),
+               iter([_batch(i) for i in range(n)]), max_steps=n, scan_chunk=chunk)
+
+    fit()                                    # `init_state`'s and the stack's programs
+    obs.reset_tracer()
+    assert _backend_events(fit) == 1
+    assert len(_compiles(obs.get_tracer().finished_spans())) == 1
 
 
 def test_a_compile_on_another_thread_is_not_the_dispatchs(chunked):
@@ -444,13 +500,13 @@ def test_per_step_path_records_program_step(per_step):
     assert per_step["root"].attributes == {"scan_chunk": 1, "first_step": 0,
                                            "steps_done": 4}
     assert per_step["snapshot"][DISPATCHED % "step"] == 4
-    # the per-step program has the scanned one's two signatures, both built
-    # at the first dispatch
+    # the per-step program, like the scanned one, has one signature from a
+    # fresh state, built at the first dispatch
     assert [s.attributes["compiled"] for s in kids] == [True, False, False, False]
     built = _compiles(per_step["spans"])
     assert [(s.attributes["program"], s.attributes["signature"], s.parent_id)
-            for s in built] == [("step", 1, kids[0].span_id), ("step", 2, kids[0].span_id)]
-    assert per_step["snapshot"][COMPILES % "step"] == 2
+            for s in built] == [("step", 1, kids[0].span_id)]
+    assert per_step["snapshot"][COMPILES % "step"] == 1
     assert "train.chunk_wait" not in per_step["kids"]
 
 
@@ -583,6 +639,133 @@ def test_scanned_step_carries_the_scope_names(mesh_dp8, user_loss):
     for scope in ('"jvp(forward)/', '"transpose(jvp(forward))/', '"optimizer/',
                   '"step_metrics/'):
         assert scope in text, scope
+
+
+# ---- (f) the state a job starts from has the step's own types ------------------
+
+def _mlp():
+    import flax.linen as nn
+
+    class MLP(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return nn.Dense(2)(nn.relu(nn.Dense(16)(x)))
+
+    return MLP()
+
+
+def _rows(seed=0, B=BATCH):
+    rng = np.random.default_rng(seed)
+    return {"x": rng.normal(size=(B, 8)).astype(np.float32),
+            "labels": rng.integers(0, 2, (B,)).astype(np.int32)}
+
+
+def _mlp_trainer(mesh_kind, mesh_dp8):
+    from synapseml_tpu.parallel import MeshConfig, create_mesh
+    from synapseml_tpu.parallel.partition import PartitionRules
+
+    cfg = TrainerConfig(total_steps=100, learning_rate=1e-2)
+    if mesh_kind == "data4":
+        mesh = create_mesh(MeshConfig(data=4), devices=jax.devices()[:4], allow_fewer=False)
+    else:
+        mesh = mesh_dp8
+    if mesh_kind == "zero_rules":
+        cfg.partition_rules = PartitionRules(zero_axes=("data", "fsdp"))
+        cfg.zero_shard = True
+    return Trainer(_mlp(), mesh, cfg)
+
+
+def _counters(state) -> list:
+    """`step` and the optimizer state's 0-d leaves (Adam's `count`)."""
+    return [state.step] + [x for x in jax.tree.leaves(state.opt_state) if np.ndim(x) == 0]
+
+
+MESHES = ["dp8", "data4", "zero_rules"]
+
+
+@pytest.mark.parametrize("mesh_kind", MESHES)
+@pytest.mark.parametrize("made", ["init_state", "resume_state", "resume_state_restored"])
+def test_the_builders_place_the_counters_replicated_on_the_mesh(mesh_dp8, mesh_kind, made):
+    tr = _mlp_trainer(mesh_kind, mesh_dp8)
+    fresh = tr.init_state(_rows(), jax.random.PRNGKey(0))
+    if made == "init_state":
+        state, step, count = fresh, 0, 0
+    else:
+        # a checkpoint restored without a rule table: host arrays
+        params = jax.device_get(fresh.params)
+        opt_state = None
+        if made == "resume_state_restored":
+            opt_state = jax.tree.map(
+                lambda x: np.full(np.shape(x), 5, np.asarray(x).dtype)
+                if np.ndim(x) == 0 else np.asarray(x), jax.device_get(fresh.opt_state))
+        state = tr.resume_state(params, opt_state, step=7)
+        step, count = 7, 5 if opt_state is not None else 0
+    counters = _counters(state)
+    assert len(counters) >= 2
+    for x in counters:
+        assert isinstance(x, jax.Array) and x.sharding == tr.mesh.replicated()
+    assert int(counters[0]) == step and counters[0].dtype == jnp.int32
+    assert [int(x) for x in counters[1:]] == [count] * (len(counters) - 1)
+    if mesh_kind == "zero_rules":
+        # the rule table's placement stays: the moments are sharded over the
+        # replica group, the counters were already on the mesh
+        assert any(x.sharding.spec != jax.sharding.PartitionSpec()
+                   for x in jax.tree.leaves(state.opt_state) if np.ndim(x))
+
+
+def test_counters_the_rule_table_placed_are_left_as_they_are(mesh_dp8):
+    tr = _mlp_trainer("zero_rules", mesh_dp8)
+    state = tr.init_state(_rows(), jax.random.PRNGKey(0))
+    opt_state, _ = tr._place_counters(state.opt_state, 0)
+    assert all(a is b for a, b in zip(jax.tree.leaves(opt_state),
+                                      jax.tree.leaves(state.opt_state)))
+
+
+@pytest.mark.parametrize("mesh_kind", MESHES)
+def test_a_fit_from_a_fresh_state_builds_one_signature_on_every_mesh(mesh_dp8, mesh_kind):
+    obs.reset_tracer()
+    tr = _mlp_trainer(mesh_kind, mesh_dp8)
+    n = CHUNK * DISPATCHES
+    state = tr.fit(tr.init_state(_rows(), jax.random.PRNGKey(0)),
+                   iter([_rows(i) for i in range(n)]), max_steps=n, scan_chunk=CHUNK)
+    spans = obs.get_tracer().finished_spans()
+    dispatches = _children(spans, _roots(spans)[0])["train.dispatch"]
+    assert [s.attributes["compiled"] for s in dispatches] == [True, False, False]
+    assert [s.attributes["signature"] for s in _compiles(spans)] == [1]
+    # what came out has the types that went in
+    assert all(x.sharding == tr.mesh.replicated() for x in _counters(state))
+
+
+def test_the_pipeline_trainers_state_has_one_signature():
+    from synapseml_tpu.models.pipeline_trainer import PipelineTrainer
+    from synapseml_tpu.parallel import MeshConfig, create_mesh
+    from synapseml_tpu.parallel.partition import PartitionRules
+
+    def stage_fn(p, h):
+        return jax.nn.relu(h @ p["block_#"]["w"] + p["block_#"]["b"])
+
+    def head_loss_fn(shared, h, b):
+        logp = jax.nn.log_softmax(h @ shared["head"]["w"], -1)
+        return -jnp.mean(jnp.take_along_axis(logp, b["labels"][:, None], axis=-1))
+
+    mesh = create_mesh(MeshConfig(data=1, pipe=2), devices=jax.devices()[:2],
+                       allow_fewer=False)
+    tr = PipelineTrainer(mesh, TrainerConfig(total_steps=100, learning_rate=1e-2,
+                                             partition_rules=PartitionRules(
+                                                 stage_regex=r"block_(\d+)")),
+                         stage_fn=stage_fn, embed_fn=lambda shared, b: b["x"],
+                         head_loss_fn=head_loss_fn, n_micro=2)
+    rng = np.random.default_rng(0)
+    flat = {"head": {"w": rng.normal(size=(8, 2)).astype(np.float32)},
+            **{f"block_{i}": {"w": rng.normal(size=(8, 8)).astype(np.float32),
+                              "b": np.zeros(8, np.float32)} for i in range(2)}}
+    state = tr.init_state(_rows(), init_params=flat)
+    assert all(x.sharding == tr.mesh.replicated() for x in _counters(state))
+    assert int(state.step) == 0
+    obs.reset_tracer()
+    n = CHUNK * DISPATCHES
+    tr.fit(state, iter([_rows(i) for i in range(n)]), max_steps=n, scan_chunk=CHUNK)
+    assert [s.attributes["signature"] for s in _compiles(obs.get_tracer().finished_spans())] == [1]
 
 
 # ---- Span -------------------------------------------------------------------
